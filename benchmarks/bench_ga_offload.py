@@ -27,6 +27,7 @@ import tempfile
 import time
 
 from repro.core.evaluator import (Evaluator, ProcessPool,
+                                  refuse_jax_children_on_tpu,
                                   register_fitness_factory)
 from repro.core.frontends.ast_frontend import Executor, PyProgram
 from repro.core.ga import Evaluation, GAConfig, run_ga
@@ -74,7 +75,9 @@ def _build_module_fitness():
         plan = module_frontend.plan_from_bits(graph, bits, ExecPlan())
         return jax.jit(lambda p, b: model.loss(p, b, plan)).lower(params, batch)
 
-    return CostModelFitness(lower=lower, n_devices=1), graph
+    # scored against v5e peaks: the kind is named, never taken from the CPU
+    return CostModelFitness(lower=lower, n_devices=1,
+                            device_kind="TPU v5 lite"), graph
 
 
 def _module_fitness_factory():
@@ -320,7 +323,7 @@ def _bench_mesh(rows: list) -> None:
     gene is cost-only — it charges the modeled per-shard transfer +
     collective cost, prices energy at n devices, and divides the transfer
     objective by its shard count — so the mesh rows are pure model
-    arithmetic, byte-stable on any host.  Genuine shard_map execution is
+    arithmetic, byte-stable on any host.  Genuine sharded execution is
     covered by the forced-8-device test, not gated here."""
     from repro.core import OffloadConfig, Offloader
     from repro.core import objectives as objmod
@@ -570,6 +573,9 @@ def _module_parallel_main() -> list[str]:
 
 
 def _bench_module_parallel(rows: list) -> None:
+    # the parent has used JAX by now: a JAX child (and its pool) would find
+    # the chip taken
+    refuse_jax_children_on_tpu("the module process-pool bench")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "

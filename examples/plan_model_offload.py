@@ -14,7 +14,6 @@ container; the mechanics are identical for the full configs.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +23,13 @@ from repro.core.frontends.registry import OffloadConfig
 from repro.core.ga import GAConfig
 from repro.core.offload import Offloader
 from repro import roofline as rl
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.dryrun import lower_cell
 
 
 def main():
+    print("compile cache:", setup_compile_cache())
     cfg = ArchConfig(arch_id="mini_dense", family="dense", n_layers=3,
                      d_model=512, n_heads=32, n_kv_heads=4, head_dim=16,
                      d_ff=1408, vocab=8000, mlp_act="silu",
@@ -46,7 +47,7 @@ def main():
         frontend="module", ga=GAConfig(population=6, generations=2, seed=0),
         log=print,
         options={"lower_fn": lower_fn, "n_devices": mesh.size,
-                 "model_flops": model_flops})
+                 "model_flops": model_flops, "device_kind": "TPU v5 lite"})
     res = Offloader(ocfg).plan(cfg)
 
     print("\n--- block pass (pattern DB) ---")

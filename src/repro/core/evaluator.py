@@ -57,7 +57,8 @@ from repro.core.journal import Journal, file_lock, newest_per_key
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
-__all__ = ["EvalStats", "Evaluator", "ProcessPool", "transfer_cost_surrogate",
+__all__ = ["EvalStats", "Evaluator", "ProcessPool",
+           "refuse_jax_children_on_tpu", "transfer_cost_surrogate",
            "register_fitness_factory", "fitness_factory",
            "fitness_factory_names", "record_search_meta", "last_rank_corr"]
 
@@ -892,6 +893,19 @@ def _pool_worker_eval(bits: tuple) -> Evaluation:
     return _POOL_FITNESS(bits)
 
 
+def refuse_jax_children_on_tpu(what: str) -> None:
+    """Raise when the backend is a TPU: a chip belongs to one process, so a
+    parent that uses JAX there leaves nothing for JAX child processes (they
+    fail or hang).  Called before any child is started."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} starts JAX child processes, but on a TPU backend the "
+            f"chip belongs to this process alone; measure serially "
+            f"(GAConfig.workers=0, pool=None) instead")
+
+
 class ProcessPool:
     """Spawn-based measurement pool built from a registered fitness factory.
 
@@ -904,12 +918,16 @@ class ProcessPool:
     ``warm(chromosomes)`` pays every worker's one-time first-compile cost up
     front (results are measured in the parent's Evaluator-free context and
     discarded), so timed searches see a warm pool.
+
+    CPU backends only: on a TPU the pool refuses to start (one process
+    per chip, :func:`refuse_jax_children_on_tpu`).
     """
 
     def __init__(self, factory: str | Callable, workers: Optional[int] = None,
                  args: tuple = (), kwargs: Optional[dict] = None):
         import multiprocessing as mp
 
+        refuse_jax_children_on_tpu("ProcessPool")
         if isinstance(factory, str):
             factory = fitness_factory(factory)
         self.workers = int(workers or min(4, (os.cpu_count() or 2)))

@@ -8,8 +8,9 @@ anti-static-prediction stance, §3.1):
   analogue) -> invalid = time ∞.
 * :class:`CostModelFitness` — AOT ``lower().compile()`` at production scale
   on the production mesh; the measured artifact is the compiled binary:
-  roofline step time as the objective, per-device HBM fit as the validity
-  check (OOM -> time ∞, like a compile error in the paper).
+  roofline step time (against the target chip's published peaks) as the
+  objective, per-device HBM fit as the validity check (OOM -> time ∞, like
+  a compile error in the paper).
 
 Both are plain ``bits -> Evaluation`` callables; caching, dedup, parallel
 dispatch and persistence belong to :mod:`repro.core.evaluator`, not here.
@@ -116,13 +117,24 @@ class CostModelFitness:
     """bits -> lower/compile -> roofline step time; OOM/compile error = ∞.
 
     ``lower`` maps bits to a jax.stages.Lowered (the caller owns mesh,
-    shardings and input specs).  ``hbm_budget`` is per-device bytes.
+    shardings and input specs).  ``device_kind`` names the chip the program
+    is compiled for, whose peaks (:func:`repro.roofline.peaks_for`) score
+    it; ``None`` means this process's first device.  ``hbm_budget`` is
+    per-device bytes, by default that chip's memory.
     """
 
     lower: Callable[[tuple], Any]
     n_devices: int
     model_flops: float = 0.0
-    hbm_budget: float = 16e9          # TPU v5e: 16 GB
+    device_kind: Optional[str] = None
+    hbm_budget: Optional[float] = None
+
+    def __post_init__(self):
+        if self.device_kind is None:
+            self.device_kind = jax.devices()[0].device_kind
+        peaks = rl.peaks_for(self.device_kind)     # unknown kind: raise now
+        if self.hbm_budget is None:
+            self.hbm_budget = peaks.hbm_bytes
 
     def __call__(self, bits: tuple) -> Evaluation:
         try:
@@ -140,7 +152,8 @@ class CostModelFitness:
         except Exception:  # pragma: no cover — backend without memory stats
             mem, live = None, 0
         roof = rl.analyze(compiled, compiled.as_text(), self.n_devices,
-                          model_flops_global=self.model_flops)
+                          model_flops_global=self.model_flops,
+                          device_kind=self.device_kind)
         detail = {"roofline": roof.summary(), "live_bytes": live}
         if live > self.hbm_budget:
             return Evaluation(bits, float("inf"), False,

@@ -1312,7 +1312,7 @@ class AstFrontend:
             # variant sites make the gene an implementation choice, so the
             # frontend proposes the variant alphabet — plus this host's
             # mesh destinations (cost-modeled: mesh_executed stays False,
-            # the interpreter never decodes a gene to shard_map execution);
+            # the interpreter never decodes a gene to sharded execution);
             # plain programs keep the paper's binary interp/jit gene
             destinations=(with_mesh_destinations(VARIANT_ALPHABET)
                           if variant_sites or block_sites else None),

@@ -2,9 +2,9 @@
 
 The "compiled language" path (the paper's C/Clang analogue): a JAX program
 is traced to a ClosedJaxpr; control-flow equations (scan / while / cond /
-user pjit closed calls) become *loop/block* regions with their own
+user jit closed calls) become *loop/block* regions with their own
 characteristic vectors, contiguous simple equations become *stmt* regions.
-Small glue calls (a pjit'd ``tril`` or ``where`` with a handful of inner
+Small glue calls (a jitted ``tril`` or ``where`` with a handful of inner
 equations) are folded into the surrounding run so a hand-written attention
 stays one matchable block instead of fragmenting at every jnp helper.
 Variable def/use sets come from the equation in/out vars, callees from
@@ -29,7 +29,7 @@ import numpy as np
 from repro.core import similarity as sim
 from repro.core.ir import Region, RegionGraph
 
-_LOOP_PRIMS = {"scan", "while", "fori_loop", "cond", "pjit", "custom_vjp_call",
+_LOOP_PRIMS = {"scan", "while", "fori_loop", "cond", "jit", "custom_vjp_call",
                "custom_jvp_call", "remat", "checkpoint", "closed_call", "core_call"}
 
 #: control-flow primitives are never glue, whatever their body size
@@ -72,7 +72,7 @@ def _inner_eqn_count(eqn) -> int:
 
 def _is_glue(eqn, derived: set) -> bool:
     """Small closed calls, and calls none of whose inputs derive from the
-    program's inputs (mask builders like a pjit'd ``tril`` over constants
+    program's inputs (mask builders like a jitted ``tril`` over constants
     compute the same value every run), are glue: they fold into the
     surrounding run instead of splitting a matchable block.  Any
     input-derived operand — float activations or integer indices into a
@@ -221,7 +221,7 @@ def annotate_block_sites(graph: RegionGraph, db, registry=None) -> RegionGraph:
     window is kept only if at least one registry variant actually binds the
     merged span's concrete avals (no dead genes in the chromosome).
     """
-    from jax import core as jcore
+    from jax.extend.core import Literal
 
     from repro.core.substitution import _span_io
     from repro.core.variants import resolve_variant
@@ -235,10 +235,10 @@ def annotate_block_sites(graph: RegionGraph, db, registry=None) -> RegionGraph:
     last_use: dict = {}
     for i, eqn in enumerate(eqns):
         for v in eqn.invars:
-            if not isinstance(v, jcore.Literal):
+            if not isinstance(v, Literal):
                 last_use[v] = i
     program_outs = {v for v in closed.jaxpr.outvars
-                    if not isinstance(v, jcore.Literal)}
+                    if not isinstance(v, Literal)}
     backend = jax.default_backend()
 
     # maximal runs of span-adjacent offloadable candidates, program order
@@ -448,7 +448,7 @@ class JaxprFrontend:
             # variant alphabet plus whatever meshes this host can really
             # build (empty extension on single-device CI)
             destinations=with_mesh_destinations(VARIANT_ALPHABET),
-            # this measured path genuinely decodes mesh genes to shard_map
+            # this measured path genuinely decodes mesh genes to sharded
             # execution, so available meshes are measured, not modeled
             mesh_executed=True,
             # bind results join the phenotype key: two chromosomes whose

@@ -105,8 +105,8 @@ def plan_from_coding(graph: RegionGraph, coding, values,
 class ModuleFrontend:
     """Model-config frontend for the unified pipeline: sites are ExecPlan
     knobs; fitness is the AOT cost model when the caller provides a
-    ``lower_fn`` (options: lower_fn, n_devices, model_flops, hbm_budget,
-    base_plan), else the static-cost stub.
+    ``lower_fn`` (options: lower_fn, n_devices, model_flops, device_kind,
+    hbm_budget, base_plan), else the static-cost stub.
 
     The static fallback carries only structural signal for module graphs:
     accelerated ExecPlan *compute* values count as device placements in the
@@ -153,21 +153,30 @@ class ModuleFrontend:
                 measured=False, destinations=VARIANT_ALPHABET,
                 context=context)
 
+        import jax
+
+        from repro import roofline as rl
+        from repro.core.fitness import CostModelFitness
+
         n_devices = int(opts.get("n_devices", 1))
         model_flops = float(opts.get("model_flops", 0.0))
-        hbm_budget = float(opts.get("hbm_budget", 16e9))
+        # the chip the lowered programs target: peaks and HBM budget follow
+        # it (an unknown kind raises here, before any search)
+        device_kind = opts.get("device_kind") or jax.devices()[0].device_kind
+        hbm_budget = float(opts.get("hbm_budget")
+                           or rl.peaks_for(device_kind).hbm_bytes)
 
         def fitness_factory(coding):
-            from repro.core.fitness import CostModelFitness
             return CostModelFitness(
                 lower=lambda values: lower_fn(
                     plan_from_coding(graph, coding, values, base)),
                 n_devices=n_devices, model_flops=model_flops,
-                hbm_budget=hbm_budget)
+                device_kind=device_kind, hbm_budget=hbm_budget)
 
-        # compiled step-time estimates are machine-portable — key the
-        # persistent cache by architecture + mesh + scale
-        cache_extra = (f"arch={cfg.arch_id}|dev={n_devices}"
+        # compiled step-time estimates depend on the target chip — key the
+        # persistent cache by architecture + chip + mesh + scale
+        cache_extra = (f"arch={cfg.arch_id}|kind={device_kind}"
+                       f"|dev={n_devices}"
                        f"|flops={model_flops:.3g}|hbm={hbm_budget:.3g}"
                        f"|base={base}|costmodel")
         return FitnessBundle(

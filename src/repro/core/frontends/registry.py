@@ -154,7 +154,7 @@ class FitnessBundle:
     mesh_executed: bool = False               # False (default) means mesh
                                               # genes
                                               # are never genuinely decoded
-                                              # to shard_map execution by
+                                              # to sharded execution by
                                               # this fitness, so the mesh
                                               # cost model must be charged
                                               # on top of measurements even

@@ -56,7 +56,7 @@ def probed_device_count() -> int:
     """How many accelerator-visible devices this process has (cached).
 
     Mesh destinations compare their ``n`` against this to decide between
-    genuine shard_map execution and cost-only modeling.  Falls back to 1
+    genuine sharded execution and cost-only modeling.  Falls back to 1
     when jax is unavailable or backend init fails."""
     global _PROBED_DEVICE_COUNT
     if _PROBED_DEVICE_COUNT is None:
@@ -149,7 +149,7 @@ class MeshDestination(Destination):
     doubles as the wire format: ``mesh:{axis}:{n}:{spec}``.
 
     Decoding keeps ``impl_index`` 0 (the reference implementation): the
-    substitution engine replaces the site's span with a shard_map'd run of
+    substitution engine replaces the site's span with a mesh-sharded run of
     that same span when the host has >= ``n`` devices; otherwise the
     destination is cost-only and :func:`modeled_cost_s` charges per-shard
     transfers plus a modeled collective term."""
@@ -466,7 +466,7 @@ def modeled_cost_s(graph: RegionGraph, coding: GeneCoding,
 
     Mesh genes charge the mesh cost model unless ``mesh_executed`` — the
     flag a frontend sets when its measured path genuinely decodes mesh
-    destinations through shard_map (the jaxpr engine on a multi-device
+    destinations as sharded execution (the jaxpr engine on a multi-device
     host), in which case the measurement already contains the real cost.
     An unavailable mesh (``is_cost_only``) charges the model regardless.
     """

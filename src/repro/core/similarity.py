@@ -94,7 +94,7 @@ def jaxpr_vector(jaxpr: Any) -> dict[str, int]:
 
 def eqns_vector(eqns: Any) -> dict[str, int]:
     """Primitive counts over a list of jaxpr equations, recursing into
-    sub-jaxprs (glue calls like a pjit'd ``tril`` contribute their inner
+    sub-jaxprs (glue calls like a jitted ``tril`` contribute their inner
     primitives, so region vectors stay comparable with the whole-trace
     vectors the pattern DB stores)."""
     counts: Counter = Counter()

@@ -26,7 +26,8 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
 import jax
-from jax import core as jcore
+from jax.core import DropVar
+from jax.extend.core import Literal
 
 from repro.core.ir import RegionGraph
 # the resolve/check/fallback rule and the report types are frontend-neutral
@@ -98,45 +99,36 @@ class SiteBinding:
             in_vars=tuple(self.in_vars))
 
 
+def _eval_eqns(eqns: Sequence, env: dict) -> None:
+    """Bind each equation on the values in ``env`` (var -> value), as
+    ``jax.core.eval_jaxpr`` does, writing the outputs back into ``env``."""
+    def read(v):
+        return v.val if isinstance(v, Literal) else env[v]
+
+    for eqn in eqns:
+        subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
+        ans = eqn.primitive.bind(*subfuns, *[read(v) for v in eqn.invars],
+                                 **bind_params)
+        outs = ans if eqn.primitive.multiple_results else [ans]
+        for v, a in zip(eqn.outvars, outs):
+            if not isinstance(v, DropVar):
+                env[v] = a
+
+
 def _span_io(eqns: Sequence, used_later: Callable) -> tuple[tuple, tuple]:
     """Free inputs (first-use order) and live outputs of an equation group."""
     defined: set = set()
     ins: list = []
     for eqn in eqns:
         for v in eqn.invars:
-            if isinstance(v, jcore.Literal) or v in defined or v in ins:
+            if isinstance(v, Literal) or v in defined or v in ins:
                 continue
             ins.append(v)
         defined.update(o for o in eqn.outvars
-                       if not isinstance(o, jcore.DropVar))
+                       if not isinstance(o, DropVar))
     outs = [o for eqn in eqns for o in eqn.outvars
-            if not isinstance(o, jcore.DropVar) and used_later(o)]
+            if not isinstance(o, DropVar) and used_later(o)]
     return tuple(ins), tuple(outs)
-
-
-#: call-like primitives whose inner jaxpr can be inlined during per-shard
-#: re-interpretation.  Loop primitives (scan/while/cond) stay bound — their
-#: body shapes are part of the loop semantics, not just trace residue.
-_INLINE_CALL_PRIMS = {"pjit", "custom_jvp_call", "custom_vjp_call", "remat",
-                      "checkpoint", "closed_call", "core_call"}
-
-
-def _inline_closed(eqn):
-    """The inner ClosedJaxpr of a call-like equation, or None.  Used by the
-    mesh adapter to interpret call bodies with per-shard shapes instead of
-    re-binding the call (whose stored jaxpr is specialized to the global
-    trace shapes)."""
-    if eqn.primitive.name not in _INLINE_CALL_PRIMS:
-        return None
-    for k in ("jaxpr", "call_jaxpr"):
-        j = eqn.params.get(k)
-        if j is None:
-            continue
-        if hasattr(j, "jaxpr"):                  # already a ClosedJaxpr
-            return j
-        if hasattr(j, "eqns"):                   # raw Jaxpr: close it
-            return jcore.ClosedJaxpr(j, ())
-    return None
 
 
 # ---------------------------------------------------------------------------
@@ -183,10 +175,10 @@ class SubstitutionEngine:
         last_use: dict = {}
         for i, eqn in enumerate(eqns):
             for v in eqn.invars:
-                if not isinstance(v, jcore.Literal):
+                if not isinstance(v, Literal):
                     last_use[v] = i
         program_outs = {v for v in jaxpr.outvars
-                        if not isinstance(v, jcore.Literal)}
+                        if not isinstance(v, Literal)}
 
         sites: list[SiteBinding] = []
         for region in self.graph.offloadable():
@@ -210,7 +202,7 @@ class SubstitutionEngine:
                 sites.append(SiteBinding(
                     region.name, pattern, kind, (s, e),
                     in_vars=tuple(v for v in eqn.invars
-                                  if not isinstance(v, jcore.Literal)),
+                                  if not isinstance(v, Literal)),
                     out_vars=tuple(eqn.outvars), params=params))
             else:
                 def used_later(v, _e=e):
@@ -236,10 +228,10 @@ class SubstitutionEngine:
         last_use: set = set()
         for eqn in jaxpr.eqns[site.span[1]:]:
             last_use.update(v for v in eqn.invars
-                            if not isinstance(v, jcore.Literal))
+                            if not isinstance(v, Literal))
         last_use.update(v for v in jaxpr.outvars
-                        if not isinstance(v, jcore.Literal))
-        return [not isinstance(v, jcore.DropVar) and v in last_use
+                        if not isinstance(v, Literal))
+        return [not isinstance(v, DropVar) and v in last_use
                 for v in site.out_vars]
 
     def _resolve_variant(self, site: SiteBinding, requested: str
@@ -274,7 +266,7 @@ class SubstitutionEngine:
 
     def _mesh_adapter(self, site: SiteBinding, dest
                       ) -> tuple[Optional[Callable], str]:
-        """-> (shard_map'd span adapter or None, why).  Memoized: the
+        """-> (mesh-placed span adapter or None, why).  Memoized: the
         decision depends only on (site, mesh destination) for the engine's
         lifetime — avals and the device set are fixed."""
         key = (site.region, dest.name)
@@ -288,25 +280,23 @@ class SubstitutionEngine:
     def _mesh_adapter_uncached(self, site: SiteBinding, dest
                                ) -> tuple[Optional[Callable], str]:
         """Build the genuine mesh execution of a site: the span's own
-        equations re-interpreted under ``shard_map`` on an n-device mesh.
+        equations, with their inputs and outputs constrained to a sharding
+        over an n-device mesh, so the compiler partitions the span across
+        those devices.
 
         The sharding heuristic is deliberately conservative and shape-
         checked: the destination's spec names a dimension (batch = leading,
-        feature = trailing), every output must carry the same extent on it
-        (a reduction over the sharded dim cannot recombine by
-        concatenation), inputs that carry it are sharded and the rest
-        replicated.  Anything the heuristic cannot place — or that
-        shard_map rejects at trace time — falls back to the normal variant
-        path with the reason reported; a placement that type-checks but
-        computes wrong partials is caught by the search's numeric
-        verification and discarded as an invalid chromosome (the paper's
-        environment-adaptive trial-and-error)."""
-        from jax.sharding import PartitionSpec as P
+        feature = trailing), every output must carry the same extent on it,
+        inputs that carry it are sharded and the rest replicated.  Anything
+        the heuristic cannot place — or that fails to trace — falls back to
+        the normal variant path with the reason reported.  The equations
+        keep their global shapes, so the placement changes where the span
+        runs, never what it computes."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.launch.mesh import make_destination_mesh
-        from repro.runtime.pspec import shard_map_compat
 
-        if any(isinstance(v, jcore.DropVar) for v in site.out_vars):
+        if any(isinstance(v, DropVar) for v in site.out_vars):
             return None, "site has dropped outputs"
         out_shapes = [tuple(getattr(v.aval, "shape", ()))
                       for v in site.out_vars]
@@ -340,66 +330,34 @@ class SubstitutionEngine:
 
         in_specs = tuple(spec_for(tuple(getattr(v.aval, "shape", ())))
                          for v in site.in_vars)
-        out_specs = tuple(spec_for(s) for s in out_shapes)
         if all(sp == P() for sp in in_specs):
             return None, "no input carries the sharded dimension"
 
         eqns = tuple(self.closed.jaxpr.eqns[site.span[0]:site.span[1]])
         in_vars, out_vars = tuple(site.in_vars), tuple(site.out_vars)
-
-        def span_fn(*ins):
-            # Re-interpret the span with *per-shard* inputs.  Nested call
-            # primitives (pjit, custom_jvp_call, ...) must be inlined rather
-            # than bound: their stored jaxprs are specialized to the global
-            # trace shapes and would re-impose them on the shards, while
-            # their member equations are shape-polymorphic.
-            def eval_eqns(eqns_, env):
-                def read(v):
-                    return v.val if isinstance(v, jcore.Literal) else env[v]
-
-                for eqn in eqns_:
-                    inner = _inline_closed(eqn)
-                    if inner is not None \
-                            and len(inner.jaxpr.invars) == len(eqn.invars):
-                        ienv: dict = dict(zip(inner.jaxpr.constvars,
-                                              inner.consts))
-                        ienv.update(zip(inner.jaxpr.invars,
-                                        [read(v) for v in eqn.invars]))
-                        eval_eqns(inner.jaxpr.eqns, ienv)
-                        outs = [v.val if isinstance(v, jcore.Literal)
-                                else ienv[v] for v in inner.jaxpr.outvars]
-                    else:
-                        subfuns, bind_params = \
-                            eqn.primitive.get_bind_params(eqn.params)
-                        ans = eqn.primitive.bind(
-                            *subfuns, *[read(v) for v in eqn.invars],
-                            **bind_params)
-                        outs = ans if eqn.primitive.multiple_results \
-                            else [ans]
-                    for v, a in zip(eqn.outvars, outs):
-                        if not isinstance(v, jcore.DropVar):
-                            env[v] = a
-
-            env: dict = dict(zip(in_vars, ins))
-            eval_eqns(eqns, env)
-            return tuple(env[v] for v in out_vars)
-
         try:
             mesh = make_destination_mesh(dest.n, dest.axis)
-            sharded = shard_map_compat(span_fn, mesh=mesh,
-                                       in_specs=in_specs,
-                                       out_specs=out_specs)
-            got = jax.eval_shape(
-                sharded, *[jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
-                           for v in in_vars])
+        except ValueError as e:
+            return None, str(e)
+        in_sh = tuple(NamedSharding(mesh, sp) for sp in in_specs)
+        out_sh = tuple(NamedSharding(mesh, spec_for(s)) for s in out_shapes)
+        constrain = jax.lax.with_sharding_constraint
+
+        def placed(*ins):
+            env: dict = {v: constrain(x, sh)
+                         for v, x, sh in zip(in_vars, ins, in_sh)}
+            _eval_eqns(eqns, env)
+            return tuple(constrain(env[v], sh)
+                         for v, sh in zip(out_vars, out_sh))
+
+        try:
+            jax.eval_shape(placed, *[jax.ShapeDtypeStruct(v.aval.shape,
+                                                          v.aval.dtype)
+                                     for v in in_vars])
         except Exception as e:  # noqa: BLE001 — any trace-time rejection
-            return None, f"shard_map build failed: {type(e).__name__}: {e}"
-        for g, v in zip(got, out_vars):
-            if (tuple(g.shape) != tuple(v.aval.shape)
-                    or g.dtype != v.aval.dtype):
-                return None, "sharded span changes output shape/dtype"
-        return sharded, (f"shard_map over {dest.n}x{dest.axis} "
-                         f"(spec {dest.spec})")
+            return None, f"mesh placement failed: {type(e).__name__}: {e}"
+        return placed, (f"sharded over a {dest.n}-device {dest.axis!r} "
+                        f"mesh (spec {dest.spec})")
 
     # -- substitution -------------------------------------------------------
 
@@ -412,9 +370,9 @@ class SubstitutionEngine:
         ``destinations`` (region -> destination name, from
         :meth:`GeneCoding.destinations_of`) routes mesh-assigned sites
         through :meth:`_mesh_adapter`: on hosts with enough devices the
-        site's span genuinely runs under shard_map; otherwise (or when the
-        heuristic rejects the shapes) the site falls back to the normal
-        variant resolution with the reason reported."""
+        site's span genuinely runs sharded over the mesh; otherwise (or
+        when the heuristic rejects the shapes) the site falls back to the
+        normal variant resolution with the reason reported."""
         from repro.core.genes import get_destination, probed_device_count
 
         report = SubstitutionReport()
@@ -475,10 +433,10 @@ class SubstitutionEngine:
             env: dict = {}
 
             def read(v):
-                return v.val if isinstance(v, jcore.Literal) else env[v]
+                return v.val if isinstance(v, Literal) else env[v]
 
             def write(v, val):
-                if not isinstance(v, jcore.DropVar):
+                if not isinstance(v, DropVar):
                     env[v] = val
 
             for v, c in zip(jaxpr.constvars, closed.consts):
@@ -541,19 +499,9 @@ class SubstitutionEngine:
         flat = jax.tree_util.tree_leaves(self.example_args)
         env: dict = dict(zip(jaxpr.constvars, closed.consts))
         env.update(zip(jaxpr.invars, flat))
-
-        def read(v):
-            return v.val if isinstance(v, jcore.Literal) else env[v]
-
-        for eqn in jaxpr.eqns[:site.span[1]]:
-            subfuns, bind_params = eqn.primitive.get_bind_params(eqn.params)
-            ans = eqn.primitive.bind(
-                *subfuns, *[read(v) for v in eqn.invars], **bind_params)
-            outs = ans if eqn.primitive.multiple_results else [ans]
-            for v, a in zip(eqn.outvars, outs):
-                if not isinstance(v, jcore.DropVar):
-                    env[v] = a
-        return ([read(v) for v in site.in_vars],
+        _eval_eqns(jaxpr.eqns[:site.span[1]], env)
+        return ([v.val if isinstance(v, Literal) else env[v]
+                 for v in site.in_vars],
                 [env.get(v) for v in site.out_vars])
 
     def verify_block(self, region: str, impl_id,

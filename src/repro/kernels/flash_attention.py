@@ -73,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool, scale: float, blk_q: int = 128,
                        blk_k: int = 128, group: int = 1,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """q: (B*Hq, Sq, D); k, v: (B*Hkv, Sk, D); Hq = Hkv * group.
 
     Returns (B*Hq, Sq, D).  Sequence lengths must be multiples of the block

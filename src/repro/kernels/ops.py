@@ -1,7 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
 Handles padding to block multiples, GQA head flattening, backend detection
-(interpret mode everywhere except real TPU), and initial-state folding.
+(interpret mode on the CPU backend only: anywhere else the kernels compile
+for the device or fail loudly), and initial-state folding.
 These wrappers are what the pattern DB registers as replacement
 implementations for the matched function blocks.
 """
@@ -20,7 +21,7 @@ from repro.kernels import wkv6 as _wk
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0) -> tuple[jax.Array, int]:
@@ -103,12 +104,13 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, log_w: jax.Array,
 @functools.partial(jax.jit, static_argnames=("eps", "blk_rows"))
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
             blk_rows: int = 256) -> jax.Array:
-    """x: (..., d); scale: (d,)."""
+    """x: (..., d); scale: (d,).  Rows pad to a whole number of blocks (a
+    block is all rows, or ``blk_rows``, a multiple of 8 as Mosaic requires);
+    zero rows normalize to zero and are sliced off."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     n = x2.shape[0]
-    blk = min(blk_rows, n)
-    while n % blk != 0:
-        blk //= 2
-    out = _rn.rmsnorm(x2, scale, eps=eps, blk_rows=max(blk, 1), interpret=_interpret())
-    return out.reshape(shape)
+    blk = n if n <= blk_rows else blk_rows
+    x2, _ = _pad_to(x2, 0, blk)
+    out = _rn.rmsnorm(x2, scale, eps=eps, blk_rows=blk, interpret=_interpret())
+    return out[:n].reshape(shape)

@@ -37,7 +37,7 @@ class CallSite:
     """What a variant binds against: one matched region, concretized.
 
     ``kind`` is how the region appears in the jaxpr — a ``span`` of simple
-    equations, a single closed ``call`` (pjit), or a ``scan``.  ``in_avals``
+    equations, a single closed ``call`` (jit), or a ``scan``.  ``in_avals``
     / ``out_avals`` follow the jaxpr equation/span order (for scans:
     ``[consts..., carry..., xs...]`` in, ``[carry..., ys...]`` out).
     ``out_used[i]`` is False when output ``i`` is dropped by the program —
@@ -448,7 +448,7 @@ def _sole_rhs_dots(site: CallSite) -> list:
 
 def _scan_params(eqns, name: str, key: str):
     """Find a primitive param anywhere in a block, including inside the
-    closed sub-jaxprs of member pjit calls."""
+    closed sub-jaxprs of member jit calls."""
     for e in eqns:
         if e.primitive.name == name:
             return e.params.get(key)

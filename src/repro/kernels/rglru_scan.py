@@ -53,7 +53,7 @@ def _rglru_kernel(log_a_ref, b_ref, out_ref, h_scr, *, chunk: int):
 
 
 def rglru_scan(log_a: jax.Array, b: jax.Array, *, chunk: int = 256,
-               d_block: int = 128, interpret: bool = True) -> jax.Array:
+               d_block: int = 128, interpret: bool) -> jax.Array:
     """log_a, b: (B, S, D) -> states h: (B, S, D).  h0 = 0 (ops.py folds a
     nonzero initial state into b[0])."""
     bsz, s, d = log_a.shape

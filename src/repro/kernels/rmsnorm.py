@@ -21,7 +21,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-            blk_rows: int = 256, interpret: bool = True) -> jax.Array:
+            blk_rows: int = 256, interpret: bool) -> jax.Array:
     """x: (N, d); scale: (d,)."""
     n, d = x.shape
     blk = min(blk_rows, n)

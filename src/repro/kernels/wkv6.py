@@ -9,6 +9,7 @@ scratch.  Within a chunk (length C) the recurrence is evaluated in closed
 form with log-space decay ratios (all exponents <= 0, numerically safe):
 
     cs_t   = cumsum(log w) (inclusive),  cs'_t = cs_t - log w_t (exclusive)
+             (a lower-triangular-ones matmul: Mosaic has no cumsum)
     inter  = (r_t * exp(cs'_t)) @ S_in
     intra  = tril_{-1}[ (r_t * exp(cs'_t)) (k_s * exp(-cs_s))^T ] @ V
     bonus  = (r_t . u . k_t) v_t
@@ -41,7 +42,13 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *, chunk: int
     u = u_ref[0].astype(jnp.float32)    # (1, D)
     s_in = s_scr[...]                   # (D, D)
 
-    cs = jnp.cumsum(lw, axis=0)         # inclusive
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = jnp.where(cols <= rows, 1.0, 0.0).astype(jnp.float32)
+    cs = jax.lax.dot_general(           # inclusive prefix sum over time
+        tril, lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     cs_prev = cs - lw                   # exclusive
     r_dec = r * jnp.exp(cs_prev)        # (C, D)
     k_dec = k * jnp.exp(-cs)            # (C, D)
@@ -50,8 +57,6 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *, chunk: int
         r_dec, s_in, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     scores = jax.lax.dot_general(
         r_dec, k_dec, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    rows = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(cols < rows, scores, 0.0)   # strictly lower triangle
     y_intra = jax.lax.dot_general(
         scores, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -66,7 +71,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *, chunk: int
 
 
 def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, log_w: jax.Array,
-         u: jax.Array, *, chunk: int = 64, interpret: bool = True) -> jax.Array:
+         u: jax.Array, *, chunk: int = 64, interpret: bool) -> jax.Array:
     """r/k/v/log_w: (BH, S, D); u: (BH, 1, D).  Returns y: (BH, S, D) fp32."""
     bh, s, d = r.shape
     assert s % chunk == 0, (s, chunk)

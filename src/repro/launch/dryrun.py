@@ -1,6 +1,5 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "10")
 
 """Multi-pod dry-run: ``lower().compile()`` every (architecture x input
@@ -27,6 +26,7 @@ import jax.numpy as jnp
 from repro import roofline as rl
 from repro.configs.base import (ALL_SHAPES, ARCH_IDS, SHAPES_BY_NAME,
                                 ArchConfig, ShapeSpec, get_config)
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.plans import production_plan, tuned_plan
 from repro.models.api import Model, build_model
@@ -40,6 +40,10 @@ from repro.runtime.pspec import axis_rules
 from repro.runtime.train import TrainState, jit_train_step, make_train_step
 
 Sds = jax.ShapeDtypeStruct
+
+#: the chip the production meshes are made of; its peaks score every cell
+#: (the placeholder devices here are CPUs and report no such kind)
+TARGET_KIND = "TPU v5 lite"
 
 log = get_logger("launch.dryrun")
 
@@ -159,7 +163,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         log.info("%s", {k: ca[k] for k in ("flops", "bytes accessed")
                         if k in ca})
         roof = rl.analyze(compiled, compiled.as_text(), n_dev,
-                          model_flops_global=mf)
+                          model_flops_global=mf, device_kind=TARGET_KIND)
         live = (getattr(mem, "argument_size_in_bytes", 0)
                 + getattr(mem, "output_size_in_bytes", 0)
                 + getattr(mem, "temp_size_in_bytes", 0)
@@ -210,6 +214,7 @@ def _write(rec: dict, out_dir: str) -> None:
 
 def main() -> None:
     setup_logging()
+    log.info("compile cache: %s", setup_compile_cache())
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(ARCH_IDS))
     ap.add_argument("--shape", default=None,

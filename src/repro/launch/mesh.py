@@ -13,19 +13,29 @@ from __future__ import annotations
 import functools
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: sharding propagates from
+    the shardings on the arguments and the ``constrain`` hints, as the model
+    code expects (``make_mesh`` otherwise defaults to explicit axes, under
+    which a gather such as ``jnp.take`` must name its output sharding)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
     """Whatever this host actually has (tests / examples)."""
     n = len(jax.devices())
     model = max(1, min(model, n))
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return auto_mesh((n // model, model), ("data", "model"))
 
 
 @functools.lru_cache(maxsize=None)
@@ -38,4 +48,4 @@ def make_destination_mesh(n: int, axis: str = "data"):
     if len(devices) < n:
         raise ValueError(f"mesh destination wants {n} devices, "
                          f"host has {len(devices)}")
-    return jax.make_mesh((n,), (axis,))
+    return auto_mesh((n,), (axis,))

@@ -21,6 +21,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.core import block_offload_pass, default_db
 from repro.core.frontends import module_frontend
 from repro.data import Batcher, DataConfig, SyntheticLMDataset
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import build_model
 from repro.models.plan import ExecPlan
 from repro.obs import trace as obs_trace
@@ -35,6 +36,7 @@ log = get_logger("launch.train")
 
 def main() -> None:
     setup_logging()
+    log.info("compile cache: %s", setup_compile_cache())
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_0_6b", choices=list(ARCH_IDS))
     ap.add_argument("--steps", type=int, default=50)

@@ -375,10 +375,9 @@ def attend_chunked(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,
             with axis_rules(None):
                 return _flash(qi, ki, vi, causal, window, ck, L.cdtype(plan), sk)
 
-        from repro.runtime.pspec import shard_map_compat
-        out = shard_map_compat(inner, mesh=rules.mesh,
-                               in_specs=(spec, spec, spec),
-                               out_specs=spec)(qf, kf, vf)
+        out = jax.shard_map(inner, mesh=rules.mesh,
+                            in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)(qf, kf, vf)
     if pad_bh:
         out = out[:bh]
     out = out.reshape(b, hq, sq, hd).transpose(0, 2, 1, 3)

@@ -238,9 +238,8 @@ def moe_scatter_ep_sharded(x2d: Array, p: dict, cfg: ArchConfig,
             return body(x_loc, wr, wg, wu, wd)
 
     tspec = P(t_axes, None)
-    from repro.runtime.pspec import shard_map_compat
-    y, lb, z = shard_map_compat(
-        inner, mesh=mesh,
+    y, lb, z = jax.shard_map(
+        inner, mesh=mesh, check_vma=False,
         in_specs=(tspec, P("data", None), P("model", "data", None),
                   P("model", "data", None), P("model", None, "data")),
         out_specs=(tspec, P(), P()),

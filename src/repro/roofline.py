@@ -1,13 +1,13 @@
-"""Roofline analysis from compiled XLA artifacts (TPU v5e targets).
+"""Roofline analysis from compiled XLA artifacts.
 
-This is the "verification environment measurement" available without real
-TPU hardware: per-device HLO FLOPs / bytes from ``compiled.cost_analysis()``
-plus per-device collective bytes parsed out of the (SPMD-partitioned) HLO
-text.  Three terms:
+Per-device HLO FLOPs / bytes from the compiled program's HLO text plus
+per-device collective bytes parsed out of the (SPMD-partitioned) HLO.  Three
+terms, each against the peaks of the target chip (:data:`PEAKS`, keyed by
+``jax.Device.device_kind``):
 
-    compute    = HLO_FLOPs / peak_FLOPs            (197 TFLOP/s bf16 / chip)
-    memory     = HLO_bytes / HBM_bw                 (819 GB/s / chip)
-    collective = ring-model link bytes / link_bw    (~50 GB/s / link)
+    compute    = HLO_FLOPs / peak bf16 FLOP/s
+    memory     = HLO_bytes / HBM bandwidth
+    collective = ring-model link bytes / link bandwidth
 
 Estimated step time = max(terms) (classic roofline).  Collective byte model
 per op (g = participating group size, sz = per-device result bytes):
@@ -19,15 +19,43 @@ per op (g = participating group size, sz = per-device result bytes):
 """
 from __future__ import annotations
 
-import dataclasses
 import re
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Optional
 
-# --- TPU v5e hardware constants (per assignment) ---------------------------
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-LINK_BW = 50e9                  # bytes/s per ICI link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published peak rates of one chip."""
+
+    flops_bf16: float            # FLOP/s
+    hbm_bw: float                # bytes/s
+    link_bw: float               # bytes/s per chip-to-chip link
+    hbm_bytes: float             # device memory
+    source: str
+
+
+#: peaks per chip, keyed by ``jax.Device.device_kind``.  A kind missing here
+#: is an error (:func:`peaks_for`), never a default.
+PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9, link_bw=50e9, hbm_bytes=16e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip "
+               "interconnect (taken as 4 links of 50 GB/s)"),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The peak table entry of ``device_kind``; raises for a kind with no
+    recorded peaks."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak rates recorded for device kind "
+                       f"{device_kind!r}; known kinds: {sorted(PEAKS)}"
+                       ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -120,6 +148,7 @@ class Roofline:
     hbm_bytes: float             # per-device bytes accessed
     collective_bytes: float      # per-device link bytes (ring model)
     n_devices: int
+    peaks: ChipPeaks
     collectives: list[CollectiveOp] = field(default_factory=list)
     model_flops: float = 0.0     # 6*N*D useful flops (per device)
     histogram: dict = field(default_factory=dict)      # op@group -> stats
@@ -127,15 +156,15 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / LINK_BW
+        return self.collective_bytes / self.peaks.link_bw
 
     @property
     def step_s(self) -> float:
@@ -156,7 +185,7 @@ class Roofline:
         """MODEL_FLOPS / (step_s * peak) — the MFU-style score."""
         if self.step_s <= 0:
             return 0.0
-        return self.model_flops / (self.step_s * PEAK_FLOPS_BF16)
+        return self.model_flops / (self.step_s * self.peaks.flops_bf16)
 
     def summary(self) -> dict:
         return {
@@ -176,8 +205,10 @@ class Roofline:
 
 
 def analyze(compiled, hlo_text: Optional[str] = None, n_devices: int = 1,
-            model_flops_global: float = 0.0) -> Roofline:
-    """Build a Roofline from a compiled executable.
+            model_flops_global: float = 0.0, *,
+            device_kind: str) -> Roofline:
+    """Build a Roofline from a compiled executable, against the peaks of
+    ``device_kind`` (the chip the program was compiled for).
 
     Primary source: our HLO-text analyzer (``repro.hlo_analysis``) over
     ``compiled.as_text()`` — it applies while-loop trip-count multipliers
@@ -194,6 +225,7 @@ def analyze(compiled, hlo_text: Optional[str] = None, n_devices: int = 1,
         hbm_bytes=hc.bytes,
         collective_bytes=hc.link_bytes,
         n_devices=n_devices,
+        peaks=peaks_for(device_kind),
         collectives=cols,
         model_flops=model_flops_global / max(n_devices, 1),
         histogram=hc.collective_histogram(),

@@ -131,18 +131,7 @@ def local_map(fn, in_specs, out_specs, *args):
         with axis_rules(None):
             return fn(*a)
 
-    return shard_map_compat(inner, mesh=rules.mesh, in_specs=in_specs,
-                            out_specs=out_specs)(*args)
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map: `jax.shard_map` (new API, `check_vma`) when
-    present, else `jax.experimental.shard_map.shard_map` (old API,
-    `check_rep`).  Replication checking is disabled in both — the local
-    bodies here intentionally compute unreplicated partial results."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    # replication checking is off: the local bodies intentionally compute
+    # unreplicated partial results
+    return jax.shard_map(inner, mesh=rules.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)

@@ -106,9 +106,11 @@ class Server:
         and the next call picks this one up — never a torn mix."""
         self._bound = _Bound(self.model, plan)
 
-    def generate(self, inputs: dict, max_new: Optional[int] = None) -> np.ndarray:
+    def generate(self, inputs: dict, max_new: Optional[int] = None,
+                 return_logits: bool = False):
         """inputs: dict with 'tokens' (B,S) (+ frames/patch_feats).  Returns
-        generated tokens (B, max_new)."""
+        generated tokens (B, max_new); with ``return_logits`` also the
+        logits each token was sampled from, (B, max_new, V) on the device."""
         bound = self._bound          # one snapshot: the whole call runs one
         max_new = max_new or self.cfg.max_new_tokens   # complete plan
         tokens = inputs["tokens"]
@@ -122,18 +124,23 @@ class Server:
             logits, state = bound.prefill_fn(cap)(self.params, inputs)
             key = jax.random.key(self.cfg.seed)
             out = np.zeros((b, max_new), np.int32)
+            seen = [logits[:, -1]] if return_logits else None
             tok = self._sample(logits, key, 0)
             for i in range(max_new):
                 out[:, i] = np.asarray(tok[:, 0])
                 if i == max_new - 1:
                     break
                 logits, state = bound.decode(self.params, tok, state)
+                if return_logits:
+                    seen.append(logits[:, -1])
                 tok = self._sample(logits, key, i + 1)
         # the histogram lives in the process-wide registry keyed by name,
         # not on the _Bound snapshot — a mid-flight swap_plan publishes a
         # new snapshot but cannot reset the latency series
         obs_metrics.histogram("serve.generate_seconds").observe(
             time.perf_counter() - t0)
+        if return_logits:
+            return out, jnp.stack(seen, axis=1)
         return out
 
     def traffic_hz(self, window_s: float = 60.0) -> float:

@@ -1,6 +1,6 @@
 """Train-step builders.
 
-* ``make_train_step``   — the production pjit step: loss -> grads -> AdamW,
+* ``make_train_step``   — the production jit step: loss -> grads -> AdamW,
   sharding via the logical-axis rules (DP/FSDP/TP/EP from one table), buffer
   donation for params/optimizer state.
 * ``make_compressed_dp_step`` — shard_map data-parallel variant with
@@ -122,8 +122,6 @@ def make_compressed_dp_step(model: Model, plan: ExecPlan,
     has_pod = "pod" in mesh.shape
     n_pods = mesh.shape.get("pod", 1)
 
-    from jax.experimental.shard_map import shard_map
-
     batch_spec = P(dp_axes)
     rep = P()
 
@@ -160,9 +158,9 @@ def make_compressed_dp_step(model: Model, plan: ExecPlan,
 
     state_specs = TrainState(rep, AdamWState(rep, rep, rep), rep)
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, rep),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(smapped, donate_argnums=(0,))

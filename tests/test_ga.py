@@ -5,7 +5,7 @@ import math
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.ga import Evaluation, GAConfig, run_ga
 

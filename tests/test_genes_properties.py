@@ -7,12 +7,11 @@ sites with short implementation menus, decode/encode (``destinations_of``)
 round-trip, cross-alphabet seed-value mapping, and phenotype-key
 consistency (decode-equivalent chromosomes share a key).
 
-Property tests run under hypothesis (via ``tests/_hypothesis_compat``,
-skipping cleanly on bare environments); the example-based sections at the
-bottom always run.
+Property tests run under hypothesis; the example-based sections at the
+bottom pin the same rules on fixed cases.
 """
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.genes import (Destination, GeneCoding, Site,
                               coding_from_graph, get_destination,
@@ -126,7 +125,7 @@ def test_phenotype_key_matches_decode_equivalence(alphabet, extras, data):
 
 
 # ---------------------------------------------------------------------------
-# example-based anchors (always run, hypothesis or not)
+# example-based anchors
 # ---------------------------------------------------------------------------
 
 
@@ -362,7 +361,7 @@ def test_mesh_modeled_cost_charged_unless_executed():
     assert probed_device_count() < 4
     assert modeled_cost_s(graph, coding, mesh_bits) > 0.0
     assert modeled_cost_s(graph, coding, (0, 0)) == 0.0
-    # a fitness that genuinely shard_maps (mesh_executed=True) on a host
+    # a fitness that genuinely shards (mesh_executed=True) on a host
     # that has the devices is not double-charged
     old = genes._PROBED_DEVICE_COUNT
     genes._PROBED_DEVICE_COUNT = 8

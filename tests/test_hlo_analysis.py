@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.hlo_analysis import analyze_hlo
-from repro.roofline import Roofline
+from repro.roofline import Roofline, peaks_for
 
 
 def _compile(fn, *specs):
@@ -68,6 +68,7 @@ def test_dynamic_update_slice_counts_slice_not_operand():
 def test_roofline_terms_and_dominance():
     r = Roofline(flops=197e12 * 0.01, hbm_bytes=819e9 * 0.05,
                  collective_bytes=50e9 * 0.002, n_devices=4,
+                 peaks=peaks_for("TPU v5 lite"),
                  model_flops=197e12 * 0.005)
     assert r.compute_s == pytest.approx(0.01)
     assert r.memory_s == pytest.approx(0.05)
@@ -76,6 +77,12 @@ def test_roofline_terms_and_dominance():
     assert r.step_s == pytest.approx(0.05)
     assert r.roofline_fraction == pytest.approx(0.005 / 0.05)
     assert r.useful_flops_ratio == pytest.approx(0.5)
+
+
+def test_unknown_device_kind_has_no_peaks():
+    # a chip with no recorded peaks is an error, never a v5e default
+    with pytest.raises(KeyError, match="no peak rates"):
+        peaks_for("cpu")
 
 
 def test_collective_ring_model():

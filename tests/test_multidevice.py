@@ -20,9 +20,10 @@ _SCRIPT = textwrap.dedent("""
     from repro.configs.base import ArchConfig, MoEConfig
     from repro.models import build_model, REFERENCE_PLAN, OFFLOAD_PLAN
     from repro.runtime import sharding as shd
+    from repro.launch.mesh import auto_mesh
     from repro.runtime.pspec import axis_rules
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     rules = shd.make_rules(mesh)
 
     cfg = ArchConfig(arch_id="mini_moe", family="moe", n_layers=2,
@@ -140,7 +141,7 @@ _MESH_GA_SCRIPT = textwrap.dedent("""
     assert ctx.bundle.mesh_executed
 
     # deterministic fitness that still GENUINELY executes every chromosome:
-    # decode -> substitute (mesh genes become shard_map spans on the real
+    # decode -> substitute (mesh genes become sharded spans on the real
     # 8-device mesh) -> run -> compare against the reference.  Latency is
     # then a deterministic function of what actually ran, so the search and
     # its Pareto front are reproducible.
@@ -166,7 +167,7 @@ _MESH_GA_SCRIPT = textwrap.dedent("""
 
     ctx.config.fitness_fn = fitness
     res = off.search(ctx)
-    assert mesh_ran, "no chromosome ever reached shard_map execution"
+    assert mesh_ran, "no chromosome ever reached sharded execution"
 
     def is_mesh(ev):
         return any(n.startswith("mesh:")
@@ -183,7 +184,7 @@ _MESH_GA_SCRIPT = textwrap.dedent("""
     art = off.apply(ctx, best_mesh.bits)
     got = np.asarray(jax.jit(art.fn)(*args))
     assert np.allclose(got, ref, rtol=1e-4, atol=1e-5)
-    assert any(c.chosen.startswith("mesh:") and "shard_map" in c.why
+    assert any(c.chosen.startswith("mesh:") and "sharded over" in c.why
                for c in art.report.choices), art.report.choices
 
     # store -> load -> rehydrate -> serve, with no new search

@@ -349,6 +349,19 @@ def test_unknown_pool_factory_raises():
                   GAConfig(pool="no-such-factory", workers=2))
 
 
+def test_process_pool_refuses_on_tpu_backend(monkeypatch):
+    # a chip belongs to one process: spawned JAX workers would find it taken
+    # (or hang), so the pool refuses before starting any
+    import jax
+
+    from repro.core.evaluator import ProcessPool
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="chip belongs to this process"):
+        ProcessPool("smoke", workers=1)
+    with pytest.raises(RuntimeError, match="ProcessPool"):
+        ga_search(_ir_graph(), None, GAConfig(pool="smoke", workers=2))
+
+
 def test_offloader_rejects_pool():
     # the pipeline composes a fitness (block claims, exclusions, destination
     # costs) that spawn workers cannot rebuild from a factory — measuring a

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import Batcher, DataConfig, SyntheticLMDataset
 from repro.optim import (OptimizerConfig, adamw_init, adamw_update,

@@ -6,7 +6,7 @@ import math
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core import Evaluation, GAConfig, OffloadConfig, Offloader
 from repro.core import objectives as objmod
 from repro.core.ga import (crowding_distances, dominates, non_dominated_sort,

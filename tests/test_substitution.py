@@ -455,13 +455,13 @@ def test_invalid_variant_result_is_rejected_by_verifier(rng):
 
 
 # ---------------------------------------------------------------------------
-# mesh destinations: genuine shard_map execution and cost-only fallback
+# mesh destinations: genuine sharded execution and cost-only fallback
 # ---------------------------------------------------------------------------
 
 
 def test_mesh_destination_executes_span_under_shard_map(rng):
     # n=1 is a degenerate but *genuine* mesh: available on any host, so the
-    # full route — gene name -> _mesh_adapter -> shard_map span -> numerics —
+    # full route — gene name -> _mesh_adapter -> sharded span -> numerics —
     # runs in-process on single-device CI
     fn, args, pat = _rmsnorm_case(rng, 16, 8)
     engine = _engine_for(fn, args)
@@ -471,7 +471,7 @@ def test_mesh_destination_executes_span_under_shard_map(rng):
     choice = next(c for c in sub.report.choices if c.region == region)
     assert choice.requested == "mesh:data:1:batch"
     assert choice.chosen == "mesh:data:1:batch"
-    assert "shard_map" in choice.why
+    assert "sharded over" in choice.why
     v = verify(fn(*args), sub(*args))
     assert v.ok, v
 

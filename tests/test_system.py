@@ -9,6 +9,7 @@ import pytest
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLMDataset
+from repro.launch.mesh import auto_mesh
 from repro.models import REFERENCE_PLAN, build_model
 from repro.models.plan import ExecPlan
 from repro.optim import OptimizerConfig
@@ -77,7 +78,7 @@ def test_supervised_training_with_failures(setup, tmp_path):
 def test_compressed_dp_step_tracks_exact(setup):
     """int8-EF compressed gradients converge like exact (single-axis mesh)."""
     cfg, model, data = setup
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = auto_mesh((1,), ("data",))
 
     def run(compress):
         state = init_train_state(model, jax.random.key(1),

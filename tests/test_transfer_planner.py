@@ -1,6 +1,6 @@
 """Transfer planner: the paper's def/use transfer rule + hoisting, checked on
 hand-built IR and property-tested for safety invariants."""
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.ir import Region, RegionGraph
 from repro.core.transfer_planner import plan_transfers
