@@ -53,7 +53,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
 from repro.core.ga import Evaluation
-from repro.core.journal import Journal, file_lock, newest_per_key
+from repro.journal import Journal, file_lock, newest_per_key
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -63,7 +63,7 @@ __all__ = ["EvalStats", "Evaluator", "ProcessPool",
            "fitness_factory_names", "record_search_meta", "last_rank_corr"]
 
 #: backcompat alias — the sidecar-flock helper now lives in
-#: :mod:`repro.core.journal` so every record stream (seed bank, search meta,
+#: :mod:`repro.journal` so every record stream (seed bank, search meta,
 #: surrogate fits, measurements, plan store) shares one code path.
 _file_lock = file_lock
 
@@ -88,7 +88,7 @@ _MEASUREMENTS_MAX_RECORDS = 2048
 class MeasurementCache:
     """On-disk (fingerprint, bits) -> Evaluation store, one JSONL per program.
 
-    Built on the shared :class:`repro.core.journal.Journal` (the same
+    Built on the shared :class:`repro.journal.Journal` (the same
     flock/fsync code path as the seed bank, search meta, surrogate fits and
     the plan store): appends serialize on the sidecar lock so concurrent
     writers from different processes can share one file; duplicate lines are

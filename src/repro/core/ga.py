@@ -416,10 +416,6 @@ def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
             history.append(entry)
             gspan.set(**history[-1])
         obs_metrics.counter("ga.generations").inc()
-        obs_metrics.gauge("ga.best_time_s").set(best.time_s)
-        obs_metrics.gauge("ga.gen_mean_time_s").set(
-            history[-1]["mean_time_s"]
-            if math.isfinite(history[-1]["mean_time_s"]) else -1.0)
         obs_metrics.counter("ga.invalid").inc(history[-1]["n_invalid"])
         if log:
             log(f"gen {gen}: best={best.time_s:.6g}s "

@@ -31,7 +31,7 @@ from repro.core import similarity as sim
 from repro.core.evaluator import (Evaluator, ProcessPool, last_rank_corr,
                                   record_search_meta,
                                   transfer_cost_surrogate)
-from repro.core.journal import Journal
+from repro.journal import Journal
 from repro.core.frontends.registry import (FitnessBundle, OffloadConfig,
                                            decoded_pattern, detect_frontend,
                                            get_frontend, resolve_alphabet)

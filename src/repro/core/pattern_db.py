@@ -32,7 +32,7 @@ import numpy as np
 
 from repro.core import similarity as sim
 from repro.core.ir import Region
-from repro.core.journal import Journal
+from repro.journal import Journal
 from repro.obs import metrics as obs_metrics
 
 # ---------------------------------------------------------------------------

@@ -391,7 +391,7 @@ def fit_surrogate(graph: RegionGraph, coding: GeneCoding, cache_dir: str,
 
 
 def _save_fit(cache_dir: str, fit: FittedSurrogate) -> None:
-    from repro.core.journal import Journal, newest_per_key
+    from repro.journal import Journal, newest_per_key
 
     os.makedirs(cache_dir, exist_ok=True)
     journal = Journal(os.path.join(cache_dir, SURROGATE_FIT_FILE))
@@ -427,7 +427,7 @@ def load_fit(cache_dir: str, fingerprint: str,
     (coefficients by feature name, journal size, both rank correlations) —
     the inspection entry point; returns None when nothing was ever fitted.
     Records from before per-objective fits count as latency fits."""
-    from repro.core.journal import Journal
+    from repro.journal import Journal
 
     out: Optional[dict] = None
     for rec in Journal(os.path.join(cache_dir, SURROGATE_FIT_FILE)).records():

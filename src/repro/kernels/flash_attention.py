@@ -106,5 +106,6 @@ def flash_attention_bh(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((blk_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
         **kwargs,
     )(q, k, v)

@@ -74,5 +74,6 @@ def rglru_scan(log_a: jax.Array, b: jax.Array, *, chunk: int = 256,
         out_shape=jax.ShapeDtypeStruct((bsz, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
         **kwargs,
     )(log_a, b)

@@ -40,5 +40,6 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
         out_specs=pl.BlockSpec((blk, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
         **kwargs,
     )(x, scale)

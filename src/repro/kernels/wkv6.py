@@ -90,5 +90,6 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, log_w: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
         interpret=interpret,
+        name="wkv6",
         **kwargs,
     )(r, k, v, log_w, u)

@@ -464,11 +464,12 @@ def attend_decode(q1: Array, cache: KVCache, cache_len: Array,
 def cache_update(cache: KVCache, k1: Array, v1: Array, cache_len: Array,
                  ring: bool) -> KVCache:
     """Insert one token's k/v at the right slot (ring or linear)."""
-    sc = cache.k.shape[1]
-    slot = (cache_len % sc) if ring else cache_len
-    k = jax.lax.dynamic_update_slice_in_dim(cache.k, k1, slot, axis=1)
-    v = jax.lax.dynamic_update_slice_in_dim(cache.v, v1, slot, axis=1)
-    return KVCache(k, v)
+    with jax.named_scope("kv_cache"):
+        sc = cache.k.shape[1]
+        slot = (cache_len % sc) if ring else cache_len
+        k = jax.lax.dynamic_update_slice_in_dim(cache.k, k1, slot, axis=1)
+        v = jax.lax.dynamic_update_slice_in_dim(cache.v, v1, slot, axis=1)
+        return KVCache(k, v)
 
 
 # ---------------------------------------------------------------------------

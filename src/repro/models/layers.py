@@ -62,17 +62,20 @@ def rmsnorm_fused(x: Array, scale: Array, eps: float) -> Array:
 
 
 def rmsnorm(x: Array, scale: Array, eps: float, plan: ExecPlan) -> Array:
-    if plan.norm_impl == "fused":
-        return rmsnorm_fused(x, scale, eps)
-    return rmsnorm_ref(x, scale, eps)
+    with jax.named_scope("norm"):
+        if plan.norm_impl == "fused":
+            return rmsnorm_fused(x, scale, eps)
+        return rmsnorm_ref(x, scale, eps)
 
 
 def layernorm(x: Array, scale: Array, bias: Array, eps: float) -> Array:
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+        y = (xf - mu) * jax.lax.rsqrt(var + eps)
+        return (y * scale.astype(jnp.float32)
+                + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -150,9 +153,10 @@ def mlp_fused(x: Array, p: dict, act: str, plan: ExecPlan) -> Array:
 
 
 def mlp(x: Array, p: dict, act: str, plan: ExecPlan) -> Array:
-    if plan.mlp_impl == "fused":
-        return mlp_fused(x, p, act, plan)
-    return mlp_ref(x, p, act, plan)
+    with jax.named_scope("mlp"):
+        if plan.mlp_impl == "fused":
+            return mlp_fused(x, p, act, plan)
+        return mlp_ref(x, p, act, plan)
 
 
 # ---------------------------------------------------------------------------

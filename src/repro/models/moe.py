@@ -249,15 +249,16 @@ def moe_scatter_ep_sharded(x2d: Array, p: dict, cfg: ArchConfig,
 
 def moe_block(x: Array, p: dict, cfg: ArchConfig, plan: ExecPlan) -> tuple[Array, MoEAux]:
     """x: (B,S,d) -> (B,S,d), aux."""
-    b, s, d = x.shape
-    x2d = x.reshape(b * s, d)
-    if plan.moe_impl == "scatter_ep":
-        out = moe_scatter_ep_sharded(x2d, p, cfg, plan)
-        if out is not None:
-            y, aux = out
-            y = y + _shared(x2d, p, cfg, plan)
+    with jax.named_scope("moe"):
+        b, s, d = x.shape
+        x2d = x.reshape(b * s, d)
+        if plan.moe_impl == "scatter_ep":
+            out = moe_scatter_ep_sharded(x2d, p, cfg, plan)
+            if out is not None:
+                y, aux = out
+                y = y + _shared(x2d, p, cfg, plan)
+            else:
+                y, aux = moe_scatter(x2d, p, cfg, plan)
         else:
-            y, aux = moe_scatter(x2d, p, cfg, plan)
-    else:
-        y, aux = moe_dense(x2d, p, cfg, plan)
-    return y.reshape(b, s, d), aux
+            y, aux = moe_dense(x2d, p, cfg, plan)
+        return y.reshape(b, s, d), aux

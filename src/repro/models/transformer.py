@@ -146,28 +146,36 @@ def _attn_sublayer_full(x, p_attn, ln, cfg: ArchConfig, plan: ExecPlan,
                         positions, want_cache: bool, cache_capacity: int):
     b, s, _ = x.shape
     h = L.rmsnorm(x, ln, cfg.norm_eps, plan)
-    q, k, v = A.project_qkv(h, p_attn, cfg, plan, positions)
-    o = A.attend(q, k, v, positions, positions, causal=True,
-                 attn_kind=cfg.attn_kind, window=cfg.local_window, plan=plan)
-    o = o.reshape(b, s, -1) @ p_attn["wo"].astype(L.cdtype(plan))
-    o = constrain(o, "batch", "seq", None)
+    with jax.named_scope("attention"):
+        q, k, v = A.project_qkv(h, p_attn, cfg, plan, positions)
+        o = A.attend(q, k, v, positions, positions, causal=True,
+                     attn_kind=cfg.attn_kind, window=cfg.local_window,
+                     plan=plan)
+        o = o.reshape(b, s, -1) @ p_attn["wo"].astype(L.cdtype(plan))
+        o = constrain(o, "batch", "seq", None)
     cache = None
     if want_cache:
-        if cfg.attn_kind == "local":
-            w = cfg.local_window
-            kc = k[:, -w:]
-            vc = v[:, -w:]
-            # ring layout: slot = position % window
-            roll = (s % w) - w
-            kc = jnp.roll(kc, roll, axis=1) if s >= w else jnp.pad(k, ((0, 0), (0, w - s), (0, 0), (0, 0)))
-            vc = jnp.roll(vc, roll, axis=1) if s >= w else jnp.pad(v, ((0, 0), (0, w - s), (0, 0), (0, 0)))
-            cache = (kc, vc)
-        else:
-            pad = cache_capacity - s
-            cax = A.cache_axes(cfg.n_kv_heads)
-            cache = (constrain(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax),
-                     constrain(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax))
+        with jax.named_scope("kv_cache"):
+            cache = _prefill_cache(k, v, cfg, s, cache_capacity)
     return x + o, cache
+
+
+def _prefill_cache(k, v, cfg: ArchConfig, s: int, cache_capacity: int):
+    """The decode cache a prefill of ``s`` tokens leaves: the last window
+    as a ring (local attention), else k/v padded to ``cache_capacity``."""
+    if cfg.attn_kind == "local":
+        w = cfg.local_window
+        kc = k[:, -w:]
+        vc = v[:, -w:]
+        # ring layout: slot = position % window
+        roll = (s % w) - w
+        kc = jnp.roll(kc, roll, axis=1) if s >= w else jnp.pad(k, ((0, 0), (0, w - s), (0, 0), (0, 0)))
+        vc = jnp.roll(vc, roll, axis=1) if s >= w else jnp.pad(v, ((0, 0), (0, w - s), (0, 0), (0, 0)))
+        return kc, vc
+    pad = cache_capacity - s
+    cax = A.cache_axes(cfg.n_kv_heads)
+    return (constrain(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax),
+            constrain(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax))
 
 
 def _mlp_sublayer_full(x, blk, cfg: ArchConfig, plan: ExecPlan):
@@ -312,17 +320,20 @@ def forward_full(params: dict, x: Array, cfg: ArchConfig, plan: ExecPlan,
 
 def embed_inputs(params: dict, cfg: ArchConfig, plan: ExecPlan, tokens: Array,
                  patch_feats: Optional[Array] = None) -> Array:
-    x = L.embed_tokens(tokens, params["embed"], plan, cfg.scale_embeddings)
-    if cfg.vision_patches and patch_feats is not None:
-        pj = params["projector"]
-        dt = L.cdtype(plan)
-        v = jax.nn.gelu(patch_feats.astype(dt) @ pj["vis_w1"].astype(dt)
-                        + pj["vis_b1"].astype(dt), approximate=True)
-        v = v @ pj["vis_w2"].astype(dt) + pj["vis_b2"].astype(dt)
-        x = jnp.concatenate([v, x], axis=1)
-    if cfg.family == "ssm":
-        x = L.layernorm(x, params["embed_norm_s"], params["embed_norm_b"], cfg.norm_eps)
-    return constrain(x, "batch", "seq", None)
+    with jax.named_scope("embed"):
+        x = L.embed_tokens(tokens, params["embed"], plan,
+                           cfg.scale_embeddings)
+        if cfg.vision_patches and patch_feats is not None:
+            pj = params["projector"]
+            dt = L.cdtype(plan)
+            v = jax.nn.gelu(patch_feats.astype(dt) @ pj["vis_w1"].astype(dt)
+                            + pj["vis_b1"].astype(dt), approximate=True)
+            v = v @ pj["vis_w2"].astype(dt) + pj["vis_b2"].astype(dt)
+            x = jnp.concatenate([v, x], axis=1)
+        if cfg.family == "ssm":
+            x = L.layernorm(x, params["embed_norm_s"], params["embed_norm_b"],
+                            cfg.norm_eps)
+        return constrain(x, "batch", "seq", None)
 
 
 def head_table(params: dict) -> Array:
@@ -330,9 +341,11 @@ def head_table(params: dict) -> Array:
 
 
 def lm_logits(params: dict, cfg: ArchConfig, plan: ExecPlan, hidden: Array) -> Array:
-    h = L.rmsnorm(hidden, params["final_norm"], cfg.norm_eps, plan)
-    out = L.logits_from_hidden(h, head_table(params), plan, cfg.logit_softcap)
-    return constrain(out, "batch", "seq", "vocab")
+    with jax.named_scope("head"):
+        h = L.rmsnorm(hidden, params["final_norm"], cfg.norm_eps, plan)
+        out = L.logits_from_hidden(h, head_table(params), plan,
+                                   cfg.logit_softcap)
+        return constrain(out, "batch", "seq", "vocab")
 
 
 # ---------------------------------------------------------------------------
@@ -396,13 +409,16 @@ def prefill(params: dict, cfg: ArchConfig, plan: ExecPlan, tokens: Array,
 
 def _dense_block_decode(x1, blk, kv, cache_len, cfg, plan):
     h = L.rmsnorm(x1, blk["ln1"], cfg.norm_eps, plan)
-    pos = cache_len[None].astype(jnp.int32)
-    q, k, v = A.project_qkv(h, blk["attn"], cfg, plan, pos)
-    ring = cfg.attn_kind == "local"
-    cache = A.cache_update(A.KVCache(kv["k"], kv["v"]), k, v, cache_len, ring)
-    o = A.attend_decode(q, cache, cache_len + 1, cfg.local_window if ring else 0,
-                        plan, ring)
-    o = o.reshape(x1.shape[0], 1, -1) @ blk["attn"]["wo"].astype(L.cdtype(plan))
+    with jax.named_scope("attention"):
+        pos = cache_len[None].astype(jnp.int32)
+        q, k, v = A.project_qkv(h, blk["attn"], cfg, plan, pos)
+        ring = cfg.attn_kind == "local"
+        cache = A.cache_update(A.KVCache(kv["k"], kv["v"]), k, v, cache_len,
+                               ring)
+        o = A.attend_decode(q, cache, cache_len + 1,
+                            cfg.local_window if ring else 0, plan, ring)
+        o = o.reshape(x1.shape[0], 1, -1) \
+            @ blk["attn"]["wo"].astype(L.cdtype(plan))
     x1 = x1 + o
     x1, _ = _mlp_sublayer_full(x1, blk, cfg, plan)
     return x1, {"k": cache.k, "v": cache.v}
@@ -419,13 +435,17 @@ def _rglru_sublayer_decode(x1, sub, st, cfg, plan):
 
 
 def _tree_index(tree, i):
-    return jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+    with jax.named_scope("kv_cache"):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            tree)
 
 
 def _tree_update(tree, sub, i):
-    return jax.tree_util.tree_map(
-        lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, i, 0), tree, sub)
+    with jax.named_scope("kv_cache"):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, i, 0),
+            tree, sub)
 
 
 def decode_step(params: dict, cfg: ArchConfig, plan: ExecPlan, token: Array,

@@ -1,18 +1,28 @@
 """Thread-safe context-manager spans with a near-zero-cost disabled path.
 
-One process-global :class:`Tracer` (installed with :func:`enable` /
-:func:`maybe_tracing`) assigns every span an id + parent and persists it
-as one JSONL record through the shared :class:`repro.core.journal.Journal`
-flock helper — the same storage cell every other on-disk record stream in
-the system uses, so a trace file tolerates concurrent writers and torn
-tails like the measurement journals do.
+A span goes to up to two sinks, each on or off on its own:
+
+* the **JSONL sink** — one process-global :class:`Tracer` (installed with
+  :func:`enable` / :func:`maybe_tracing`) assigns every span an id +
+  parent and persists it as one JSONL record through the shared
+  :class:`repro.journal.Journal` flock helper — the same storage cell
+  every other on-disk record stream in the system uses, so a trace file
+  tolerates concurrent writers and torn tails like the measurement
+  journals do;
+* the **profiler sink** (:func:`enable_profiler`) — each span also enters
+  a ``jax.profiler.TraceAnnotation`` of its name on the thread that opens
+  it, so while a ``jax.profiler`` session records, the span lands on the
+  profiler's host planes, on the same clock as the device's operations.
+  ``jax`` is imported when the sink is enabled, never before: the JSONL
+  path and ``launch/obsreport`` import no jax.
 
 Design points the hot paths rely on:
 
-* **disabled path**: :func:`span` reads one module global and returns the
-  shared :data:`NULL_SPAN` singleton — no allocation, no clock read, no
-  branch in the instrumented code.  ``benchmarks/bench_obs.py`` measures
-  this cost and CI gates it (``obs.trace_overhead_pct``).
+* **disabled path**: with neither sink on, :func:`span` reads one module
+  global and returns the shared :data:`NULL_SPAN` singleton — no
+  allocation, no clock read, no branch in the instrumented code.
+  ``benchmarks/bench_obs.py`` measures this cost and CI gates it
+  (``obs.trace_overhead_pct``).
 * **per-thread nesting**: each thread keeps its own span stack
   (``threading.local``), so concurrently-planning threads don't parent
   into each other.  Cross-thread work (the Evaluator's compile pool)
@@ -46,12 +56,13 @@ import time
 import uuid
 from typing import Any, Dict, Iterator, Optional, Union
 
-from repro.core.journal import Journal
+from repro.journal import Journal
 from repro.obs import metrics as _metrics
 
 __all__ = ["Span", "NullSpan", "NULL_SPAN", "Tracer", "span",
            "current_span_id", "enable", "disable", "active_tracer",
-           "maybe_tracing", "read_trace"]
+           "enable_profiler", "disable_profiler", "maybe_tracing",
+           "read_trace"]
 
 
 class Span:
@@ -60,7 +71,7 @@ class Span:
     exit."""
 
     __slots__ = ("tracer", "name", "id", "parent", "t0", "ts",
-                 "dur_s", "attrs")
+                 "dur_s", "attrs", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent: Optional[int], attrs: Dict[str, Any]):
@@ -69,6 +80,7 @@ class Span:
         self.id = span_id
         self.parent = parent
         self.attrs = attrs
+        self.annotation = None        # the profiler sink's, while it is on
         self.dur_s: Optional[float] = None
         self.ts = time.time()
         self.t0 = time.perf_counter()
@@ -78,9 +90,13 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        if self.annotation is not None:
+            self.annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.annotation is not None:
+            self.annotation.__exit__(exc_type, exc, tb)
         self.dur_s = time.perf_counter() - self.t0
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
@@ -110,6 +126,24 @@ class NullSpan:
 
 
 NULL_SPAN = NullSpan()
+
+
+class ProfilerSpan(NullSpan):
+    """A span while only the profiler sink is on: the annotation alone,
+    with no id, clock read or record."""
+
+    __slots__ = ("annotation",)
+
+    def __init__(self, annotation):
+        self.annotation = annotation
+
+    def __enter__(self) -> "ProfilerSpan":
+        self.annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.annotation.__exit__(exc_type, exc, tb)
+        return False
 
 
 class Tracer:
@@ -190,20 +224,34 @@ class Tracer:
 
 
 # ---------------------------------------------------------------------------
-# the module-global tracer (the disabled path is one global read)
+# the module-global sinks (the disabled path is one global read)
 # ---------------------------------------------------------------------------
 
-_TRACER: Optional[Tracer] = None
+_TRACER: Optional[Tracer] = None      # the JSONL sink
+_ANNOTATE = None                      # the profiler sink: TraceAnnotation
+_SINKS: Optional[tuple] = None        # (_TRACER, _ANNOTATE) while either is on
+
+
+def _publish() -> None:
+    global _SINKS
+    on = _TRACER is not None or _ANNOTATE is not None
+    _SINKS = (_TRACER, _ANNOTATE) if on else None
 
 
 def span(name: str, parent: Optional[int] = None,
          **attrs: Any) -> Union[Span, NullSpan]:
-    """A span under the installed tracer, or :data:`NULL_SPAN` when
-    tracing is disabled — the only call instrumented code makes."""
-    t = _TRACER
-    if t is None:
+    """A span in every sink that is on, or :data:`NULL_SPAN` when none is —
+    the only call instrumented code makes."""
+    sinks = _SINKS
+    if sinks is None:
         return NULL_SPAN
-    return t.span(name, parent=parent, **attrs)
+    tracer, annotate = sinks
+    if tracer is None:
+        return ProfilerSpan(annotate(name))
+    s = tracer.span(name, parent=parent, **attrs)
+    if annotate is not None:
+        s.annotation = annotate(name)
+    return s
 
 
 def current_span_id() -> Optional[int]:
@@ -223,10 +271,12 @@ def enable(path: str, trace_id: Optional[str] = None,
     closes) any previously installed tracer."""
     global _TRACER
     old, _TRACER = _TRACER, None
+    _publish()
     if old is not None:
         old.close()
     t = Tracer(path, trace_id=trace_id, flush_every=flush_every)
     _TRACER = t
+    _publish()
     return t
 
 
@@ -234,8 +284,28 @@ def disable() -> None:
     """Close and uninstall the global tracer (no-op when none)."""
     global _TRACER
     old, _TRACER = _TRACER, None
+    _publish()
     if old is not None:
         old.close()
+
+
+def enable_profiler() -> None:
+    """Turn the profiler sink on: from now on every span also enters a
+    ``jax.profiler.TraceAnnotation`` of its name (recorded while a
+    ``jax.profiler`` session runs).  Independent of the JSONL sink."""
+    global _ANNOTATE
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATE = TraceAnnotation
+    _publish()
+
+
+def disable_profiler() -> None:
+    """Turn the profiler sink off (no-op when off).  Spans already open
+    still close their annotations."""
+    global _ANNOTATE
+    _ANNOTATE = None
+    _publish()
 
 
 @contextlib.contextmanager

@@ -12,11 +12,18 @@ plan end-to-end — a concurrent :meth:`Server.swap_plan` (the planning
 service's hot-swap) takes effect on the *next* call, never mid-sequence.
 ``Server.from_store`` constructs a server straight from a persisted plan
 fingerprint, with no planner in the loop.
+
+The jitted steps are named functions, so their device programs read
+``jit_serve_prefill`` and ``jit_serve_decode`` in a profiler trace.  Each
+``generate`` opens the span tree ``serve.generate`` > ``serve.prefill``,
+then per token ``serve.sample`` (its dispatches), ``serve.token_to_host``
+(the copy that waits for the token) and ``serve.decode_step`` (the decode
+dispatch); with the profiler sink on (``repro.obs.enable_profiler``) they
+land on the profiler's clock beside the device's operations.
 """
 from __future__ import annotations
 
 import collections
-import functools
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -48,18 +55,22 @@ class _Bound:
     def __init__(self, model: Model, plan: ExecPlan):
         self.plan = plan
         self._model = model
-        self.decode = jax.jit(
-            lambda p, tok, st: model.decode(p, tok, st, plan),
-            donate_argnums=(2,))
+
+        def serve_decode(p, tok, st):
+            return model.decode(p, tok, st, plan)
+
+        self.decode = jax.jit(serve_decode, donate_argnums=(2,))
         self._prefill: dict = {}
 
     def prefill_fn(self, cache_capacity: int):
         if cache_capacity not in self._prefill:
             model, plan = self._model, self.plan
-            self._prefill[cache_capacity] = jax.jit(
-                functools.partial(
-                    lambda p, inp: model.prefill(
-                        p, inp, plan, cache_capacity=cache_capacity)))
+
+            def serve_prefill(p, inp):
+                return model.prefill(p, inp, plan,
+                                     cache_capacity=cache_capacity)
+
+            self._prefill[cache_capacity] = jax.jit(serve_prefill)
         return self._prefill[cache_capacity]
 
 
@@ -117,23 +128,27 @@ class Server:
         b, s = tokens.shape
         t0 = time.perf_counter()
         self._req_times.append(t0)
-        obs_metrics.gauge("serve.traffic_hz").set(self.traffic_hz())
-        with obs_trace.span("serve.generate", batch=b, prompt_len=s,
-                            max_new=max_new):
+        span = obs_trace.span
+        with span("serve.generate", batch=b, prompt_len=s, max_new=max_new):
             cap = s + max_new + (self.model.cfg.vision_patches or 0)
-            logits, state = bound.prefill_fn(cap)(self.params, inputs)
+            with span("serve.prefill"):
+                logits, state = bound.prefill_fn(cap)(self.params, inputs)
             key = jax.random.key(self.cfg.seed)
             out = np.zeros((b, max_new), np.int32)
             seen = [logits[:, -1]] if return_logits else None
-            tok = self._sample(logits, key, 0)
+            with span("serve.sample"):
+                tok = self._sample(logits, key, 0)
             for i in range(max_new):
-                out[:, i] = np.asarray(tok[:, 0])
+                with span("serve.token_to_host"):
+                    out[:, i] = np.asarray(tok[:, 0])
                 if i == max_new - 1:
                     break
-                logits, state = bound.decode(self.params, tok, state)
+                with span("serve.decode_step"):
+                    logits, state = bound.decode(self.params, tok, state)
                 if return_logits:
                     seen.append(logits[:, -1])
-                tok = self._sample(logits, key, i + 1)
+                with span("serve.sample"):
+                    tok = self._sample(logits, key, i + 1)
         # the histogram lives in the process-wide registry keyed by name,
         # not on the _Bound snapshot — a mid-flight swap_plan publishes a
         # new snapshot but cannot reset the latency series
@@ -142,6 +157,30 @@ class Server:
         if return_logits:
             return out, jnp.stack(seen, axis=1)
         return out
+
+    def step_hlo(self, batch: int, prompt_len: int,
+                 max_new: Optional[int] = None) -> dict:
+        """The optimized HLO text of the two programs ``generate`` runs for
+        a tokens-only request of ``batch`` x ``prompt_len``, by module name
+        (``jit_serve_prefill``, ``jit_serve_decode``).  Each instruction's
+        ``op_name`` metadata names its model region (``embed``,
+        ``attention``, ``kv_cache``, ``mlp``, ``moe``, ``norm``, ``head``):
+        the map from a trace's operation names to regions.  Compiles both
+        programs."""
+        bound = self._bound
+        max_new = max_new or self.cfg.max_new_tokens
+        cap = prompt_len + max_new + (self.model.cfg.vision_patches or 0)
+        prefill = bound.prefill_fn(cap)
+        inputs = {"tokens": jax.ShapeDtypeStruct((batch, prompt_len),
+                                                 jnp.int32)}
+        _, state = jax.eval_shape(prefill, self.params, inputs)
+        token = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+        return {
+            "jit_serve_prefill":
+                prefill.lower(self.params, inputs).compile().as_text(),
+            "jit_serve_decode": bound.decode.lower(
+                self.params, token, state).compile().as_text(),
+        }
 
     def traffic_hz(self, window_s: float = 60.0) -> float:
         """Recent request rate (requests/s over the trailing window) — feed

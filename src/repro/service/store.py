@@ -5,7 +5,7 @@ The paper's environment-adaptive framing is that code is committed once and
 the *environment* keeps adapting it — so a winning offload pattern must
 outlive the process that searched for it.  The store is a single
 ``plan_store.jsonl`` journal (the shared flock/fsync code path from
-:mod:`repro.core.journal` — the same one the measurement journals use), one
+:mod:`repro.journal` — the same one the measurement journals use), one
 record per deployed plan *version*:
 
 * the **chromosome** (``bits``) plus the gene-site region names and the
@@ -36,8 +36,9 @@ from typing import Any, Optional
 
 import os
 
-from repro.core.journal import Journal, newest_per_key
 from repro.core.offload import OffloadResult, Offloader, PlanContext
+from repro.journal import Journal, newest_per_key
+from repro.obs import trace as obs_trace
 
 __all__ = ["PlanRecord", "PlanStore", "PlanMismatchError",
            "environment_fingerprint", "env_matches", "record_from_result"]
@@ -254,7 +255,8 @@ class PlanStore:
 
     def load(self, fingerprint: str) -> Optional[PlanRecord]:
         """Newest stored version for a fingerprint, or None (cold)."""
-        hist = self.history(fingerprint)
+        with obs_trace.span("store.load"):
+            hist = self.history(fingerprint)
         return hist[-1] if hist else None
 
     # -- writes --------------------------------------------------------------
@@ -345,13 +347,15 @@ class PlanStore:
         """
         from repro.models.plan import ExecPlan
 
-        if "exec_plan" in record.payload:
-            return ExecPlan(**record.payload["exec_plan"])
-        if target is None:
-            raise ValueError(
-                "stored plan has no self-contained payload; pass the "
-                "original target (and inputs/config) to rebuild its artifact")
-        off = Offloader(config) if config is not None else Offloader()
-        ctx = off.prepare(target, inputs)
-        self.check(record, ctx)
-        return off.apply(ctx, record.bits)
+        with obs_trace.span("store.rehydrate"):
+            if "exec_plan" in record.payload:
+                return ExecPlan(**record.payload["exec_plan"])
+            if target is None:
+                raise ValueError(
+                    "stored plan has no self-contained payload; pass the "
+                    "original target (and inputs/config) to rebuild its "
+                    "artifact")
+            off = Offloader(config) if config is not None else Offloader()
+            ctx = off.prepare(target, inputs)
+            self.check(record, ctx)
+            return off.apply(ctx, record.bits)

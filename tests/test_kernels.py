@@ -146,3 +146,33 @@ def test_model_flash_custom_vjp_matches_naive(rng, causal):
     assert abs(float(o1 - o2)) < 1e-3
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# kernel names (what a profiler trace and the compiled custom call show)
+# ---------------------------------------------------------------------------
+
+
+def _pallas_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", p)
+            if hasattr(inner, "eqns"):
+                out += _pallas_names(inner)
+    return out
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "rglru_scan", "wkv6",
+                                    "rmsnorm"])
+def test_pallas_calls_carry_their_kernel_names(kernel):
+    x = jnp.ones((2, 64, 2, 32), jnp.float32)
+    calls = {
+        "flash_attention": lambda: ops.flash_attention(x, x, x),
+        "rglru_scan": lambda: ops.rglru_scan(x[:, :, 0], x[:, :, 0]),
+        "wkv6": lambda: ops.wkv6(x, x, x, -x, x[0, 0]),
+        "rmsnorm": lambda: ops.rmsnorm(x, x[0, 0, 0]),
+    }
+    assert _pallas_names(jax.make_jaxpr(calls[kernel])().jaxpr) == [kernel]

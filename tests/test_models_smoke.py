@@ -96,3 +96,35 @@ def test_input_specs_cover_model(built, arch):
     specs_d = m.input_specs(dec)
     jax.eval_shape(lambda p, t, s: m.decode(p, t, s, REFERENCE_PLAN),
                    params, specs_d["token"], specs_d["state"])
+
+
+REGIONS = {"embed", "attention", "kv_cache", "mlp", "moe", "norm", "head"}
+
+
+@pytest.mark.parametrize("plan_name", ["reference", "offload"])
+@pytest.mark.parametrize("arch", ["qwen3_0_6b", "olmoe_1b_7b"])
+def test_compiled_serve_steps_name_every_matmul_region(arch, plan_name):
+    """The served prefill and decode compile to modules named for their
+    step, and every dot or convolution in them carries a model region in
+    its ``op_name`` metadata (what the profiler trace reports as the op's
+    scope)."""
+    import re
+
+    from repro.runtime.serve import ServeConfig, Server
+
+    cfg = get_config(arch).reduced()
+    m = build_model(cfg)
+    plan = REFERENCE_PLAN if plan_name == "reference" else SMALL_OFFLOAD
+    params = m.init(jax.random.key(0))
+    hlo = Server(m, params, plan, ServeConfig(max_new_tokens=4)).step_hlo(
+        2, 16)
+    assert set(hlo) == {"jit_serve_prefill", "jit_serve_decode"}
+    for module, text in hlo.items():
+        assert text.startswith(f"HloModule {module}")
+        matmuls = [ln for ln in text.splitlines()
+                   if re.search(r"= \S+ (dot|convolution)\(", ln)]
+        assert matmuls
+        for ln in matmuls:
+            op_name = re.search(r'op_name="([^"]*)"', ln)
+            assert op_name and REGIONS & set(op_name.group(1).split("/")), \
+                (module, ln[:160])

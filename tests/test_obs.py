@@ -180,6 +180,114 @@ def test_error_inside_span_is_recorded_and_reraised(tmp_path):
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# the profiler sink: spans on the jax profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _host_events(profile_dir) -> dict:
+    """{(plane, line index): [(event name, start_ns, end_ns)]} of the host
+    planes of the one ``.xplane.pb`` under ``profile_dir`` (a line per
+    thread; threads may share a line name)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True)
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                out[plane.name, i] = [(e.name, e.start_ns, e.end_ns)
+                                      for e in line.events]
+    return out
+
+
+def _profiled(tmp_path, body) -> dict:
+    import jax
+
+    obs_trace.enable_profiler()
+    jax.profiler.start_trace(str(tmp_path / "profile"))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+        obs_trace.disable_profiler()
+    return _host_events(tmp_path / "profile")
+
+
+def test_profiler_sink_puts_nested_spans_on_host_planes(tmp_path):
+    def body():
+        with obs_trace.span("outer", ignored=1) as sp:
+            assert sp.set(more=2) is sp and sp.id is None
+            with obs_trace.span("inner"):
+                pass
+        worker = threading.Thread(target=lambda: obs_trace.span(
+            "on_worker").__enter__().__exit__(None, None, None))
+        worker.start()
+        worker.join(timeout=30)
+        assert not worker.is_alive()
+
+    lines = _profiled(tmp_path, body)
+    where = {n: (line, s, e) for line, evs in lines.items()
+             for n, s, e in evs if n in ("outer", "inner", "on_worker")}
+    assert set(where) == {"outer", "inner", "on_worker"}
+    outer, inner = where["outer"], where["inner"]
+    assert outer[0] == inner[0]                  # one thread, one line
+    assert outer[1] <= inner[1] <= inner[2] <= outer[2]
+    assert where["on_worker"][0] != outer[0]     # the worker's own line
+
+
+def test_profiler_and_jsonl_sinks_record_together(tmp_path):
+    path = str(tmp_path / "trace.jsonl")
+
+    def body():
+        obs_trace.enable(path, flush_every=1)
+        try:
+            with obs_trace.span("both") as sp:
+                with obs_trace.span("both.child") as child:
+                    assert child.parent == sp.id
+        finally:
+            obs_trace.disable()
+
+    lines = _profiled(tmp_path, body)
+    names = {n for evs in lines.values() for n, _, _ in evs}
+    assert {"both", "both.child"} <= names
+    spans, _ = obs_trace.read_trace(path)
+    assert [s["name"] for s in spans] == ["both.child", "both"]
+
+
+def test_profiler_sink_off_is_the_null_span_again():
+    obs_trace.enable_profiler()
+    assert obs_trace.span("x") is not obs_trace.NULL_SPAN
+    obs_trace.disable_profiler()
+    assert obs_trace.span("x") is obs_trace.NULL_SPAN
+    assert obs_trace.active_tracer() is None
+
+
+def test_obs_imports_no_jax_until_the_profiler_sink(tmp_path):
+    import subprocess
+    import sys
+
+    code = f"""
+import sys
+from repro.launch.obsreport import render
+from repro.obs import trace
+path = {str(tmp_path / "t.jsonl")!r}
+trace.enable(path)
+with trace.span("a"):
+    pass
+trace.disable()
+render(trace.read_trace(path)[0])
+assert "jax" not in sys.modules, "the JSONL path imported jax"
+trace.enable_profiler()
+assert "jax.profiler" in sys.modules
+"""
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert res.returncode == 0, res.stderr[-2000:]
+
+
 @pytest.mark.parametrize("name", ALL_FRONTENDS)
 def test_plan_trace_covers_phases_on_every_frontend(name, tmp_path):
     target, inputs, kwargs = FRONTEND_CASES[name]()
@@ -243,6 +351,53 @@ def test_obsreport_render_orphans_and_metrics():
     assert "orphan" in out and "k=v" in out
     assert "account for 50.0% of root wall" in out
     assert "c" in out and "counter" in out
+
+
+def test_serve_span_tree_and_its_obsreport(tmp_path):
+    """One traced ``generate`` from a stored plan: the store's spans, then
+    prefill and, per token, sample / token_to_host / decode_step under
+    ``serve.generate``; obsreport renders the tree."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import REFERENCE_PLAN, build_model
+    from repro.runtime.serve import ServeConfig, Server
+    from repro.service import PlanRecord, PlanStore
+
+    cfg = get_config("qwen3_0_6b").reduced()
+    model = build_model(cfg)
+    store = PlanStore(str(tmp_path / "plans"))
+    store.put(PlanRecord(
+        fingerprint="fp", frontend="module", version=0, bits=(), sites=(),
+        destinations=(), pattern={}, best_time_s=1.0, baseline_time_s=1.0,
+        verified=True,
+        payload={"exec_plan": {
+            k: v for k, v in dataclasses.asdict(REFERENCE_PLAN).items()
+            if isinstance(v, (str, int, float, bool))}}))
+    path = str(tmp_path / "serve.jsonl")
+    new = 3
+    with obs_trace.maybe_tracing(path):
+        server = Server.from_store(model, model.init(jax.random.key(0)),
+                                   store, "fp",
+                                   ServeConfig(max_new_tokens=new))
+        server.generate({"tokens": jax.numpy.ones((2, 8), "int32")})
+    spans, _ = obs_trace.read_trace(path)
+    names = [s["name"] for s in spans]
+    assert names.count("store.load") == 1
+    assert names.count("store.rehydrate") == 1
+    (gen,) = [s for s in spans if s["name"] == "serve.generate"]
+    kids = sorted((s for s in spans if s["parent"] == gen["id"]),
+                  key=lambda s: s["t0"])
+    assert [s["name"] for s in kids] == (
+        ["serve.prefill", "serve.sample"]
+        + ["serve.token_to_host", "serve.decode_step", "serve.sample"]
+        * (new - 1) + ["serve.token_to_host"])
+    out = render(spans)
+    for name in ("serve.generate", "serve.prefill", "serve.decode_step",
+                 "serve.sample", "serve.token_to_host", "store.load"):
+        assert name in out
 
 
 # ---------------------------------------------------------------------------
