@@ -30,8 +30,11 @@ NEG_INF = -1e30
 
 
 class KVCache(NamedTuple):
-    k: Array  # (B, S_cache, Hkv, D)
-    v: Array  # (B, S_cache, Hkv, D)
+    """One layer's decode cache, head-major: the order the decode
+    contraction reads, so a step reads it in place.  Stacked over layers it
+    is (L, B, Hkv, S_cache, D)."""
+    k: Array  # (B, Hkv, S_cache, D)
+    v: Array  # (B, Hkv, S_cache, D)
 
 
 # ---------------------------------------------------------------------------
@@ -130,17 +133,25 @@ def _repeat_kv(k: Array, group: int) -> Array:
 
 
 def cache_axes(n_kv_heads: int) -> tuple:
-    """Logical axes for a (B, Sc, Hkv, D) KV-cache entry: heads over "model"
+    """Logical axes for a (B, Hkv, Sc, D) KV-cache entry: heads over "model"
     when divisible, else the cache sequence dim (matches
     runtime.sharding._axes_for_state so prefill output needs no reshard)."""
     from repro.runtime.pspec import current_rules
     rules = current_rules()
     if rules is None:
-        return ("batch", None, "kv_heads", None)
+        return ("batch", "kv_heads", None, None)
     msize = rules.mesh.shape.get("model", 1)
     if n_kv_heads % msize == 0:
-        return ("batch", None, "kv_heads", None)
-    return ("batch", "kv_seq", None, None)
+        return ("batch", "kv_heads", None, None)
+    return ("batch", None, "kv_seq", None)
+
+
+def to_cache(x: Array, capacity: int) -> Array:
+    """A prefill's (B, S, Hkv, D) keys or values as a head-major cache
+    entry (B, Hkv, capacity, D), zero past S."""
+    x = jnp.pad(x, ((0, 0), (0, capacity - x.shape[1]), (0, 0), (0, 0)))
+    x = x.transpose(0, 2, 1, 3)
+    return constrain(x, *cache_axes(x.shape[1]))
 
 
 def _score_axes(n_heads: int) -> tuple:
@@ -433,43 +444,69 @@ def attend_local_banded(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array
 # ---------------------------------------------------------------------------
 
 
-def attend_decode(q1: Array, cache: KVCache, cache_len: Array,
-                  window: int, plan: ExecPlan, ring: bool) -> Array:
-    """q1: (B,1,Hq,D); cache.k/v: (B,Sc,Hkv,D).  Returns (B,1,Hq,D).
-
-    ``ring`` means the cache is a ring buffer of size `window` (local attn);
-    otherwise it is a linear buffer with `cache_len` valid entries.
-    """
-    b, _, hq, hd = q1.shape
-    sc, nkv = cache.k.shape[1], cache.k.shape[2]
-    qg = _group(q1, nkv)[:, 0]  # (B,Hkv,G,D)
-    scale = 1.0 / np.sqrt(hd)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qg, cache.k,
-                   preferred_element_type=jnp.float32) * scale
+def decode_valid(sc: int, cache_len: Array, ring: bool) -> Array:
+    """(Sc,) mask of the cache slots a new token at position ``cache_len``
+    attends besides itself.  A ring (local attention, Sc = window) holds the
+    min(cache_len, Sc - 1) most recent tokens, never the slot the new token
+    overwrites; a linear cache holds ``cache_len`` tokens."""
     idx = jnp.arange(sc)
     if ring:
-        # valid entries: the min(cache_len, window) most recent slots
-        age = (cache_len - 1 - idx) % sc  # 0 = newest
-        valid = age < jnp.minimum(cache_len, sc)
-    else:
-        valid = idx < cache_len
-        if window > 0:
-            valid &= idx > cache_len - 1 - window
-    s = jnp.where(valid[None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1).astype(L.cdtype(plan))
-    out = jnp.einsum("bhgk,bkhd->bhgd", p, cache.v)
-    return out.reshape(b, 1, hq, hd)
+        return (cache_len - 1 - idx) % sc < jnp.minimum(cache_len, sc - 1)
+    return idx < cache_len
 
 
-def cache_update(cache: KVCache, k1: Array, v1: Array, cache_len: Array,
-                 ring: bool) -> KVCache:
-    """Insert one token's k/v at the right slot (ring or linear)."""
+def attend_decode(q1: Array, cache: KVCache, valid: Optional[Array],
+                  plan: ExecPlan, k1: Optional[Array] = None,
+                  v1: Optional[Array] = None) -> Array:
+    """One query token against a head-major cache that it only reads.
+
+    q1: (B,1,Hq,D); cache.k/v: (B,Hkv,Sc,D); ``valid``: (Sc,) mask, or None
+    for every slot.  The new token's own k1/v1 (B,1,Hkv,D), when given, join
+    the same softmax as one more key: scores over the cache and against k1,
+    softmax, cast, then ``p_cache @ V_cache + p_new * v1``.  Returns
+    (B,1,Hq,D)."""
+    b, _, hq, hd = q1.shape
+    nkv = cache.k.shape[1]
+    qg = _group(q1, nkv)[:, 0]  # (B,Hkv,G,D)
+    scale = 1.0 / np.sqrt(hd)
+    s = jnp.einsum("bhgd,bhkd->bhgk", qg, cache.k,
+                   preferred_element_type=jnp.float32) * scale
+    if valid is not None:
+        s = jnp.where(valid[None, None, None], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if k1 is not None:
+        s_new = jnp.einsum("bhgd,bhd->bhg", qg, k1[:, 0],
+                           preferred_element_type=jnp.float32)[..., None] * scale
+        m = jnp.maximum(m, s_new)
+    e = jnp.exp(s - m)
+    total = jnp.sum(e, axis=-1, keepdims=True)
+    if k1 is not None:
+        e_new = jnp.exp(s_new - m)
+        total = total + e_new
+    dt = L.cdtype(plan)
+    out = jnp.einsum("bhgk,bhkd->bhgd", (e / total).astype(dt), cache.v,
+                     preferred_element_type=jnp.float32)
+    if v1 is not None:
+        p_new = (e_new / total).astype(dt).astype(jnp.float32)
+        out = out + p_new * v1[:, 0, :, None].astype(jnp.float32)
+    return out.astype(dt).reshape(b, 1, hq, hd)
+
+
+def write_tokens(stack: dict, new: dict, cache_len: Array, ring: bool) -> dict:
+    """Write one token's k/v per layer into the stacked caches in place.
+
+    ``stack``: name -> (L,B,Hkv,Sc,D); ``new``: name -> (L,B,Hkv,1,D), as a
+    decode scan emits them.  The slot is ``cache_len``, or ``cache_len % Sc``
+    for a ring.  One dynamic_update_slice per leaf: on a donated state it
+    touches only the token's slot."""
     with jax.named_scope("kv_cache"):
-        sc = cache.k.shape[1]
-        slot = (cache_len % sc) if ring else cache_len
-        k = jax.lax.dynamic_update_slice_in_dim(cache.k, k1, slot, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(cache.v, v1, slot, axis=1)
-        return KVCache(k, v)
+        out = {}
+        for name, x in new.items():
+            sc = stack[name].shape[3]
+            slot = (cache_len % sc) if ring else cache_len
+            out[name] = jax.lax.dynamic_update_slice_in_dim(
+                stack[name], x, slot, axis=3)
+        return out
 
 
 # ---------------------------------------------------------------------------

@@ -161,21 +161,15 @@ def _attn_sublayer_full(x, p_attn, ln, cfg: ArchConfig, plan: ExecPlan,
 
 
 def _prefill_cache(k, v, cfg: ArchConfig, s: int, cache_capacity: int):
-    """The decode cache a prefill of ``s`` tokens leaves: the last window
-    as a ring (local attention), else k/v padded to ``cache_capacity``."""
+    """The head-major decode cache a prefill of ``s`` tokens leaves: the
+    last window as a ring (local attention), else k/v padded to
+    ``cache_capacity``."""
     if cfg.attn_kind == "local":
         w = cfg.local_window
-        kc = k[:, -w:]
-        vc = v[:, -w:]
-        # ring layout: slot = position % window
-        roll = (s % w) - w
-        kc = jnp.roll(kc, roll, axis=1) if s >= w else jnp.pad(k, ((0, 0), (0, w - s), (0, 0), (0, 0)))
-        vc = jnp.roll(vc, roll, axis=1) if s >= w else jnp.pad(v, ((0, 0), (0, w - s), (0, 0), (0, 0)))
-        return kc, vc
-    pad = cache_capacity - s
-    cax = A.cache_axes(cfg.n_kv_heads)
-    return (constrain(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax),
-            constrain(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax))
+        if s >= w:  # ring layout: slot = position % window
+            k, v = (jnp.roll(x[:, -w:], (s % w) - w, axis=1) for x in (k, v))
+        return A.to_cache(k, w), A.to_cache(v, w)
+    return A.to_cache(k, cache_capacity), A.to_cache(v, cache_capacity)
 
 
 def _mlp_sublayer_full(x, blk, cfg: ArchConfig, plan: ExecPlan):
@@ -408,20 +402,20 @@ def prefill(params: dict, cfg: ArchConfig, plan: ExecPlan, tokens: Array,
 
 
 def _dense_block_decode(x1, blk, kv, cache_len, cfg, plan):
+    """One layer's step against its cache ``kv`` (head-major, read only).
+    Returns the new hidden state and the token's own k/v, (B,Hkv,1,D)."""
     h = L.rmsnorm(x1, blk["ln1"], cfg.norm_eps, plan)
     with jax.named_scope("attention"):
         pos = cache_len[None].astype(jnp.int32)
         q, k, v = A.project_qkv(h, blk["attn"], cfg, plan, pos)
-        ring = cfg.attn_kind == "local"
-        cache = A.cache_update(A.KVCache(kv["k"], kv["v"]), k, v, cache_len,
-                               ring)
-        o = A.attend_decode(q, cache, cache_len + 1,
-                            cfg.local_window if ring else 0, plan, ring)
+        valid = A.decode_valid(kv["k"].shape[2], cache_len,
+                               cfg.attn_kind == "local")
+        o = A.attend_decode(q, A.KVCache(kv["k"], kv["v"]), valid, plan, k, v)
         o = o.reshape(x1.shape[0], 1, -1) \
             @ blk["attn"]["wo"].astype(L.cdtype(plan))
     x1 = x1 + o
     x1, _ = _mlp_sublayer_full(x1, blk, cfg, plan)
-    return x1, {"k": cache.k, "v": cache.v}
+    return x1, {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
 
 
 def _rglru_sublayer_decode(x1, sub, st, cfg, plan):
@@ -452,12 +446,16 @@ def decode_step(params: dict, cfg: ArchConfig, plan: ExecPlan, token: Array,
                 state: dict) -> tuple[Array, dict]:
     """token: (B,1) int32.  Returns (logits (B,1,V), new state).
 
-    The stacked per-layer caches travel as scan CARRIES (indexed and
-    written back per layer) instead of xs/ys: with input donation the
-    while loop updates them in place — one cache-sized buffer live instead
-    of three (measured: gemma decode_32k 34.8 GB -> fits).
+    The stacked KV caches enter the layer scan as read-only ``xs``: each
+    layer's attention reads its cache once, in place.  The new token's k/v
+    leave the scan as token-sized ``ys``, and one ``A.write_tokens`` after
+    the scan puts them in their slot of the donated stack, so one
+    cache-sized buffer is live and a step writes only the token.  The small
+    recurrent states (RG-LRU, RWKV), replaced whole every step, travel as
+    carries indexed and written back per layer.
     """
     cache_len = state["cache_len"]
+    ring = cfg.attn_kind == "local"
     x1 = embed_inputs(params, cfg, plan, token, None)
     new_state: dict = {"cache_len": cache_len + 1}
 
@@ -486,13 +484,12 @@ def decode_step(params: dict, cfg: ArchConfig, plan: ExecPlan, token: Array,
 
         n_macro = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
 
-        def body(carry, blk_i):
-            h, rg_all, kv_all = carry
-            blk, i = blk_i
+        def body(carry, xs):
+            h, rg_all = carry
+            blk, i, kv = xs
             rg_st = _tree_index(rg_all, i)
-            kv = _tree_index(kv_all, i)
             new_rg: dict = {}
-            new_kv = kv
+            new_kv = None
             for j, kind in enumerate(cfg.block_pattern):
                 sub = blk[f"sub{j}"]
                 if kind == "rglru":
@@ -500,25 +497,19 @@ def decode_step(params: dict, cfg: ArchConfig, plan: ExecPlan, token: Array,
                         h, sub, rg_st[f"rglru{j}"], cfg, plan)
                 else:
                     h, new_kv = _dense_block_decode(h, sub, kv, cache_len, cfg, plan)
-            return (h, _tree_update(rg_all, new_rg, i),
-                    _tree_update(kv_all, new_kv, i)), None
-        (x1, rg_sts, kv_sts), _ = jax.lax.scan(
-            body, (x1, state["macro_rglru"], state["macro_kv"]),
-            (params["blocks"], jnp.arange(n_macro)))
+            return (h, _tree_update(rg_all, new_rg, i)), new_kv
+        (x1, rg_sts), new_kv = jax.lax.scan(
+            body, (x1, state["macro_rglru"]),
+            (params["blocks"], jnp.arange(n_macro), state["macro_kv"]))
         new_state["macro_rglru"] = rg_sts
-        new_state["macro_kv"] = kv_sts
+        new_state["macro_kv"] = A.write_tokens(state["macro_kv"], new_kv,
+                                               cache_len, ring)
     else:
-        n_layers = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
-
-        def body(carry, blk_i):
-            h, kv_all = carry
-            blk, i = blk_i
-            kv = _tree_index(kv_all, i)
-            h, new_kv = _dense_block_decode(h, blk, kv, cache_len, cfg, plan)
-            return (h, _tree_update(kv_all, new_kv, i)), None
-        (x1, kv_sts), _ = jax.lax.scan(
-            body, (x1, state["kv"]), (params["blocks"], jnp.arange(n_layers)))
-        new_state["kv"] = kv_sts
+        def body(h, xs):
+            blk, kv = xs
+            return _dense_block_decode(h, blk, kv, cache_len, cfg, plan)
+        x1, new_kv = jax.lax.scan(body, x1, (params["blocks"], state["kv"]))
+        new_state["kv"] = A.write_tokens(state["kv"], new_kv, cache_len, ring)
 
     logits = lm_logits(params, cfg, plan, x1)
     return logits, new_state

@@ -120,14 +120,10 @@ def _dec_block_full(x, blk, enc_out, cfg, plan, positions, want_cache, cache_cap
     x = x + L.mlp(h2, blk["mlp"], cfg.mlp_act, plan)
     cache = None
     if want_cache:
-        pad = cache_capacity - s
-        cax = A.cache_axes(cfg.n_kv_heads)
-        cache = {
-            "k": constrain(jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax),
-            "v": constrain(jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))), *cax),
-            "xk": constrain(kx, *cax),
-            "xv": constrain(vx, *cax),
-        }
+        t_enc = enc_out.shape[1]
+        cache = {"k": A.to_cache(k, cache_capacity),
+                 "v": A.to_cache(v, cache_capacity),
+                 "xk": A.to_cache(kx, t_enc), "xv": A.to_cache(vx, t_enc)}
     return x, cache
 
 
@@ -182,37 +178,37 @@ def prefill(params: dict, cfg: ArchConfig, plan: ExecPlan, tokens: Array,
 
 def decode_step(params: dict, cfg: ArchConfig, plan: ExecPlan, token: Array,
                 state: dict):
+    """The decoder's step, on the cache scheme of ``transformer.decode_step``:
+    the self-attention and cross-attention caches are read-only scan ``xs``;
+    the token's k/v come out as ``ys`` and are written once after the scan.
+    The cross-attention cache (``xk``/``xv``) is passed through unchanged."""
     dt = L.cdtype(plan)
     cache_len = state["cache_len"]
     b = token.shape[0]
     x1 = L.embed_tokens(token, params["embed"], plan, False)
     x1 = x1 + sinusoid_positions(1, cfg.d_model, offset=cache_len).astype(dt)
-    from repro.models.transformer import _tree_index, _tree_update
-    n_layers = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+    pos = cache_len[None].astype(jnp.int32)
 
-    def body(carry, blk_i):
-        x, caches = carry
-        blk, i = blk_i
-        kv = _tree_index(caches, i)
-        pos = cache_len[None].astype(jnp.int32)
+    def body(x, xs):
+        blk, kv = xs
         h = L.rmsnorm(x, blk["ln1"], cfg.norm_eps, plan)
-        q, k, v = A.project_qkv(h, blk["attn"], cfg, plan, pos)
-        cache = A.cache_update(A.KVCache(kv["k"], kv["v"]), k, v, cache_len, False)
-        o = A.attend_decode(q, cache, cache_len + 1, 0, plan, False)
-        x = x + (o.reshape(b, 1, -1) @ blk["attn"]["wo"].astype(dt))
+        with jax.named_scope("attention"):
+            q, k, v = A.project_qkv(h, blk["attn"], cfg, plan, pos)
+            valid = A.decode_valid(kv["k"].shape[2], cache_len, False)
+            o = A.attend_decode(q, A.KVCache(kv["k"], kv["v"]), valid, plan, k, v)
+            x = x + (o.reshape(b, 1, -1) @ blk["attn"]["wo"].astype(dt))
         hx = L.rmsnorm(x, blk["ln_x"], cfg.norm_eps, plan)
-        qx = A.project_q(hx, blk["xattn"], cfg, plan, pos)
-        xcache = A.KVCache(kv["xk"], kv["xv"])
-        ox = A.attend_decode(qx, xcache, jnp.asarray(kv["xk"].shape[1], jnp.int32),
-                             0, plan, False)
-        x = x + (ox.reshape(b, 1, -1) @ blk["xattn"]["wo"].astype(dt))
+        with jax.named_scope("attention"):
+            qx = A.project_q(hx, blk["xattn"], cfg, plan, pos)
+            ox = A.attend_decode(qx, A.KVCache(kv["xk"], kv["xv"]), None, plan)
+            x = x + (ox.reshape(b, 1, -1) @ blk["xattn"]["wo"].astype(dt))
         h2 = L.rmsnorm(x, blk["ln2"], cfg.norm_eps, plan)
         x = x + L.mlp(h2, blk["mlp"], cfg.mlp_act, plan)
-        new_kv = {"k": cache.k, "v": cache.v, "xk": kv["xk"], "xv": kv["xv"]}
-        return (x, _tree_update(caches, new_kv, i)), None
+        return x, {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
 
-    (x1, caches), _ = jax.lax.scan(
-        body, (x1, state["dec"]), (params["blocks"], jnp.arange(n_layers)))
+    dec = state["dec"]
+    x1, new_kv = jax.lax.scan(body, x1, (params["blocks"], dec))
     h = L.rmsnorm(x1, params["final_norm"], cfg.norm_eps, plan)
     logits = L.logits_from_hidden(h, params["embed"], plan, 0.0)
-    return logits, {"dec": caches, "cache_len": cache_len + 1}
+    dec = {**dec, **A.write_tokens(dec, new_kv, cache_len, False)}
+    return logits, {"dec": dec, "cache_len": cache_len + 1}

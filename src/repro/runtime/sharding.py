@@ -145,13 +145,13 @@ def _axes_for_state(path: str, shape: tuple, cfg: ArchConfig, mesh: Mesh) -> tup
     if path.endswith("cache_len"):
         return ()
     if re.search(r"(^|/)(k|v|xk|xv)$", path):
-        # (L, B, S, Hkv, D) or (B, S, Hkv, D)
-        hkv, s = shape[-2], shape[-3]
+        # head-major (L, B, Hkv, S, D) or (B, Hkv, S, D)
+        hkv, s = shape[-3], shape[-2]
         lead = (None,) * (ndim - 4)
         if hkv % model == 0:
-            return lead + ("batch", None, "kv_heads", None)
+            return lead + ("batch", "kv_heads", None, None)
         if s % model == 0:
-            return lead + ("batch", "kv_seq", None, None)
+            return lead + ("batch", None, "kv_seq", None)
         return lead + ("batch", None, None, None)
     if path.endswith("wkv"):  # (L,B,H,Dk,Dv)
         h = shape[-3]

@@ -83,6 +83,56 @@ def test_decode_matches_full_forward(built, arch):
     assert int(state2["cache_len"]) == int(state["cache_len"]) + 1
 
 
+@pytest.mark.parametrize("arch", ["qwen3_0_6b", "olmoe_1b_7b",
+                                  "recurrentgemma_2b", "whisper_small",
+                                  "llava_next_mistral_7b"])
+def test_multistep_decode_matches_full_forward(built, arch):
+    """Six decode steps after a prefill: each step's logits match the full
+    forward at its position, and every cache slot that no step wrote keeps
+    what the prefill left there (the cross-attention cache, all of it).  The
+    hybrid's prompt ends three slots before its ring's end, so the steps
+    wrap the ring."""
+    cfg, m, params, _ = built[arch]
+    steps = 6
+    s = 2 * cfg.local_window - 3 if cfg.family == "hybrid" else 24
+    batch = m.demo_batch(jax.random.key(3), 2,
+                         s + steps + (cfg.vision_patches or 0))
+    toks = batch["tokens"]
+    inputs = {k: v for k, v in batch.items() if k != "labels"}
+    cap = toks.shape[1] + (cfg.vision_patches or 0) + 4
+    _, first = m.prefill(params, {**inputs, "tokens": toks[:, :s]},
+                         REFERENCE_PLAN, cache_capacity=cap)
+    decode = jax.jit(lambda p, t, st: m.decode(p, t, st, REFERENCE_PLAN))
+    state, written = first, []
+    for i in range(steps):
+        written.append(int(state["cache_len"]))
+        lg_step, state = decode(params, toks[:, s + i:s + i + 1], state)
+        lg_full, _ = m.prefill(params, {**inputs, "tokens": toks[:, :s + i + 1]},
+                               REFERENCE_PLAN)
+        d = float(jnp.max(jnp.abs(lg_step.astype(jnp.float32)
+                                  - lg_full.astype(jnp.float32))))
+        assert d < 2e-2, (i, d)
+    assert int(state["cache_len"]) == written[0] + steps
+
+    ring = cfg.attn_kind == "local"
+    after = dict(jax.tree_util.tree_flatten_with_path(state)[0])
+    caches = 0
+    for path, before in jax.tree_util.tree_flatten_with_path(first)[0]:
+        name = path[-1].key
+        if name not in ("k", "v", "xk", "xv"):
+            continue
+        caches += 1
+        sc = before.shape[3]                      # (L, B, Hkv, Sc, D)
+        hit = set() if name in ("xk", "xv") else \
+            {p % sc if ring else p for p in written}
+        if not ring:
+            assert all(p >= written[0] for p in hit)
+        keep = np.array([j for j in range(sc) if j not in hit])
+        np.testing.assert_array_equal(np.asarray(after[path])[:, :, :, keep],
+                                      np.asarray(before)[:, :, :, keep])
+    assert caches == (4 if cfg.family == "encdec" else 2)
+
+
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_input_specs_cover_model(built, arch):
     """input_specs must be sufficient to trace every step kind (this is what

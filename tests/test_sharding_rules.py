@@ -83,8 +83,8 @@ def test_state_axes_kv_fallback():
         def __contains__(self, x):
             return x in self.shape
     cfg = get_config("tinyllama_1_1b")  # kv=4: not divisible -> shard seq
-    ax = shd._axes_for_state("kv/k", (22, 2, 32768, 4, 64), cfg, M())  # type: ignore
-    assert ax == (None, "batch", "kv_seq", None, None)
+    ax = shd._axes_for_state("kv/k", (22, 2, 4, 32768, 64), cfg, M())  # type: ignore
+    assert ax == (None, "batch", None, "kv_seq", None)
     cfg2 = get_config("gemma_7b")  # kv=16: divisible -> shard heads
-    ax2 = shd._axes_for_state("kv/k", (28, 2, 32768, 16, 256), cfg2, M())  # type: ignore
-    assert ax2 == (None, "batch", None, "kv_heads", None)
+    ax2 = shd._axes_for_state("kv/k", (28, 2, 16, 32768, 256), cfg2, M())  # type: ignore
+    assert ax2 == (None, "batch", "kv_heads", None, None)

@@ -8,6 +8,7 @@ described chip cannot be read back without one.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,7 +83,7 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _qwen3_serving_programs(one_chip, plan, batch=4, prompt=128, new=32):
+def _qwen3_serving_lowered(one_chip, plan, batch, prompt, new):
     cfg = get_config("qwen3_0_6b")
     model = build_model(cfg)
     on = functools.partial(jax.tree_util.tree_map,
@@ -96,8 +97,12 @@ def _qwen3_serving_programs(one_chip, plan, batch=4, prompt=128, new=32):
     decode = jax.jit(lambda p, tok, st: model.decode(p, tok, st, plan),
                      donate_argnums=(2,))
     token = _spec(one_chip, (batch, 1), jnp.int32)
-    return (prefill.lower(params, tokens).compile(),
-            decode.lower(params, token, state).compile())
+    return (prefill.lower(params, tokens), decode.lower(params, token, state))
+
+
+def _qwen3_serving_programs(one_chip, plan, batch=4, prompt=128, new=32):
+    return tuple(low.compile() for low in
+                 _qwen3_serving_lowered(one_chip, plan, batch, prompt, new))
 
 
 @pytest.mark.parametrize("plan_name", ["reference", "offload"])
@@ -111,3 +116,49 @@ def test_qwen3_serving_fits_one_v5e(one_chip, plan_name):
                 + mem.generated_code_size_in_bytes)
         # bf16 weights alone are ~1.2 GB; the program must fit the chip
         assert 1e9 < live < hbm, live
+
+
+def _loop_bodies(hlo: str) -> dict:
+    """Instruction lines of each while loop's body computation, by name."""
+    names = {b.lstrip("%") for b in re.findall(r"body=%?([\w.\-]+)", hlo)}
+    out = {}
+    for comp in re.split(r"\n(?=\S)", hlo):
+        head = comp.split(" ", 1)[0].lstrip("%")
+        if head in names:
+            out[head] = comp.splitlines()[1:]
+    return out
+
+
+def test_qwen3_decode_reads_each_cache_once(one_chip):
+    """Full-width qwen3_0_6b decode at batch 32 and cache capacity 640 (the
+    long bucket of the decode cell).  Attention reads each layer's cache in
+    place and the token is written once after the layer loop, so nothing in
+    the loop's body makes a cache-layer-sized or stacked-cache-sized array
+    (a carried cache took a slice, a relayout and a write-back of the whole
+    stack per layer).  XLA's bytes accessed, the body counted once, read
+    0.99 GB with the carried cache and must stay under 0.6 GB; scratch
+    memory must not exceed the 42,297,856 bytes it needed then."""
+    _, low = _qwen3_serving_lowered(one_chip, REFERENCE_PLAN, batch=32,
+                                    prompt=512, new=128)
+    compiled = low.compile()
+    cfg = get_config("qwen3_0_6b")
+    layer = sorted((32, cfg.n_kv_heads, 640, cfg.resolved_head_dim))
+    shapes = (layer, sorted(layer + [cfg.n_layers]))
+    aliases = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+    bodies = _loop_bodies(compiled.as_text())
+    assert bodies
+    made = []
+    for name, lines in bodies.items():
+        for ln in lines:
+            m = re.match(r"\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                         ln)
+            if not m or m.group(3) in aliases:
+                continue
+            dims = sorted(int(d) for d in m.group(2).split(",") if d not in ("", "1"))
+            if dims in shapes:
+                made.append((name, m.group(1), m.group(3)))
+    assert not made, made
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 0.6e9, cost["bytes accessed"]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 42_297_856
