@@ -56,6 +56,28 @@ class MoEConfig:
     n_shared_experts: int = 0     # llama4-style shared expert (always-on)
     router_z_loss: float = 1e-3
     aux_loss: float = 1e-2
+    norm_topk: bool = True        # renormalise the top-k gates to sum 1
+    # expert parallelism: this chip holds routed experts
+    # [held_first, held_first + held_count) of n_experts (0 = all of them)
+    held_first: int = 0
+    held_count: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held_count or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling (arXiv:2309.00071), as DeepSeek-V2 configures it,
+    with its ``mscale`` equal to ``mscale_all_dim``: cos and sin keep their
+    scale and the softmax scale takes ``yarn_mscale(factor, mscale)**2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
 
 
 @dataclass(frozen=True)
@@ -75,12 +97,19 @@ class ArchConfig:
     vocab: int = 0
 
     # attention flavour ------------------------------------------------------
-    attn_kind: str = "full"       # full | local | none (pure recurrence)
+    attn_kind: str = "full"       # full | local | mla | none (pure recurrence)
     local_window: int = 2048      # for attn_kind == "local"
     qk_norm: bool = False         # qwen3-style RMSNorm on q and k
     qkv_bias: bool = False        # qwen1.5-style bias on qkv projections
     rope_theta: float = 10_000.0
+    rope_yarn: Optional[YarnRope] = None
     logit_softcap: float = 0.0    # gemma-style final-logit softcap (0 = off)
+
+    # latent attention (attn_kind == "mla", DeepSeek-V2 without q LoRA) -------
+    kv_lora_rank: int = 0         # width of the cached latent
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0     # the rotary key, shared by every head
+    v_head_dim: int = 0
 
     # MLP flavour --------------------------------------------------------------
     mlp_act: str = "silu"         # silu (SwiGLU) | gelu (GeGLU)
@@ -88,6 +117,7 @@ class ArchConfig:
     # MoE ----------------------------------------------------------------------
     moe: Optional[MoEConfig] = None
     moe_every: int = 1            # MoE in every k-th layer (1 = all layers)
+    n_dense_layers: int = 0       # leading dense layers (d_ff) before the MoE ones
 
     # hybrid / recurrent -----------------------------------------------------
     block_pattern: tuple[str, ...] = ()   # e.g. ("rglru","rglru","local_attn")
@@ -148,6 +178,12 @@ class ArchConfig:
         embed = self.vocab * d * (1 if self.tie_embeddings else 2)
 
         def attn_params() -> int:
+            if self.attn_kind == "mla":
+                h, r = self.n_heads, self.kv_lora_rank
+                qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                return (d * h * qk + d * (r + self.qk_rope_head_dim) + r
+                        + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                        + h * self.v_head_dim * d)
             bias = (n_q + 2 * n_kv) if self.qkv_bias else 0
             return d * n_q + 2 * d * n_kv + n_q * d + bias
 
@@ -181,11 +217,14 @@ class ArchConfig:
                 continue
             total += attn_params()
             active += attn_params()
-            if self.moe is not None and (li % self.moe_every == 0):
+            if self.moe is not None and li >= self.n_dense_layers \
+                    and (li % self.moe_every == 0):
                 e = self.moe
                 per_exp = dense_mlp(e.d_ff_expert or self.d_ff)
-                total += e.n_experts * per_exp + d * e.n_experts
-                active += (e.top_k + e.n_shared_experts) * per_exp + d * e.n_experts
+                total += e.n_held * per_exp + d * e.n_experts
+                # a held share serves top_k * held / routed experts a token
+                active += (e.top_k * per_exp * e.n_held // e.n_experts
+                           + e.n_shared_experts * per_exp + d * e.n_experts)
                 if e.n_shared_experts:
                     total += e.n_shared_experts * per_exp
             else:
@@ -219,9 +258,18 @@ class ArchConfig:
             vocab=256,
         )
         if self.moe is not None:
+            # a held share stays a share: 2 of 8 routed experts
+            held = 0 < self.moe.held_count < self.moe.n_experts
             kw["moe"] = dataclasses.replace(
-                self.moe, n_experts=4, top_k=min(self.moe.top_k, 2), d_ff_expert=64
-            )
+                self.moe, n_experts=8 if held else 4,
+                top_k=min(self.moe.top_k, 2), d_ff_expert=64,
+                held_first=2 if held else 0, held_count=2 if held else 0)
+        if self.n_dense_layers:
+            kw["n_dense_layers"] = 1
+            kw["n_layers"] = min(self.n_layers, 3)
+        if self.attn_kind == "mla":
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                      v_head_dim=16)
         if self.block_pattern:
             kw["n_layers"] = len(self.block_pattern)
         if self.d_rnn:
@@ -253,6 +301,7 @@ ARCH_IDS: tuple[str, ...] = (
     "recurrentgemma_2b",
     "llava_next_mistral_7b",
     "rwkv6_3b",
+    "deepseek_v2_lite",
 )
 
 _ALIASES = {
@@ -266,6 +315,7 @@ _ALIASES = {
     "recurrentgemma-2b": "recurrentgemma_2b",
     "llava-next-mistral-7b": "llava_next_mistral_7b",
     "rwkv6-3b": "rwkv6_3b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -174,7 +174,8 @@ def _score_axes(n_heads: int) -> tuple:
 
 
 def attend_naive(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,
-                 causal: bool, window: int, plan: ExecPlan) -> Array:
+                 causal: bool, window: int, plan: ExecPlan,
+                 scale: Optional[float] = None) -> Array:
     b, sq, hq, hd = q.shape
     nkv = k.shape[2]
     ax = _score_axes(hq)
@@ -184,7 +185,7 @@ def attend_naive(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,
     qh = constrain(q.transpose(0, 2, 1, 3), ax[0], ax[1], ax[2], None)
     kh = _repeat_kv(k, hq // nkv).transpose(0, 2, 1, 3)
     vh = _repeat_kv(v, hq // nkv).transpose(0, 2, 1, 3)
-    scale = 1.0 / np.sqrt(hd)
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     scores = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
                         preferred_element_type=jnp.float32) * scale
     scores = constrain(scores, ax[0], ax[1], ax[2], None)
@@ -224,25 +225,34 @@ def _chunk_kv(x: Array, ck: int) -> Array:
     return x.reshape(bh, sk // ck, ck, d).transpose(1, 0, 2, 3)   # (n,BH,ck,D)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q: Array, k: Array, v: Array, causal: bool, window: int,
-           ck: int, out_dtype, sk_valid: int) -> Array:
-    """Flattened-head flash attention.  q: (BH, Sq, D); k/v: (BH, Sk, D)
-    (equal heads — GQA repeat outside).  Sk must be a multiple of ck (padded
-    by the caller; sk_valid = true length).  Runs LOCALLY under shard_map —
-    no sharding constraints inside."""
-    out, _ = _flash_fwd(q, k, v, causal, window, ck, out_dtype, sk_valid)
+           ck: int, out_dtype, sk_valid: int, scale: float,
+           q_offset: int) -> Array:
+    """Flattened-head flash attention.  q: (BH, Sq, D); k: (BH, Sk, D);
+    v: (BH, Sk, Dv) (equal heads — GQA repeat outside).  Sk must be a
+    multiple of ck (padded by the caller; sk_valid = true length); query i
+    sits at position ``q_offset + i``.  Runs LOCALLY under shard_map — no
+    sharding constraints inside."""
+    out, _ = _flash_fwd(q, k, v, causal, window, ck, out_dtype, sk_valid,
+                        scale, q_offset)
     return out
 
 
-def _flash_fwd(q, k, v, causal, window, ck, out_dtype, sk_valid):
-    bh, sq, hd = q.shape
-    sk = k.shape[1]
+def _positions(sq: int, sk: int, q_offset: int):
     pos_q = jnp.arange(sq, dtype=jnp.int32)
-    pos_k = jnp.arange(sk, dtype=jnp.int32)
+    if q_offset:
+        pos_q = pos_q + q_offset
+    return pos_q, jnp.arange(sk, dtype=jnp.int32)
+
+
+def _flash_fwd(q, k, v, causal, window, ck, out_dtype, sk_valid, scale,
+               q_offset):
+    bh, sq, hd = q.shape
+    sk, vd = k.shape[1], v.shape[-1]
+    pos_q, pos_k = _positions(sq, sk, q_offset)
     kc, vc = _chunk_kv(k, ck), _chunk_kv(v, ck)
     pkc = pos_k.reshape(-1, ck)
-    scale = 1.0 / np.sqrt(hd)
 
     def body(carry, chunk):
         m, l, acc = carry
@@ -261,22 +271,21 @@ def _flash_fwd(q, k, v, causal, window, ck, out_dtype, sk_valid):
 
     init = (jnp.full((bh, sq), NEG_INF, jnp.float32),
             jnp.zeros((bh, sq), jnp.float32),
-            jnp.zeros((bh, sq, hd), jnp.float32))
+            jnp.zeros((bh, sq, vd), jnp.float32))
     (m, l, acc), _ = jax.lax.scan(body, init, (kc, vc, pkc))
     out = (acc / jnp.maximum(l, 1e-37)[..., None]).astype(out_dtype)
     lse = m + jnp.log(jnp.maximum(l, 1e-37))
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, window, ck, out_dtype, sk_valid, res, dout):
+def _flash_bwd(causal, window, ck, out_dtype, sk_valid, scale, q_offset,
+               res, dout):
     q, k, v, out, lse = res
     bh, sq, hd = q.shape
-    sk = k.shape[1]
-    pos_q = jnp.arange(sq, dtype=jnp.int32)
-    pos_k = jnp.arange(sk, dtype=jnp.int32)
+    sk, vd = k.shape[1], v.shape[-1]
+    pos_q, pos_k = _positions(sq, sk, q_offset)
     kc, vc = _chunk_kv(k, ck), _chunk_kv(v, ck)
     pkc = pos_k.reshape(-1, ck)
-    scale = 1.0 / np.sqrt(hd)
     do = dout.astype(jnp.float32)
     delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)        # (BH,Sq)
 
@@ -297,7 +306,7 @@ def _flash_bwd(causal, window, ck, out_dtype, sk_valid, res, dout):
     dq0 = jnp.zeros((bh, sq, hd), jnp.float32)
     dq, (dks, dvs) = jax.lax.scan(body, dq0, (kc, vc, pkc))
     dk = dks.transpose(1, 0, 2, 3).reshape(bh, sk, hd)
-    dv = dvs.transpose(1, 0, 2, 3).reshape(bh, sk, hd)
+    dv = dvs.transpose(1, 0, 2, 3).reshape(bh, sk, vd)
     return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
 
 
@@ -324,17 +333,45 @@ def _bh_axes(bh: int) -> tuple:
     return ()
 
 
+#: query rows per block of a causal self-attention longer than this: a block
+#: attends only the keys up to its last row, so blocks above the diagonal are
+#: skipped and the (BH, rows, kv chunk) score tile stays bounded
+Q_BLOCK = 4096
+
+
+def _flash_rows(q, k, v, causal, window, ck, out_dtype, sk_valid, scale):
+    """``_flash`` over all rows at once, or, for a causal self-attention
+    longer than ``Q_BLOCK``, block by block of query rows, each against the
+    key prefix it can see."""
+    sq = q.shape[1]
+    if not causal or sq != sk_valid or sq <= Q_BLOCK:
+        return _flash(q, k, v, causal, window, ck, out_dtype, sk_valid, scale,
+                      0)
+    outs = []
+    for s0 in range(0, sq, Q_BLOCK):
+        s1 = min(s0 + Q_BLOCK, sq)
+        end = -(-s1 // ck) * ck
+        outs.append(_flash(q[:, s0:s1], k[:, :end], v[:, :end], causal,
+                           window, ck, out_dtype, s1, scale, s0))
+    return jnp.concatenate(outs, axis=1)
+
+
 def attend_chunked(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,
-                   causal: bool, window: int, plan: ExecPlan) -> Array:
+                   causal: bool, window: int, plan: ExecPlan,
+                   scale: Optional[float] = None) -> Array:
     """Flash attention over KV chunks with a custom backward (recompute, no
     stacked score residuals).  The (B, H) dims flatten into one leading dim
     sharded across the whole mesh with shard_map: compute is fully local —
     zero collectives inside attention.  jnp twin of kernels/flash_attention.
-    Positions must be aranges (true for every full-sequence caller)."""
+    Positions must be aranges (true for every full-sequence caller).  v may
+    be narrower than q and k (latent attention); ``scale`` defaults to
+    1/sqrt(head dim)."""
     from jax.sharding import PartitionSpec as P
     from repro.runtime.pspec import axis_rules, current_rules
 
     b, sq, hq, hd = q.shape
+    dv = v.shape[-1]
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     sk = k.shape[1]
     nkv = k.shape[2]
     group = hq // nkv
@@ -353,7 +390,7 @@ def attend_chunked(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,
     vh = constrain(vh, "batch", None, hax, None)
     qf = q.transpose(0, 2, 1, 3).reshape(b * hq, sq, hd)
     kf = kh.transpose(0, 2, 1, 3).reshape(b * hq, -1, hd)
-    vf = vh.transpose(0, 2, 1, 3).reshape(b * hq, -1, hd)
+    vf = vh.transpose(0, 2, 1, 3).reshape(b * hq, -1, dv)
 
     rules = current_rules()
     bh = b * hq
@@ -378,20 +415,22 @@ def attend_chunked(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,
         kf = jnp.pad(kf, ((0, pad_bh), (0, 0), (0, 0)))
         vf = jnp.pad(vf, ((0, pad_bh), (0, 0), (0, 0)))
     if rules is None or not axes:
-        out = _flash(qf, kf, vf, causal, window, ck, L.cdtype(plan), sk)
+        out = _flash_rows(qf, kf, vf, causal, window, ck, L.cdtype(plan), sk,
+                          scale)
     else:
         spec = P(axes if len(axes) > 1 else axes[0], None, None)
 
         def inner(qi, ki, vi):
             with axis_rules(None):
-                return _flash(qi, ki, vi, causal, window, ck, L.cdtype(plan), sk)
+                return _flash_rows(qi, ki, vi, causal, window, ck,
+                                   L.cdtype(plan), sk, scale)
 
         out = jax.shard_map(inner, mesh=rules.mesh,
                             in_specs=(spec, spec, spec),
                             out_specs=spec, check_vma=False)(qf, kf, vf)
     if pad_bh:
         out = out[:bh]
-    out = out.reshape(b, hq, sq, hd).transpose(0, 2, 1, 3)
+    out = out.reshape(b, hq, sq, dv).transpose(0, 2, 1, 3)
     return constrain(out, "batch", None, hax, None)
 
 
@@ -495,18 +534,144 @@ def attend_decode(q1: Array, cache: KVCache, valid: Optional[Array],
 def write_tokens(stack: dict, new: dict, cache_len: Array, ring: bool) -> dict:
     """Write one token's k/v per layer into the stacked caches in place.
 
-    ``stack``: name -> (L,B,Hkv,Sc,D); ``new``: name -> (L,B,Hkv,1,D), as a
-    decode scan emits them.  The slot is ``cache_len``, or ``cache_len % Sc``
-    for a ring.  One dynamic_update_slice per leaf: on a donated state it
+    ``stack``: name -> (L,B,Hkv,Sc,D), or a latent (L,B,Sc,R); ``new``:
+    name -> the same with Sc = 1, as a decode scan emits them.  The slot,
+    on the second-to-last axis, is ``cache_len``, or ``cache_len % Sc`` for
+    a ring.  One dynamic_update_slice per leaf: on a donated state it
     touches only the token's slot."""
     with jax.named_scope("kv_cache"):
         out = {}
         for name, x in new.items():
-            sc = stack[name].shape[3]
+            axis = x.ndim - 2
+            sc = stack[name].shape[axis]
             slot = (cache_len % sc) if ring else cache_len
             out[name] = jax.lax.dynamic_update_slice_in_dim(
-                stack[name], x, slot, axis=3)
+                stack[name], x, slot, axis=axis)
         return out
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2 §2.1, no q LoRA)
+#
+#   q = h Wq -> per head [q_nope | q_pe];  [c | k_pe] = h Wkv_a;
+#   c = RMSNorm(c);  [k_nope | v] = c Wkv_b per head;  rope on q_pe, k_pe
+#   (k_pe one head shared by all);  softmax(q.k * scale) v;  Wo.
+#
+# Prefill runs the expanded form: per-head k = [k_nope | k_pe] and v built
+# from the latent, then ordinary causal attention (q/k dim nope + rope, v dim
+# v_head_dim).  Decode runs the absorbed form against a cache of c and k_pe
+# only: q_nope goes through Wkv_b's k half into the latent, scores are
+# q_lat.c + q_pe.k_pe, the weighted sum stays in the latent and Wkv_b's v
+# half and Wo follow.  No per-head k/v is ever built over the cache.
+# ---------------------------------------------------------------------------
+
+
+def mla_init(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": L.dense_init(ks[0], (d, h * (dn + dr)), dtype=dtype),
+        "wkv_a": L.dense_init(ks[1], (d, r + dr), dtype=dtype),
+        "kv_norm": jnp.zeros((r,), dtype),
+        "wkv_b": L.dense_init(ks[2], (r, h * (dn + dv)), dtype=dtype),
+        "wo": L.dense_init(ks[3], (h * dv, d), dtype=dtype),
+    }
+
+
+def mla_scale(cfg: ArchConfig) -> float:
+    """Softmax scale: (nope + rope)^-1/2, times YaRN's mscale squared."""
+    y = cfg.rope_yarn
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 \
+        * L.yarn_mscale(y.factor, y.mscale) ** 2
+
+
+def _mla_rope(x: Array, positions: Array, cfg: ArchConfig) -> Array:
+    return L.apply_rope_pairs(
+        x, positions,
+        L.yarn_inv_freq(x.shape[-1], cfg.rope_theta, cfg.rope_yarn))
+
+
+def mla_project(x: Array, p: dict, cfg: ArchConfig, plan: ExecPlan,
+                positions: Array) -> tuple:
+    """(q_nope (B,S,H,dn), q_pe (B,S,H,dr), c (B,S,R), k_pe (B,S,dr)): the
+    queries, and what the cache keeps, normalised and rotated.  Two matmuls,
+    or one over [Wq | Wkv_a] (qkv_fused)."""
+    dt = L.cdtype(plan)
+    b, s, _ = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if plan.qkv_fused:
+        w = jnp.concatenate([p["wq"], p["wkv_a"]], axis=1).astype(dt)
+        q, kv = jnp.split(x @ w, [h * (dn + dr)], axis=-1)
+    else:
+        q, kv = x @ p["wq"].astype(dt), x @ p["wkv_a"].astype(dt)
+    q = q.reshape(b, s, h, dn + dr)
+    q_nope, q_pe = q[..., :dn], _mla_rope(q[..., dn:], positions, cfg)
+    c = L.rmsnorm(kv[..., :r], p["kv_norm"], cfg.norm_eps, plan)
+    k_pe = _mla_rope(kv[..., None, r:], positions, cfg)[:, :, 0]
+    return q_nope, q_pe, c, k_pe
+
+
+def _kv_b(p: dict, cfg: ArchConfig, dt) -> tuple[Array, Array]:
+    """Wkv_b as (R, H, dn) for keys and (R, H, dv) for values."""
+    w = p["wkv_b"].astype(dt).reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def mla_prefill(x: Array, p: dict, cfg: ArchConfig, plan: ExecPlan,
+                positions: Array) -> tuple[Array, Array, Array]:
+    """Expanded form over a full sequence.  Returns the attention output
+    (B,S,H*dv), before Wo, and the latent c, k_pe the cache keeps."""
+    dt = L.cdtype(plan)
+    b, s, _ = x.shape
+    q_nope, q_pe, c, k_pe = mla_project(x, p, cfg, plan, positions)
+    w_k, w_v = _kv_b(p, cfg, dt)
+    k_nope = jnp.einsum("bsr,rhn->bshn", c, w_k)
+    v = jnp.einsum("bsr,rhv->bshv", c, w_v)
+    k_pe_h = jnp.broadcast_to(k_pe[:, :, None], q_pe.shape)
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([k_nope, k_pe_h], axis=-1)
+    o = attend(q, k, v, positions, positions, causal=True, attn_kind="full",
+               window=0, plan=plan, scale=mla_scale(cfg))
+    return o.reshape(b, s, -1), c, k_pe
+
+
+def mla_decode(x1: Array, p: dict, cfg: ArchConfig, plan: ExecPlan,
+               cache_c: Array, cache_pe: Array, valid: Array,
+               pos: Array) -> tuple[Array, Array, Array]:
+    """Absorbed form: one token (B,1,d) against the latent cache it only
+    reads, c (B,Sc,R) and k_pe (B,Sc,dr), with ``valid`` (Sc,) slots; the
+    token's own latent joins the softmax as one more key.  Returns the
+    attention output (B,1,H*dv), before Wo, and the token's c (B,1,R) and
+    k_pe (B,1,dr)."""
+    dt = L.cdtype(plan)
+    f32 = jnp.float32
+    b = x1.shape[0]
+    q_nope, q_pe, c1, pe1 = mla_project(x1, p, cfg, plan, pos)
+    w_k, w_v = _kv_b(p, cfg, dt)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_k,
+                       preferred_element_type=f32).astype(dt)
+    q_pe = q_pe[:, 0]
+    scale = mla_scale(cfg)
+    s = (jnp.einsum("bhr,bkr->bhk", q_lat, cache_c, preferred_element_type=f32)
+         + jnp.einsum("bhe,bke->bhk", q_pe, cache_pe,
+                      preferred_element_type=f32)) * scale
+    s = jnp.where(valid[None, None], s, NEG_INF)
+    s_new = (jnp.einsum("bhr,br->bh", q_lat, c1[:, 0],
+                        preferred_element_type=f32)
+             + jnp.einsum("bhe,be->bh", q_pe, pe1[:, 0],
+                          preferred_element_type=f32))[..., None] * scale
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    total = jnp.sum(e, axis=-1, keepdims=True) + e_new
+    o_lat = jnp.einsum("bhk,bkr->bhr", (e / total).astype(dt), cache_c,
+                       preferred_element_type=f32)
+    p_new = (e_new / total).astype(dt).astype(f32)
+    o_lat = o_lat + p_new * c1[:, 0, None, :].astype(f32)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(dt), w_v,
+                   preferred_element_type=f32).astype(dt)
+    return o.reshape(b, 1, -1), c1, pe1
 
 
 # ---------------------------------------------------------------------------
@@ -515,10 +680,11 @@ def write_tokens(stack: dict, new: dict, cache_len: Array, ring: bool) -> dict:
 
 
 def attend(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array, *,
-           causal: bool, attn_kind: str, window: int, plan: ExecPlan) -> Array:
+           causal: bool, attn_kind: str, window: int, plan: ExecPlan,
+           scale: Optional[float] = None) -> Array:
     if attn_kind == "local" and causal and q.shape[1] > window:
         return attend_local_banded(q, k, v, pos_q, pos_k, window, plan)
     win = window if attn_kind == "local" else 0
     if plan.attn_impl == "chunked":
-        return attend_chunked(q, k, v, pos_q, pos_k, causal, win, plan)
-    return attend_naive(q, k, v, pos_q, pos_k, causal, win, plan)
+        return attend_chunked(q, k, v, pos_q, pos_k, causal, win, plan, scale)
+    return attend_naive(q, k, v, pos_q, pos_k, causal, win, plan, scale)

@@ -7,6 +7,7 @@ path — a fused-jnp rewrite on CPU/dry-run, a Pallas kernel on real TPU; see
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -98,6 +99,51 @@ def apply_rope(x: Array, positions: Array, theta: float) -> Array:
     sin, cos = jnp.sin(angles), jnp.cos(angles)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, yarn) -> tuple[int, int]:
+    """The rotary dims between which YaRN ramps from extrapolated to
+    interpolated frequencies (DeepSeek-V2's ``yarn_find_correction_range``)."""
+    def dim_of(rotations: float) -> float:
+        return dim * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = math.floor(dim_of(yarn.beta_fast))
+    high = math.ceil(dim_of(yarn.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """(dim/2,) inverse frequencies: the extrapolated ones below the
+    correction range, the ones interpolated by ``factor`` above it, a linear
+    ramp between."""
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / yarn.factor
+    low, high = yarn_correction_range(dim, theta, yarn)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (inter * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope_pairs(x: Array, positions: Array,
+                     inv_freq: np.ndarray) -> Array:
+    """Rotary embedding of adjacent pairs (2i, 2i+1), DeepSeek-V2's
+    convention; x: (..., S, H, D), positions: (S,).  The result is in
+    halves layout ``[rotated evens | rotated odds]``, as upstream emits it
+    (de-interleave, then rotate halves); the same permutation on q and k
+    leaves their dot products unchanged."""
+    angles = positions[:, None, None].astype(jnp.float32) \
+        * jnp.asarray(inv_freq)                          # (S,1,D/2)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xf = x.astype(jnp.float32)
+    ev, od = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([ev * cos - od * sin, od * cos + ev * sin], axis=-1)
     return out.astype(x.dtype)
 
 

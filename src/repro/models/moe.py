@@ -8,6 +8,12 @@ Two region implementations (ExecPlan.moe_impl):
 * ``scatter_ep``   — production: top-k routing, capacity-limited scatter into
   per-expert (E, C, d) buffers, batched expert matmuls, weighted combine.
   Expert dim shards over the "model"/"expert" mesh axis (EP).
+
+A layer may hold a share of the routed experts (``MoEConfig.held_first`` /
+``held_count``), as one chip of an expert-parallel group does: the router
+keeps all ``n_experts`` outputs and its top-k, and the layer returns the held
+experts' weighted outputs (plus the shared experts'); what the other experts
+would add is left to the chips that hold them.  Every path takes the share.
 """
 from __future__ import annotations
 
@@ -33,15 +39,35 @@ def moe_init(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
     e = cfg.moe
     d, ff = cfg.d_model, (e.d_ff_expert or cfg.d_ff)
     ks = jax.random.split(key, 5)
+    n = e.n_held
     p = {
         "w_router": L.dense_init(ks[0], (d, e.n_experts), dtype=jnp.float32),
-        "w_gate": L.dense_init(ks[1], (e.n_experts, d, ff), dtype=dtype),
-        "w_up": L.dense_init(ks[2], (e.n_experts, d, ff), dtype=dtype),
-        "w_down": L.dense_init(ks[3], (e.n_experts, ff, d), in_axis=-2, dtype=dtype),
+        "w_gate": L.dense_init(ks[1], (n, d, ff), dtype=dtype),
+        "w_up": L.dense_init(ks[2], (n, d, ff), dtype=dtype),
+        "w_down": L.dense_init(ks[3], (n, ff, d), in_axis=-2, dtype=dtype),
     }
     if e.n_shared_experts:
         p["shared"] = L.mlp_init(ks[4], d, ff * e.n_shared_experts, dtype=dtype)
     return p
+
+
+def _gates(probs: Array, e) -> tuple[Array, Array]:
+    """Top-k of the router's probabilities: (gates (T,k), expert idx (T,k)),
+    the gates renormalised to sum 1 (``norm_topk``)."""
+    gates, idx = jax.lax.top_k(probs, e.top_k)
+    if e.norm_topk:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True),
+                                    1e-9)
+    return gates, idx
+
+
+def _held(idx: Array, e) -> Array:
+    """Expert ids as indices of the held experts; a token's choices that lie
+    on other chips map to ``n_held`` (out of range: scatters drop them)."""
+    if e.n_held == e.n_experts:
+        return idx
+    loc = idx - e.held_first
+    return jnp.where((loc >= 0) & (loc < e.n_held), loc, e.n_held)
 
 
 def _route(x2d: Array, p: dict, cfg: ArchConfig) -> tuple[Array, Array, MoEAux]:
@@ -49,8 +75,7 @@ def _route(x2d: Array, p: dict, cfg: ArchConfig) -> tuple[Array, Array, MoEAux]:
     e = cfg.moe
     logits = (x2d.astype(jnp.float32) @ p["w_router"])  # (T,E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, e.top_k)  # (T,k)
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    gates, idx = _gates(probs, e)
     # Switch-style load-balance loss + z-loss
     density = jnp.mean(jax.nn.one_hot(idx, e.n_experts), axis=(0, 1))  # (E,)
     density_prob = jnp.mean(probs, axis=0)
@@ -71,6 +96,8 @@ def moe_dense(x2d: Array, p: dict, cfg: ArchConfig, plan: ExecPlan) -> tuple[Arr
     # (T, E) combined gate matrix (zero outside top-k)
     onehot = jax.nn.one_hot(idx, e.n_experts, dtype=jnp.float32)  # (T,k,E)
     combine = jnp.einsum("tk,tke->te", gates, onehot).astype(dt)
+    if e.n_held != e.n_experts:
+        combine = combine[:, e.held_first:e.held_first + e.n_held]
     # every token through every expert
     g = jnp.einsum("td,edf->tef", x2d, p["w_gate"].astype(dt))
     u = jnp.einsum("td,edf->tef", x2d, p["w_up"].astype(dt))
@@ -93,22 +120,13 @@ def moe_scatter(x2d: Array, p: dict, cfg: ArchConfig, plan: ExecPlan) -> tuple[A
 
     n = t * e.top_k
     cap = int(max(1, (t * e.top_k / e.n_experts) * e.capacity_factor))
-    e_flat = idx.reshape(-1)                         # (N,)
+    e_flat = _held(idx, e).reshape(-1)               # (N,)
     tok_flat = jnp.repeat(jnp.arange(t), e.top_k)    # (N,)
     gate_flat = gates.reshape(-1)
-
-    # within-expert rank via sort (dropless up to capacity)
-    order = jnp.argsort(e_flat)
-    sorted_e = e_flat[order]
-    counts = jnp.bincount(e_flat, length=e.n_experts)
-    starts = jnp.cumsum(counts) - counts
-    rank_sorted = jnp.arange(n) - starts[sorted_e]
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
-
-    keep = rank < cap
+    rank, keep = _ranks(e_flat, n, cap, e)
 
     # 2-D scatter into (E, C, d); out-of-capacity rows drop (token dropping).
-    xb = jnp.zeros((e.n_experts, cap, d), dt)
+    xb = jnp.zeros((e.n_held, cap, d), dt)
     xb = xb.at[e_flat, rank].set(x2d[tok_flat].astype(dt), mode="drop")
     xb = pspec_constrain_experts(xb)
 
@@ -125,6 +143,22 @@ def moe_scatter(x2d: Array, p: dict, cfg: ArchConfig, plan: ExecPlan) -> tuple[A
     weighted = gathered * gate_flat[:, None].astype(dt)
     out = jnp.zeros((t, d), dt).at[tok_flat].add(weighted)
     return out + _shared(x2d, p, cfg, plan), aux
+
+
+def _ranks(e_flat: Array, n: int, cap: int, e) -> tuple[Array, Array]:
+    """Each assignment's rank within its expert (by a sort: dropless up to
+    capacity) and whether it is kept: within capacity, on a held expert."""
+    n_bins = e.n_held + (e.n_held != e.n_experts)
+    order = jnp.argsort(e_flat)
+    sorted_e = e_flat[order]
+    counts = jnp.bincount(e_flat, length=n_bins)
+    starts = jnp.cumsum(counts) - counts
+    rank_sorted = jnp.arange(n) - starts[sorted_e]
+    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
+    keep = rank < cap
+    if e.n_held != e.n_experts:
+        keep &= e_flat < e.n_held
+    return rank, keep
 
 
 def pspec_constrain_experts(xb: Array) -> Array:
@@ -161,24 +195,16 @@ def _moe_ep_body(x_loc, wr, wg, wu, wd, *, cfg: ArchConfig, plan: ExecPlan,
 
     logits = x_loc.astype(jnp.float32) @ wr                      # (Tl, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, e.top_k)
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    gates, idx = _gates(probs, e)
 
     n = tl * e.top_k
     cap = int(max(1, (tl * e.top_k / e.n_experts) * e.capacity_factor))
-    e_flat = idx.reshape(-1)
+    e_flat = _held(idx, e).reshape(-1)
     tok_flat = jnp.repeat(jnp.arange(tl), e.top_k)
     gate_flat = gates.reshape(-1)
+    rank, keep = _ranks(e_flat, n, cap, e)
 
-    order = jnp.argsort(e_flat)
-    sorted_e = e_flat[order]
-    counts = jnp.bincount(e_flat, length=e.n_experts)
-    starts = jnp.cumsum(counts) - counts
-    rank_sorted = jnp.arange(n) - starts[sorted_e]
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
-    keep = rank < cap
-
-    buf = jnp.zeros((e.n_experts, cap, d), dt)
+    buf = jnp.zeros((e.n_held, cap, d), dt)
     buf = buf.at[e_flat, rank].set(x_loc[tok_flat].astype(dt), mode="drop")
 
     # expert-major <-> shard-major swap (EP all_to_all over "model")
@@ -189,7 +215,7 @@ def _moe_ep_body(x_loc, wr, wg, wu, wd, *, cfg: ArchConfig, plan: ExecPlan,
     h = L._act(g, cfg.mlp_act if cfg.mlp_act != "relu_sq" else "silu") * u
     yb = jnp.einsum("ecf,efd->ecd", h, wd.astype(dt))
     yb = jax.lax.all_to_all(yb, "model", split_axis=1, concat_axis=0,
-                            tiled=True)                          # (E, C, d)
+                            tiled=True)                          # (E_held, C, d)
 
     rank_c = jnp.clip(rank, 0, cap - 1)
     gathered = jnp.where(keep[:, None], yb[e_flat, rank_c], 0.0)
@@ -219,7 +245,7 @@ def moe_scatter_ep_sharded(x2d: Array, p: dict, cfg: ArchConfig,
     msize = mesh.shape.get("model", 1)
     if msize <= 1 or "data" not in mesh.shape:
         return None
-    if cfg.moe.n_experts % msize != 0:
+    if cfg.moe.n_held % msize != 0:
         return None
     t = x2d.shape[0]
     t_axes = dividing_axes(t, (("pod", "data", "model"), ("data", "model")))

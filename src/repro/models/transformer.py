@@ -56,14 +56,15 @@ def _stack_init(key, n: int, init_fn) -> Any:
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
 
 
-def _dense_block_init(key, cfg: ArchConfig, dtype) -> dict:
+def _dense_block_init(key, cfg: ArchConfig, dtype, moe: bool = True) -> dict:
     k1, k2 = jax.random.split(key)
+    attn_init = A.mla_init if cfg.attn_kind == "mla" else A.attn_init
     blk = {
         "ln1": jnp.zeros((cfg.d_model,), dtype),
         "ln2": jnp.zeros((cfg.d_model,), dtype),
-        "attn": A.attn_init(k1, cfg, dtype=dtype),
+        "attn": attn_init(k1, cfg, dtype=dtype),
     }
-    if cfg.moe is not None:
+    if cfg.moe is not None and moe:
         blk["moe"] = M.moe_init(k2, cfg, dtype=dtype)
     else:
         blk["mlp"] = L.mlp_init(k2, cfg.d_model, cfg.d_ff, dtype=dtype)
@@ -123,8 +124,14 @@ def init_params(cfg: ArchConfig, rng: jax.Array, dtype=jnp.float32) -> dict:
         params["blocks"] = _stack_init(
             km, n_macro, lambda k: _hybrid_macro_init(k, cfg, dtype))
     else:  # dense / moe / vlm trunk
+        if cfg.n_dense_layers:  # leading dense layers, outside the scan
+            kd, k_blocks = jax.random.split(k_blocks)
+            params["dense_blocks"] = _stack_init(
+                kd, cfg.n_dense_layers,
+                lambda k: _dense_block_init(k, cfg, dtype, moe=False))
         params["blocks"] = _stack_init(
-            k_blocks, cfg.n_layers, lambda k: _dense_block_init(k, cfg, dtype))
+            k_blocks, cfg.n_layers - cfg.n_dense_layers,
+            lambda k: _dense_block_init(k, cfg, dtype))
 
     if cfg.vision_patches:
         kv1, kv2 = jax.random.split(k_extra)
@@ -146,6 +153,9 @@ def _attn_sublayer_full(x, p_attn, ln, cfg: ArchConfig, plan: ExecPlan,
                         positions, want_cache: bool, cache_capacity: int):
     b, s, _ = x.shape
     h = L.rmsnorm(x, ln, cfg.norm_eps, plan)
+    if cfg.attn_kind == "mla":
+        return _mla_sublayer_full(x, h, p_attn, cfg, plan, positions,
+                                  want_cache, cache_capacity)
     with jax.named_scope("attention"):
         q, k, v = A.project_qkv(h, p_attn, cfg, plan, positions)
         o = A.attend(q, k, v, positions, positions, causal=True,
@@ -160,6 +170,23 @@ def _attn_sublayer_full(x, p_attn, ln, cfg: ArchConfig, plan: ExecPlan,
     return x + o, cache
 
 
+def _mla_sublayer_full(x, h, p_attn, cfg: ArchConfig, plan: ExecPlan,
+                       positions, want_cache: bool, cache_capacity: int):
+    """Latent attention over the sequence; its cache is the normalised
+    latent and the rotated shared key, (B, capacity, R) and (B, capacity,
+    dr), zero past the prompt."""
+    with jax.named_scope("attention"):
+        o, c, k_pe = A.mla_prefill(h, p_attn, cfg, plan, positions)
+        o = constrain(o @ p_attn["wo"].astype(L.cdtype(plan)),
+                      "batch", "seq", None)
+    cache = None
+    if want_cache:
+        with jax.named_scope("kv_cache"):
+            pad = ((0, 0), (0, cache_capacity - x.shape[1]), (0, 0))
+            cache = {"c": jnp.pad(c, pad), "k_pe": jnp.pad(k_pe, pad)}
+    return x + o, cache
+
+
 def _prefill_cache(k, v, cfg: ArchConfig, s: int, cache_capacity: int):
     """The head-major decode cache a prefill of ``s`` tokens leaves: the
     last window as a ring (local attention), else k/v padded to
@@ -168,8 +195,9 @@ def _prefill_cache(k, v, cfg: ArchConfig, s: int, cache_capacity: int):
         w = cfg.local_window
         if s >= w:  # ring layout: slot = position % window
             k, v = (jnp.roll(x[:, -w:], (s % w) - w, axis=1) for x in (k, v))
-        return A.to_cache(k, w), A.to_cache(v, w)
-    return A.to_cache(k, cache_capacity), A.to_cache(v, cache_capacity)
+        return {"k": A.to_cache(k, w), "v": A.to_cache(v, w)}
+    return {"k": A.to_cache(k, cache_capacity),
+            "v": A.to_cache(v, cache_capacity)}
 
 
 def _mlp_sublayer_full(x, blk, cfg: ArchConfig, plan: ExecPlan):
@@ -285,13 +313,23 @@ def forward_full(params: dict, x: Array, cfg: ArchConfig, plan: ExecPlan,
         if want_cache:
             states, kv = outs
             caches["macro_rglru"] = states
-            caches["macro_kv"] = {"k": kv[0], "v": kv[1]}
+            caches["macro_kv"] = kv
             if pre_states:
                 caches["pre_rglru"] = jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs), *pre_states)
         return x, jnp.zeros((2,), jnp.float32), caches
 
-    # dense / moe / vlm
+    # dense / moe / vlm; leading dense layers first, unrolled
+    pre_kv = []
+    for i in range(cfg.n_dense_layers):
+        blk = jax.tree_util.tree_map(lambda a: a[i], params["dense_blocks"])
+        x, _, kv = _dense_block_full(x, blk, cfg, plan, positions, want_cache,
+                                     cache_capacity)
+        pre_kv.append(kv)
+    if want_cache and pre_kv:
+        caches["pre_kv"] = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *pre_kv)
+
     def body(carry, blk):
         h, aux, kv = _dense_block_full(
             carry, blk, cfg, plan, positions, want_cache, cache_capacity)
@@ -301,7 +339,7 @@ def forward_full(params: dict, x: Array, cfg: ArchConfig, plan: ExecPlan,
     x, outs = jax.lax.scan(body, x, params["blocks"])
     if want_cache:
         auxs, kv = outs
-        caches["kv"] = {"k": kv[0], "v": kv[1]}
+        caches["kv"] = kv
     else:
         auxs = outs
     return x, jnp.sum(auxs, axis=0), caches
@@ -403,8 +441,18 @@ def prefill(params: dict, cfg: ArchConfig, plan: ExecPlan, tokens: Array,
 
 def _dense_block_decode(x1, blk, kv, cache_len, cfg, plan):
     """One layer's step against its cache ``kv`` (head-major, read only).
-    Returns the new hidden state and the token's own k/v, (B,Hkv,1,D)."""
+    Returns the new hidden state and the token's own k/v, (B,Hkv,1,D)
+    (latent attention: its c (B,1,R) and k_pe (B,1,dr))."""
     h = L.rmsnorm(x1, blk["ln1"], cfg.norm_eps, plan)
+    if cfg.attn_kind == "mla":
+        with jax.named_scope("attention"):
+            pos = cache_len[None].astype(jnp.int32)
+            valid = A.decode_valid(kv["c"].shape[1], cache_len, False)
+            o, c, k_pe = A.mla_decode(h, blk["attn"], cfg, plan, kv["c"],
+                                      kv["k_pe"], valid, pos)
+            o = o @ blk["attn"]["wo"].astype(L.cdtype(plan))
+        x1, _ = _mlp_sublayer_full(x1 + o, blk, cfg, plan)
+        return x1, {"c": c, "k_pe": k_pe}
     with jax.named_scope("attention"):
         pos = cache_len[None].astype(jnp.int32)
         q, k, v = A.project_qkv(h, blk["attn"], cfg, plan, pos)
@@ -505,6 +553,18 @@ def decode_step(params: dict, cfg: ArchConfig, plan: ExecPlan, token: Array,
         new_state["macro_kv"] = A.write_tokens(state["macro_kv"], new_kv,
                                                cache_len, ring)
     else:
+        pre_kv = []
+        for i in range(cfg.n_dense_layers):
+            blk, kv = jax.tree_util.tree_map(
+                lambda a: a[i], (params["dense_blocks"], state["pre_kv"]))
+            x1, tok = _dense_block_decode(x1, blk, kv, cache_len, cfg, plan)
+            pre_kv.append(tok)
+        if pre_kv:
+            new_state["pre_kv"] = A.write_tokens(
+                state["pre_kv"],
+                jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *pre_kv),
+                cache_len, ring)
+
         def body(h, xs):
             blk, kv = xs
             return _dense_block_decode(h, blk, kv, cache_len, cfg, plan)

@@ -18,8 +18,10 @@ The jitted steps are named functions, so their device programs read
 ``generate`` opens the span tree ``serve.generate`` > ``serve.prefill``,
 then per token ``serve.sample`` (its dispatches), ``serve.token_to_host``
 (the copy that waits for the token) and ``serve.decode_step`` (the decode
-dispatch); with the profiler sink on (``repro.obs.enable_profiler``) they
-land on the profiler's clock beside the device's operations.
+dispatch); ``serve.generate`` carries, and the ``serve.kv_cache_bytes`` gauge
+holds, the bytes of the decode state the call allocated.  With the profiler
+sink on (``repro.obs.enable_profiler``) the spans land on the profiler's
+clock beside the device's operations.
 """
 from __future__ import annotations
 
@@ -129,10 +131,14 @@ class Server:
         t0 = time.perf_counter()
         self._req_times.append(t0)
         span = obs_trace.span
-        with span("serve.generate", batch=b, prompt_len=s, max_new=max_new):
+        with span("serve.generate", batch=b, prompt_len=s,
+                  max_new=max_new) as gen:
             cap = s + max_new + (self.model.cfg.vision_patches or 0)
             with span("serve.prefill"):
                 logits, state = bound.prefill_fn(cap)(self.params, inputs)
+            state_bytes = sum(x.nbytes for x in jax.tree.leaves(state))
+            obs_metrics.gauge("serve.kv_cache_bytes").set(state_bytes)
+            gen.set(kv_cache_bytes=state_bytes)
             key = jax.random.key(self.cfg.seed)
             out = np.zeros((b, max_new), np.int32)
             seen = [logits[:, -1]] if return_logits else None

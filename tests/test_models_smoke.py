@@ -64,7 +64,8 @@ def test_offload_plan_matches_reference(built, arch):
 
 @pytest.mark.parametrize("arch", ["tinyllama_1_1b", "qwen3_0_6b", "olmoe_1b_7b",
                                   "recurrentgemma_2b", "rwkv6_3b",
-                                  "whisper_small", "llava_next_mistral_7b"])
+                                  "whisper_small", "llava_next_mistral_7b",
+                                  "deepseek_v2_lite"])
 def test_decode_matches_full_forward(built, arch):
     cfg, m, params, _ = built[arch]
     S = 64 if cfg.family == "hybrid" else 33
@@ -152,7 +153,8 @@ REGIONS = {"embed", "attention", "kv_cache", "mlp", "moe", "norm", "head"}
 
 
 @pytest.mark.parametrize("plan_name", ["reference", "offload"])
-@pytest.mark.parametrize("arch", ["qwen3_0_6b", "olmoe_1b_7b"])
+@pytest.mark.parametrize("arch", ["qwen3_0_6b", "olmoe_1b_7b",
+                                  "deepseek_v2_lite"])
 def test_compiled_serve_steps_name_every_matmul_region(arch, plan_name):
     """The served prefill and decode compile to modules named for their
     step, and every dot or convolution in them carries a model region in

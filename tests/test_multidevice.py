@@ -61,6 +61,27 @@ _SCRIPT = textwrap.dedent("""
     print(f"ref={float(l_ref):.6f} off={float(l_off):.6f} d={d:.2e}")
     assert d < 5e-3, d
 
+    # a held share (experts 4-7 of 8, unnormalised gates): the EP path
+    # computes the same part of the layer as the unsharded one
+    cfg_h = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, held_first=4, held_count=4, norm_topk=False))
+    m_h = build_model(cfg_h)
+    params_h = m_h.init(jax.random.key(0))
+    lh_ref, _ = jax.jit(lambda p, b: m_h.loss(p, b, plan_ref))(params_h, batch)
+    ph_shard = shd.tree_shardings(
+        rules, params_h, shd.param_logical_axes(m_h.param_shapes(), cfg_h,
+                                                mesh))
+
+    def loss_held(p, b):
+        with axis_rules(rules):
+            return m_h.loss(p, b, plan_off)
+
+    lh_off, _ = jax.jit(loss_held, in_shardings=(ph_shard, b_shard))(
+        jax.device_put(params_h, ph_shard), batch_s)
+    dh = abs(float(lh_ref) - float(lh_off))
+    print(f"held ref={float(lh_ref):.6f} off={float(lh_off):.6f} d={dh:.2e}")
+    assert dh < 5e-3, dh
+
     # rwkv: shard_map wkv path on the mesh
     from repro.configs import get_config
     cfg2 = get_config("rwkv6_3b").reduced()

@@ -162,3 +162,119 @@ def test_qwen3_decode_reads_each_cache_once(one_chip):
     cost = cost[0] if isinstance(cost, list) else cost
     assert cost["bytes accessed"] < 0.6e9, cost["bytes accessed"]
     assert compiled.memory_analysis().temp_size_in_bytes <= 42_297_856
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V2-Lite's cut (the deepseek-v2-lite.long-doc cell): latent
+# attention at 65,536 tokens on one chip
+# ---------------------------------------------------------------------------
+
+
+def _mla_cut():
+    """The cell's cut: the dense layer and four MoE layers, routed experts
+    0-7 of 64 held, dropless (capacity factor 64/6)."""
+    import dataclasses
+
+    cfg = get_config("deepseek_v2_lite")
+    return dataclasses.replace(cfg, n_layers=5, moe=dataclasses.replace(
+        cfg.moe, held_count=8, capacity_factor=64 / 6))
+
+
+def _lower_prefill(one_chip, cfg, plan, batch, prompt, cap):
+    model = build_model(cfg)
+    on = functools.partial(jax.tree_util.tree_map,
+                           lambda s: _spec(one_chip, s.shape, s.dtype))
+    params = on(model.param_shapes(jnp.bfloat16))
+    tokens = {"tokens": _spec(one_chip, (batch, prompt), jnp.int32)}
+    # argument names are part of the HLO (parameter names): keep them
+    prefill = jax.jit(lambda p, i: model.prefill(p, i, plan,
+                                                 cache_capacity=cap))
+    return model, on, params, prefill.lower(params, tokens), \
+        on(jax.eval_shape(prefill, params, tokens)[1])
+
+
+def test_module_frontend_refuses_a_plan_that_does_not_fit(one_chip):
+    """The module frontend's fitness compiles each candidate for the chip:
+    naive attention at 65,536 tokens needs a 275 GB score tensor and is
+    scored invalid on its memory; the chunked plan fits, and is valid."""
+    from repro.core.fitness import CostModelFitness
+    from repro.models.plan import ExecPlan
+
+    cfg = _mla_cut()
+    plans = [ExecPlan(), ExecPlan(attn_impl="chunked")]
+    fit = CostModelFitness(
+        lower=lambda bits: _lower_prefill(one_chip, cfg, plans[bits[0]], 1,
+                                          65_536, 65_568)[3],
+        n_devices=1, device_kind=V5E)
+    naive, chunked = fit((0,)), fit((1,))
+    assert not naive.valid and naive.time_s == float("inf")
+    assert "RESOURCE_EXHAUSTED" in naive.detail["error"] \
+        or "OOM" in naive.detail["error"], naive.detail
+    assert chunked.valid, chunked.detail
+    # weights 1.8 GB, the 378 MB latent cache out, scratch: under 16 GB
+    assert 2e9 < chunked.detail["live_bytes"] < 8e9, chunked.detail
+
+
+def test_mla_decode_state_is_the_latent_cache_only(one_chip):
+    """The cut's decode step at capacity 65,568 takes and returns only the
+    latent stacks (L, B, S, 512) and (L, B, S, 64), written in place, and
+    no instruction makes anything shaped like a per-head cache."""
+    cfg = _mla_cut()
+    model, on, params, _, state = _lower_prefill(
+        one_chip, cfg, REFERENCE_PLAN, 1, 65_536, 65_568)
+    shapes = {tuple(x.shape) for x in jax.tree_util.tree_leaves(state)}
+    assert shapes == {(), (1, 1, 65_568, 512), (1, 1, 65_568, 64),
+                      (4, 1, 65_568, 512), (4, 1, 65_568, 64)}
+    decode = jax.jit(lambda p, t, st: model.decode(p, t, st, REFERENCE_PLAN),
+                     donate_argnums=(2,))
+    compiled = decode.lower(params, _spec(one_chip, (1, 1), jnp.int32),
+                            state).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * 576 * 2 * 65_568
+    assert mem.temp_size_in_bytes < 64e6
+    per_head = {m for m in re.findall(r"\w+\[([\d,]+)\]",
+                                      compiled.as_text())
+                if {"16", "65568"} <= set(m.split(","))
+                and {"128", "192"} & set(m.split(","))}
+    assert not per_head, per_head
+
+
+#: sha256 (first 16 hex digits) of the optimised HLO of the qwen3-0.6b.decode
+#: and olmoe-1b-7b.prefill cells' serving programs (prefill, decode) at their
+#: long bucket, with op metadata and the source tables left out: the
+#: programs as they were before latent attention, the leading dense layer
+#: and the held-expert share joined the model code (their defaults must
+#: leave these programs as they were)
+SERVING_HLO = {
+    ("qwen3_0_6b", "default"): ("88db2f99b52cb0d2", "c1be19814151399a"),
+    ("qwen3_0_6b", "offload"): ("0ab54b6200f8ed2a", "c0005e5fb664f435"),
+    ("olmoe_1b_7b", "default"): ("7efa6e448b4aad05", "b72ccdadaf32e19d"),
+    ("olmoe_1b_7b", "offload"): ("4a3d2a9b30cb782a", "ba638036522a7847"),
+}
+_CELL_SHAPES = {"qwen3_0_6b": (32, 512, 128), "olmoe_1b_7b": (4, 2048, 16)}
+
+
+def _digest(text: str) -> str:
+    import hashlib
+
+    text = re.sub(r",? metadata=\{[^}]*\}", "", text)
+    kept = [ln for ln in text.splitlines() if not re.match(
+        r"(\d+ |FileNames|FunctionNames|FileLocations|StackFrames)", ln)]
+    return hashlib.sha256("\n".join(kept).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("arch,plan_name", sorted(SERVING_HLO))
+def test_serving_programs_of_the_earlier_cells_are_unchanged(
+        one_chip, arch, plan_name):
+    from repro.models.plan import ExecPlan
+
+    plan = {"default": ExecPlan(), "offload": OFFLOAD_PLAN}[plan_name]
+    batch, prompt, new = _CELL_SHAPES[arch]
+    model, on, params, prefill, state = _lower_prefill(
+        one_chip, get_config(arch), plan, batch, prompt, prompt + new)
+    decode = jax.jit(lambda p, t, s: model.decode(p, t, s, plan),
+                     donate_argnums=(2,))
+    got = (_digest(prefill.compile().as_text()),
+           _digest(decode.lower(params, _spec(one_chip, (batch, 1), jnp.int32),
+                                state).compile().as_text()))
+    assert got == SERVING_HLO[(arch, plan_name)]
