@@ -48,19 +48,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, -1, d)
     bq = min(blk_q, max(1, sq))
     bk = min(blk_k, kf.shape[1])
-    qf, pad_q = _pad_to(qf, 1, bq)
-    kf, _ = _pad_to(kf, 1, bk)
-    vf, _ = _pad_to(vf, 1, bk)
-    # NOTE padded KV columns would contaminate non-causal softmax; mask by
-    # giving padded keys -inf via a causal-style trick is not available here,
-    # so we require Sk % blk_k == 0 for non-causal use (asserted).
-    if not causal:
-        assert k.shape[1] % bk == 0, "non-causal flash requires Sk % blk_k == 0"
     out = _fa.flash_attention_bh(qf, kf, vf, causal=causal, scale=scale,
                                  blk_q=bq, blk_k=bk, group=group,
                                  interpret=_interpret())
-    if pad_q:
-        out = out[:, :sq]
     return out.reshape(b, hq, sq, d).transpose(0, 2, 1, 3)
 
 

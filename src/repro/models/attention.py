@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels import flash_attention as _fa
 from repro.models import layers as L
 from repro.models.plan import ExecPlan
 from repro.runtime.pspec import constrain
@@ -333,27 +334,84 @@ def _bh_axes(bh: int) -> tuple:
     return ()
 
 
-#: query rows per block of a causal self-attention longer than this: a block
-#: attends only the keys up to its last row, so blocks above the diagonal are
-#: skipped and the (BH, rows, kv chunk) score tile stays bounded
+#: query rows per block of a causal self-attention longer than this, off
+#: TPU: a block attends only the keys up to its last row, so blocks above
+#: the diagonal are skipped and the (BH, rows, kv chunk) score tile stays
+#: bounded.  On TPU such an attention is one Pallas call (``_flash_pallas``)
 Q_BLOCK = 4096
+
+
+def _row_blocks(sq: int, ck: int):
+    """(first row, end row, end key) of each ``Q_BLOCK`` block of a causal
+    self-attention: the block's keys are those up to its last row, rounded
+    up to the chunk."""
+    for s0 in range(0, sq, Q_BLOCK):
+        s1 = min(s0 + Q_BLOCK, sq)
+        yield s0, s1, -(-s1 // ck) * ck
+
+
+_long_causal_flash = functools.partial(
+    _fa.flash_attention_bh, causal=True, blk_q=_fa.LONG_CAUSAL_BLOCKS[0],
+    blk_k=_fa.LONG_CAUSAL_BLOCKS[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_pallas(q: Array, k: Array, v: Array, ck: int, scale: float,
+                  interpret: bool) -> Array:
+    """Causal self-attention of all rows in one Pallas flash call (the TPU
+    branch of ``_flash_rows``).  q: (BH, S, D); k: (BH, Sk, D) and v:
+    (BH, Sk, Dv) with Sk >= S a multiple of ck (keys past S are padding,
+    masked by causality).  The backward is ``_flash_bwd`` on the kernel's
+    log-sum-exp, block by ``Q_BLOCK`` block of rows as the jnp path runs."""
+    return _long_causal_flash(q, k, v, scale=scale, interpret=interpret)
+
+
+def _flash_pallas_fwd(q, k, v, ck, scale, interpret):
+    out, lse = _long_causal_flash(q, k, v, scale=scale, interpret=interpret,
+                                  with_lse=True)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_pallas_bwd(ck, scale, interpret, res, dout):
+    q, k, v, out, lse = res
+    dq, dk, dv = [], jnp.zeros_like(k), jnp.zeros_like(v)
+    for s0, s1, end in _row_blocks(q.shape[1], ck):
+        gq, gk, gv = _flash_bwd(
+            True, 0, ck, out.dtype, s1, scale, s0,
+            (q[:, s0:s1], k[:, :end], v[:, :end], out[:, s0:s1],
+             lse[:, s0:s1]), dout[:, s0:s1])
+        dq.append(gq)
+        dk = dk.at[:, :end].add(gk)
+        dv = dv.at[:, :end].add(gv)
+    return jnp.concatenate(dq, axis=1), dk, dv
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
 def _flash_rows(q, k, v, causal, window, ck, out_dtype, sk_valid, scale):
     """``_flash`` over all rows at once, or, for a causal self-attention
-    longer than ``Q_BLOCK``, block by block of query rows, each against the
-    key prefix it can see."""
+    longer than ``Q_BLOCK``: on TPU one Pallas flash call over all rows, and
+    elsewhere ``_flash`` block by block of query rows, each against the key
+    prefix it can see.  The branch is taken by the platform the program is
+    lowered for."""
     sq = q.shape[1]
     if not causal or sq != sk_valid or sq <= Q_BLOCK:
         return _flash(q, k, v, causal, window, ck, out_dtype, sk_valid, scale,
                       0)
-    outs = []
-    for s0 in range(0, sq, Q_BLOCK):
-        s1 = min(s0 + Q_BLOCK, sq)
-        end = -(-s1 // ck) * ck
-        outs.append(_flash(q[:, s0:s1], k[:, :end], v[:, :end], causal,
-                           window, ck, out_dtype, s1, scale, s0))
-    return jnp.concatenate(outs, axis=1)
+
+    def row_blocks(q, k, v):
+        return jnp.concatenate(
+            [_flash(q[:, s0:s1], k[:, :end], v[:, :end], causal, window, ck,
+                    out_dtype, s1, scale, s0)
+             for s0, s1, end in _row_blocks(sq, ck)], axis=1)
+
+    def kernel(q, k, v):
+        return _flash_pallas(q, k, v, ck, scale, False).astype(out_dtype)
+
+    if window:
+        return row_blocks(q, k, v)
+    return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=row_blocks)
 
 
 def attend_chunked(q: Array, k: Array, v: Array, pos_q: Array, pos_k: Array,

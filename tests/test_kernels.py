@@ -43,6 +43,110 @@ def test_flash_attention_sweep(rng, b, s, hq, hkv, d, causal, dtype):
                                np.asarray(exp, np.float32), atol=tol, rtol=tol)
 
 
+# The widened kernel at latent attention's shapes (q/k 192, v 128, MLA's
+# scale), causal over lengths that are not block multiples: against the jnp
+# twin it stands in for (``_flash``, same bf16-in / f32-accumulate
+# arithmetic) and the materialised reference, and its custom_vjp gradient
+# against ``_flash``'s.
+
+
+def _mla_qkv(rng, s, dtype, bh=2):
+    return (_arr(rng, bh, s, 192, dtype=dtype), _arr(rng, bh, s, 192, dtype=dtype),
+            _arr(rng, bh, s, 128, dtype=dtype))
+
+
+def _mla_scale():
+    from repro.configs import get_config
+    from repro.models import attention as A
+    return A.mla_scale(get_config("deepseek_v2_lite"))
+
+
+@pytest.mark.parametrize("s", [1000, 1536])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_kernel_mla_dims_match_flash_and_naive(rng, s, dtype):
+    from repro.kernels import flash_attention as fa
+    from repro.models import attention as A
+    from repro.models.plan import ExecPlan
+    q, k, v = _mla_qkv(rng, s, dtype)
+    scale = _mla_scale()
+    out = fa.flash_attention_bh(q, k, v, causal=True, scale=scale, blk_q=512,
+                                blk_k=256, interpret=True)
+    assert out.shape == (2, s, 128) and out.dtype == dtype
+    twin = A._flash(q, k, v, True, 0, s if s % 256 else 256, dtype, s, scale, 0)
+    pos = jnp.arange(s)
+    plan = ExecPlan(compute_dtype=jnp.dtype(dtype).name)
+    naive = A.attend_naive(q[None].transpose(0, 2, 1, 3),
+                           k[None].transpose(0, 2, 1, 3),
+                           v[None].transpose(0, 2, 1, 3), pos, pos, True, 0,
+                           plan, scale)[0].transpose(1, 0, 2)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for exp in (twin, naive):
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(exp, np.float32), atol=tol,
+                                   rtol=tol)
+
+
+@pytest.mark.parametrize("s", [1000, 1536])
+def test_flash_kernel_log_sum_exp_and_gradient_match_flash(rng, s):
+    from repro.models import attention as A
+    q, k, v = _mla_qkv(rng, s, jnp.float32)
+    scale, ck = _mla_scale(), 256
+    pad = (-s) % ck
+    kp, vp = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (k, v))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v)))
+
+    def kernel(q, k, v):
+        return A._flash_pallas(q, k, v, ck, scale, True)
+
+    def twin(q, k, v):
+        return A._flash(q, k, v, True, 0, ck, jnp.float32, s, scale, 0)
+
+    _, (*_, lse) = A._flash_pallas_fwd(q, kp, vp, ck, scale, True)
+    _, (*_, lse_twin) = A._flash_fwd(q, kp, vp, True, 0, ck, jnp.float32, s,
+                                     scale, 0)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_twin),
+                               atol=1e-5, rtol=1e-5)
+    o1, g1 = jax.value_and_grad(loss(kernel), argnums=(0, 1, 2))(q, kp, vp)
+    o2, g2 = jax.value_and_grad(loss(twin), argnums=(0, 1, 2))(q, kp, vp)
+    np.testing.assert_allclose(float(o1), float(o2), rtol=1e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
+                                   rtol=1e-3)
+
+
+def test_long_causal_attention_keeps_the_row_block_loop_off_tpu(rng):
+    """Lowered for the CPU, a causal self-attention just above ``Q_BLOCK``
+    rows runs ``_flash`` block by block of rows, as before the kernel took
+    the TPU branch: no Pallas call is lowered, and the result is the loop's
+    to the bit."""
+    from repro.models import attention as A
+    from repro.models.plan import ExecPlan
+    s, h, d = A.Q_BLOCK + 200, 1, 16
+    q, k, v = (_arr(rng, 1, s, h, d) for _ in range(3))
+    pos = jnp.arange(s)
+    plan = ExecPlan(compute_dtype="float32")
+    fn = jax.jit(lambda q, k, v: A.attend_chunked(q, k, v, pos, pos, True, 0,
+                                                   plan))
+    text = fn.lower(q, k, v).as_text()
+    assert "tpu_custom_call" not in text and "pallas" not in text.lower()
+    out = fn(q, k, v)
+    ck = plan.attn_kv_chunk
+    end = -(-s // ck) * ck
+    qf, kf, vf = (x[0].transpose(1, 0, 2) for x in (q, k, v))
+    kf, vf = (jnp.pad(x, ((0, 0), (0, end - s), (0, 0))) for x in (kf, vf))
+    loop = jnp.concatenate(
+        [A._flash(qf[:, s0:s1], kf[:, :e], vf[:, :e], True, 0, ck,
+                  jnp.float32, s1, d ** -0.5, s0)
+         for s0, s1, e in A._row_blocks(s, ck)], axis=1)
+    np.testing.assert_array_equal(np.asarray(out[0].transpose(1, 0, 2)),
+                                  np.asarray(loop))
+    naive = A.attend_naive(q, k, v, pos, pos, True, 0, plan)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(naive), atol=2e-5,
+                               rtol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # rglru linear recurrence
 # ---------------------------------------------------------------------------

@@ -21,6 +21,7 @@ from repro.kernels import rglru_scan as rg
 from repro.kernels import rmsnorm as rn
 from repro.kernels import wkv6 as wk
 from repro.models import OFFLOAD_PLAN, REFERENCE_PLAN, build_model
+from repro.models.attention import mla_scale
 from repro.roofline import peaks_for
 
 V5E = "TPU v5 lite"
@@ -59,6 +60,14 @@ _KERNELS = {
                           scale=128 ** -0.5, blk_q=128, blk_k=128, group=2,
                           interpret=False),
         [((16, 2048, 128),), ((8, 2048, 128),), ((8, 2048, 128),)]),
+    # deepseek_v2_lite latent attention, the model path's long causal
+    # prefill: 16 heads, q/k 192, v 128, MLA's scale
+    "flash_attention_mla": (
+        functools.partial(fa.flash_attention_bh, causal=True,
+                          scale=mla_scale(get_config("deepseek_v2_lite")),
+                          blk_q=fa.LONG_CAUSAL_BLOCKS[0],
+                          blk_k=fa.LONG_CAUSAL_BLOCKS[1], interpret=False),
+        [((16, 8192, 192),), ((16, 8192, 192),), ((16, 8192, 128),)]),
     # recurrentgemma_2b: d_rnn 2560
     "rglru_scan": (
         functools.partial(rg.rglru_scan, chunk=256, d_block=128,
@@ -237,6 +246,34 @@ def test_mla_decode_state_is_the_latent_cache_only(one_chip):
                 if {"16", "65568"} <= set(m.split(","))
                 and {"128", "192"} & set(m.split(","))}
     assert not per_head, per_head
+
+
+#: scratch (``temp_size_in_bytes``) of the cut's chunked prefill with the
+#: Pallas kernel; the 4096-row jnp loop it replaced needed 463,018,496
+#: bytes at 8,192 tokens and 3,042,782,208 at 65,536
+MLA_PREFILL_TEMP = {8192: 271_428_096, 65_536: 3_009_940_480}
+
+
+@pytest.mark.parametrize("prompt", sorted(MLA_PREFILL_TEMP))
+def test_mla_long_prefill_runs_attention_in_the_kernel(one_chip, prompt):
+    """Lowered for the chip, the cut's prefill longer than ``Q_BLOCK`` runs
+    its causal attention as one Pallas call per layer (the dense layer's
+    and the layer scan's), under the ``attention`` region, and none of the
+    jnp path's 4096-row blocks (their f32[16,4096] statistics) remain."""
+    from repro.models.attention import Q_BLOCK
+    from repro.models.plan import ExecPlan
+
+    assert prompt > Q_BLOCK
+    compiled = _lower_prefill(one_chip, _mla_cut(), ExecPlan(attn_impl="chunked"),
+                              1, prompt, prompt + 32)[3].compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 2, calls
+    assert all("/attention/" in ln and "flash_attention" in ln for ln in calls)
+    assert "f32[16,4096]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= MLA_PREFILL_TEMP[prompt]
 
 
 #: sha256 (first 16 hex digits) of the optimised HLO of the qwen3-0.6b.decode
