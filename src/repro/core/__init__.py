@@ -14,14 +14,12 @@ from repro.core.frontends import (Frontend, FitnessBundle, detect_frontend,
                                   frontend_names, get_frontend,
                                   register_frontend)
 from repro.core.ga import Evaluation, GAConfig, GAResult, run_ga
-from repro.core.genes import (DEFAULT_ALPHABET, EXTENDED_ALPHABET,
-                              VARIANT_ALPHABET, CPU, FPGA_STUB, GPU,
+from repro.core.genes import (DEFAULT_ALPHABET, VARIANT_ALPHABET, CPU, GPU,
                               GPU_FUSED, GPU_PALLAS, Destination, Device,
                               GeneCoding, MeshDestination, Site,
                               coding_from_graph, destination_names,
                               get_destination, mesh_proposals,
-                              modeled_cost_s, register_destination,
-                              site_modeled_cost_s, with_mesh_destinations)
+                              register_destination, with_mesh_destinations)
 from repro.core.ir import Region, RegionGraph
 from repro.core.offload import (OffloadConfig, OffloadResult, Offloader,
                                 SeedBank, ga_search, phenotype_key, plan,
@@ -47,12 +45,11 @@ __all__ = [
     "Frontend", "FitnessBundle", "detect_frontend", "frontend_names",
     "get_frontend", "register_frontend",
     "Evaluation", "GAConfig", "GAResult", "run_ga",
-    "DEFAULT_ALPHABET", "EXTENDED_ALPHABET", "VARIANT_ALPHABET",
-    "CPU", "GPU", "FPGA_STUB", "GPU_FUSED", "GPU_PALLAS",
+    "DEFAULT_ALPHABET", "VARIANT_ALPHABET",
+    "CPU", "GPU", "GPU_FUSED", "GPU_PALLAS",
     "Destination", "Device", "MeshDestination", "GeneCoding", "Site",
     "coding_from_graph", "destination_names", "get_destination",
-    "mesh_proposals", "modeled_cost_s", "register_destination",
-    "site_modeled_cost_s", "with_mesh_destinations",
+    "mesh_proposals", "register_destination", "with_mesh_destinations",
     "SubstitutedCallable", "SubstitutionEngine", "SubstitutionReport",
     "SubstitutionChoice", "generic_plan_report", "resolve_variant",
     "FeatureExtractor", "FittedSurrogate", "fit_surrogate", "load_fit",

@@ -320,15 +320,8 @@ class Evaluator:
                  executor: Optional[Any] = None,
                  dispatch_fn: Optional[Callable[[tuple], Evaluation]] = None,
                  phenotype_key: Optional[Callable[[tuple], Any]] = None,
-                 compile_workers: Optional[int] = None,
-                 annotate: Optional[Callable[[Evaluation], Evaluation]]
-                 = None):
+                 compile_workers: Optional[int] = None):
         self.fitness_fn = fitness_fn
-        # post-measurement hook: enrich an Evaluation's detail dict before it
-        # is cached/persisted (multi-objective search stamps per-objective
-        # fields — energy_j, transfer_bytes — so journal rows carry them;
-        # see repro.core.objectives.annotate_objectives)
-        self.annotate = annotate
         self.workers = max(0, int(workers))
         self.compile_workers = max(0, int(compile_workers or 0))
         self._key = phenotype_key or (lambda bits: bits)
@@ -398,11 +391,6 @@ class Evaluator:
     # -- measurement --------------------------------------------------------
 
     def _record(self, bits: tuple, ev: Evaluation) -> Evaluation:
-        if self.annotate is not None:
-            try:
-                ev = self.annotate(ev)
-            except Exception:  # noqa: BLE001 — annotation must never cost a
-                pass           # measurement; the objective fn recomputes
         score = None
         if self.surrogate is not None and math.isfinite(ev.time_s):
             try:
@@ -786,25 +774,12 @@ def transfer_cost_surrogate(graph, coding, var_bytes: Optional[dict] = None,
     and weights the resulting transfer count by per-variable byte sizes when
     known.  Patterns that offload more while transferring less rank first —
     a roofline-style prior, used *only* to order offspring for measurement.
-
-    Destination-aware: genes on cost-only destinations decode to the
-    reference path (zero transfers), so their modeled device cost is folded
-    into the rank instead — otherwise stub-parked chromosomes would rank
-    *best* while the fitness charges them the stub's modeled latency, and
-    screening would invert.  Only genes on executable accelerator
-    destinations count as "more offloaded work" for the tiebreak.
+    Mesh genes count as device placements whose transfers split over the
+    mesh's links.
     """
-    from repro.core.genes import get_destination, modeled_cost_s
     from repro.core.transfer_planner import plan_transfers
 
     var_bytes = var_bytes or {}
-    dests = [get_destination(d) for d in coding.destinations]
-    # any placement that charges a model (stub devices, mesh genes) folds
-    # its modeled seconds into the rank so screening can't invert
-    any_charged = any(d.placement_tag is not None for d in dests)
-    #: rank-units per modeled second — arbitrary but monotone: it only has
-    #: to make stub-parked genes rank behind the free reference path
-    _COST_ONLY_SCALE = 1e6
     memo: dict[tuple, float] = {}
 
     def cost(bits: tuple) -> float:
@@ -825,13 +800,10 @@ def transfer_cost_surrogate(graph, coding, var_bytes: Optional[dict] = None,
                     r = graph.by_name(r.parent) if r.parent else None
             total += (trips * float(var_bytes.get(t.var, 1.0))
                       / max(t.shards, 1))
-        if any_charged:
-            total += _COST_ONLY_SCALE * modeled_cost_s(graph, coding, bits)
         # prefer more offloaded work at equal transfer cost (paper intuition:
         # offload wins when transfers are amortized); for the binary alphabet
         # this is exactly the historical sum(bits)
-        offloaded = sum(1 for v in bits
-                        if not dests[int(v)].is_cost_only and int(v) != 0)
+        offloaded = sum(1 for v in bits if int(v) != 0)
         memo[bits] = total - 1e-9 * offloaded
         return memo[bits]
 

@@ -1300,7 +1300,7 @@ class AstFrontend:
         # *menu* level — region.alternatives holds only BOUND variants, so
         # the gene decode itself clamps every chromosome into implementations
         # that run (phenotype dedup needs no extra resolution step here)
-        from repro.core.genes import VARIANT_ALPHABET, with_mesh_destinations
+        from repro.core.genes import VARIANT_ALPHABET
         return FitnessBundle(
             fitness_factory=fitness_factory,
             block=block, claimed=claimed, base_impl=block_impl,
@@ -1310,11 +1310,10 @@ class AstFrontend:
             # on its own when contention eats the estimated saving
             overlap_compiles=True,
             # variant sites make the gene an implementation choice, so the
-            # frontend proposes the variant alphabet — plus this host's
-            # mesh destinations (cost-modeled: mesh_executed stays False,
-            # the interpreter never decodes a gene to sharded execution);
-            # plain programs keep the paper's binary interp/jit gene
-            destinations=(with_mesh_destinations(VARIANT_ALPHABET)
+            # frontend proposes the variant alphabet; no mesh genes, since
+            # the interpreter never runs a region sharded.  Plain programs
+            # keep the paper's binary interp/jit gene
+            destinations=(VARIANT_ALPHABET
                           if variant_sites or block_sites else None),
             context={"program": program, "lib_calls": lib_all,
                      "variant_sites": variant_sites,

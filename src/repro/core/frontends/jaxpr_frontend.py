@@ -338,8 +338,8 @@ class JaxprFrontend:
         graph = annotate_variants(graph, db,
                                   registry=config.options.get("registry"))
         # function-block genes (whole-window substitution) ride alongside
-        # the loop/span genes unless explicitly disabled — benchmarks use
-        # options={"block_sites": False} for the loop-only comparison arm
+        # the loop/span genes unless explicitly disabled with
+        # options={"block_sites": False} (the loop-only comparison arm)
         if config.options.get("block_sites", True):
             graph = annotate_block_sites(
                 graph, db, registry=config.options.get("registry"))
@@ -438,19 +438,16 @@ class JaxprFrontend:
         return FitnessBundle(
             fitness_factory=factory,
             block=block, claimed=(), base_impl={},
-            # device count joins the cache key: a mesh gene measured on an
-            # 8-device host and the same bits cost-modeled on a laptop are
-            # different experiments
+            # device count joins the cache key: the same bits measured on
+            # hosts of different sizes are different experiments
             cache_extra=(f"jaxpr={graph.source_name}|measured"
                          f"|args={args_sig}|backend={engine.backend}"
                          f"|ndev={jax.device_count()}"),
             serial_only=True, measured=True, overlap_compiles=True,
             # variant alphabet plus whatever meshes this host can really
-            # build (empty extension on single-device CI)
+            # build (empty extension on single-device CI); this measured
+            # path decodes mesh genes to sharded execution
             destinations=with_mesh_destinations(VARIANT_ALPHABET),
-            # this measured path genuinely decodes mesh genes to sharded
-            # execution, so available meshes are measured, not modeled
-            mesh_executed=True,
             # bind results join the phenotype key: two chromosomes whose
             # variants fall back to ref at a site are one program and
             # share one measurement (eager resolution is static per

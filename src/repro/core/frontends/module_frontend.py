@@ -70,10 +70,9 @@ def plan_from_bits(graph: RegionGraph, bits, base: Optional[ExecPlan] = None,
                    exclude: tuple = ()) -> ExecPlan:
     """Decode a chromosome into an ExecPlan (respecting block-pass claims).
 
-    Multi-destination genes are welcome: value 1 is the primary accelerator
-    (the offloaded plan value); any other value — 0 (CPU) or a cost-only
-    stub destination — keeps the reference value, since only executable
-    destinations change what actually compiles.
+    Value 1 is the primary accelerator (the offloaded plan value); any
+    other value keeps the reference value (:func:`plan_from_coding` decodes
+    wider alphabets).
     """
     plan = base or ExecPlan()
     sites = [r for r in graph.offloadable() if r.name not in exclude]
@@ -89,7 +88,7 @@ def plan_from_bits(graph: RegionGraph, bits, base: Optional[ExecPlan] = None,
 def plan_from_coding(graph: RegionGraph, coding, values,
                      base: Optional[ExecPlan] = None) -> ExecPlan:
     """Destination-aware decode: the coding's alphabet picks each site's
-    implementation (cost-only destinations resolve to the reference value)."""
+    implementation (a mesh gene resolves to the reference value)."""
     impl = coding.decode(values)
     plan = base or ExecPlan()
     kw = {graph.by_name(region).meta["plan_field"]: value

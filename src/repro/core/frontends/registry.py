@@ -52,24 +52,32 @@ def resolve_alphabet(config: Optional["OffloadConfig"],
     1. an explicit ``OffloadConfig.destinations`` always wins (the caller
        knows the hardware they are planning for),
     2. else the frontend's proposal (``FitnessBundle.destinations`` — e.g.
-       the jaxpr variant alphabet, extended with this host's executable
-       mesh destinations),
+       the jaxpr variant alphabet, extended with the mesh destinations this
+       host can build),
     3. else :data:`~repro.core.genes.DEFAULT_ALPHABET` (the paper's binary
        cpu/gpu chromosome).
 
     Every entry is validated against the destination registry (mesh wire
     strings parse on demand), so a typo fails here — before a search — with
-    the registry's own error."""
-    from repro.core.genes import DEFAULT_ALPHABET, get_destination
+    the registry's own error.  An explicit mesh wider than this host's
+    device count is refused the same way: a gene the host cannot execute
+    has no measurement to be searched by."""
+    from repro.core.genes import (DEFAULT_ALPHABET, get_destination,
+                                  probed_device_count)
 
-    if config is not None and config.destinations is not None:
+    explicit = config is not None and config.destinations is not None
+    if explicit:
         alphabet = tuple(config.destinations)
     elif proposed:
         alphabet = tuple(proposed)
     else:
         alphabet = DEFAULT_ALPHABET
     for name in alphabet:
-        get_destination(name)        # fail fast on unknown alphabet entries
+        dest = get_destination(name)  # fail fast on unknown alphabet entries
+        if explicit and dest.device_count > probed_device_count():
+            raise ValueError(
+                f"destination {name!r} needs {dest.device_count} devices; "
+                f"this host has {probed_device_count()}")
     return alphabet
 
 
@@ -151,17 +159,6 @@ class FitnessBundle:
                                               # alphabet (e.g. the jaxpr
                                               # variant alphabet); used when
                                               # the config left the default
-    mesh_executed: bool = False               # False (default) means mesh
-                                              # genes
-                                              # are never genuinely decoded
-                                              # to sharded execution by
-                                              # this fitness, so the mesh
-                                              # cost model must be charged
-                                              # on top of measurements even
-                                              # when the host has the
-                                              # devices (ast/module paths).
-                                              # Irrelevant when no mesh
-                                              # destination is in play
     impl_resolver: Optional[Callable[[str, Any], Any]] = None
                                               # (region, decoded impl) -> the
                                               # impl that actually runs after

@@ -110,17 +110,6 @@ class GAConfig:
     surrogate_min_records: int = 10   # journal rows below which the fit
                                       # abstains and the hand formula stays
     dup_retries: int = 3              # re-mutation attempts per duplicate child
-    objectives: tuple = ("latency",)  # objective axes for selection.  The
-                                      # default single axis keeps the paper's
-                                      # fitness-proportional roulette path
-                                      # byte-identical; a multi-axis tuple
-                                      # (e.g. repro.core.objectives.OBJECTIVES
-                                      # = latency/energy/transfer) makes
-                                      # ga_search build an objective vector fn
-                                      # and run_ga switch to NSGA-style
-                                      # non-dominated + crowding selection,
-                                      # reporting the Pareto front in
-                                      # GAResult.front
 
 
 @dataclass
@@ -162,11 +151,6 @@ class GAResult:
     compile_overlap_saved_s: float = 0.0  # wall-clock saved by overlapping
                                       # warm-up compiles ahead of the serial
                                       # timing loop (EvalStats)
-    front: list = field(default_factory=list)  # Pareto-optimal Evaluations
-                                      # (multi-objective search: every
-                                      # non-dominated measured pattern,
-                                      # sorted fastest-first; single-
-                                      # objective: just [best])
 
     @property
     def speedup_vs_baseline(self) -> float:
@@ -181,94 +165,12 @@ class GAResult:
 
 
 FitnessFn = Callable[[tuple], Evaluation]
-ObjectiveFn = Callable[[Evaluation], tuple]
-
-
-# ---------------------------------------------------------------------------
-# NSGA-style multi-objective selection primitives (Deb et al. 2002)
-# ---------------------------------------------------------------------------
-
-
-def dominates(a: Sequence[float], b: Sequence[float]) -> bool:
-    """Pareto dominance, all axes minimized: ``a`` dominates ``b`` iff it is
-    no worse everywhere and strictly better somewhere.  Totality note: for
-    any pair exactly one of {a dom b, b dom a, neither} holds — equal
-    vectors (and all-inf invalid points) are mutually non-dominating."""
-    assert len(a) == len(b), (len(a), len(b))
-    better = False
-    for x, y in zip(a, b):
-        if x > y:
-            return False
-        if x < y:
-            better = True
-    return better
-
-
-def non_dominated_sort(points: Sequence[Sequence[float]]) -> list[list[int]]:
-    """Fast-ish O(n²) non-dominated sort: index lists per front, front 0
-    first.  Every input index appears in exactly one front."""
-    n = len(points)
-    dominated_by: list[list[int]] = [[] for _ in range(n)]
-    dom_count = [0] * n
-    for i in range(n):
-        for j in range(i + 1, n):
-            if dominates(points[i], points[j]):
-                dominated_by[i].append(j)
-                dom_count[j] += 1
-            elif dominates(points[j], points[i]):
-                dominated_by[j].append(i)
-                dom_count[i] += 1
-    fronts: list[list[int]] = []
-    current = [i for i in range(n) if dom_count[i] == 0]
-    while current:
-        fronts.append(current)
-        nxt = []
-        for i in current:
-            for j in dominated_by[i]:
-                dom_count[j] -= 1
-                if dom_count[j] == 0:
-                    nxt.append(j)
-        current = nxt
-    return fronts
-
-
-def crowding_distances(points: Sequence[Sequence[float]]) -> list[float]:
-    """Crowding distance within one front: boundary points (per-axis min or
-    max) get ``inf`` so selection always preserves the extremes; interior
-    points sum normalized neighbor gaps per axis."""
-    n = len(points)
-    if n == 0:
-        return []
-    if n <= 2:
-        return [float("inf")] * n
-    m = len(points[0])
-    dist = [0.0] * n
-    for ax in range(m):
-        order = sorted(range(n), key=lambda i: points[i][ax])
-        lo, hi = points[order[0]][ax], points[order[-1]][ax]
-        dist[order[0]] = dist[order[-1]] = float("inf")
-        span = hi - lo
-        if span <= 0 or not math.isfinite(span):
-            continue
-        for k in range(1, n - 1):
-            gap = (points[order[k + 1]][ax] - points[order[k - 1]][ax]) / span
-            if math.isfinite(dist[order[k]]):
-                dist[order[k]] += gap
-    return dist
-
-
-def pareto_front(points: Sequence[Sequence[float]]) -> list[int]:
-    """Indices of the non-dominated points (first front), input order kept."""
-    if not points:
-        return []
-    return sorted(non_dominated_sort(points)[0])
 
 
 def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
            log: Optional[Callable[[str], None]] = None,
            evaluator=None, arity: int = 2,
-           seeds: Sequence[Sequence[int]] = (),
-           objective_fn: Optional[ObjectiveFn] = None) -> GAResult:
+           seeds: Sequence[Sequence[int]] = ()) -> GAResult:
     """Search chromosomes of `length`; returns the fastest valid one.
 
     Genes range over ``{0 .. arity-1}`` (2 = the paper's binary CPU/GPU
@@ -284,17 +186,6 @@ def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
     knobs (`workers`, `cache_dir`, `screen_top_k`).  The GAResult measurement
     counters are the evaluator's lifetime totals, so pass a fresh evaluator
     per search if you want per-search numbers.
-
-    ``objective_fn`` switches selection to NSGA-style multi-objective mode:
-    it maps each :class:`Evaluation` to a smaller-is-better float tuple
-    (see :func:`repro.core.objectives.make_objective_fn`), parents are
-    chosen by binary tournament on (non-domination rank, crowding distance)
-    and elites are the rank/crowding-best individuals.  ``best``, patience
-    and the history stay latency-first (objective 0 is latency by
-    convention) so tier-1 semantics are untouched, and
-    :attr:`GAResult.front` reports every non-dominated measured pattern.
-    When ``objective_fn`` is None (the default) the paper's roulette path
-    runs byte-identically to before.
     """
     from repro.core.evaluator import Evaluator  # deferred: avoids import cycle
 
@@ -320,29 +211,12 @@ def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
                               screen_top_k=cfg.screen_top_k,
                               compile_workers=cfg.compile_workers)
 
-    multi = objective_fn is not None
-    archive: dict[tuple, Evaluation] = {}   # every measured pattern (multi)
-
-    def _front_of_archive() -> list[Evaluation]:
-        """Non-dominated subset of every pattern seen, fastest-first."""
-        evs = [e for e in archive.values()
-               if e.valid and math.isfinite(e.time_s)]
-        pts = [objective_fn(e) for e in evs]
-        keep = [k for k in pareto_front(pts)
-                if all(math.isfinite(v) for v in pts[k])]
-        return sorted((evs[k] for k in keep), key=lambda e: e.time_s)
-
     def finish(best, history, baseline) -> GAResult:
         st = evaluator.stats
         corr = getattr(evaluator, "surrogate_rank_correlation",
                        lambda: float("nan"))()
         if owns_evaluator:
             evaluator.close()
-        if multi:
-            front = _front_of_archive()
-        else:
-            front = [best] if best.valid and math.isfinite(best.time_s) \
-                else []
         return GAResult(
             best, history, evaluations=st.measurements,
             cache_hits=st.cache_hits + st.inflight_hits,
@@ -353,14 +227,11 @@ def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
             eval_wall_s=st.eval_wall_s,
             surrogate_rank_corr=corr,
             compile_overlap_saved_s=getattr(st, "compile_overlap_saved_s",
-                                            0.0),
-            front=front)
+                                            0.0))
 
     dup_avoided = 0
     if length == 0:
         ev = evaluator.evaluate(())
-        if multi:
-            archive[ev.bits] = ev
         return finish(ev, [], ev)
 
     def _remutate(chromo: list, pos: int) -> None:
@@ -408,11 +279,6 @@ def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
                 else float("inf"),
                 "n_invalid": sum(1 for e in evals if not e.valid),
             }
-            if multi:
-                for p, e in zip(pop, evals):
-                    archive[p] = e
-                entry["front_size"] = len(_front_of_archive())
-                obs_metrics.gauge("ga.front_size").set(entry["front_size"])
             history.append(entry)
             gspan.set(**history[-1])
         obs_metrics.counter("ga.generations").inc()
@@ -424,53 +290,19 @@ def run_ga(length: int, fitness_fn: FitnessFn, cfg: GAConfig,
         if cfg.patience is not None and stale >= cfg.patience:
             break
 
-        if not multi:
-            # --- selection: fitness-proportional (roulette) ----------------
-            fits = np.array([e.fitness for e in evals])
-            if fits.sum() <= 0:
-                probs = np.full(len(pop), 1.0 / len(pop))
-            else:
-                probs = fits / fits.sum()
-
-            ranked = sorted(zip(pop, evals), key=lambda pe: pe[1].time_s)
-            next_pop: list[tuple] = [p for p, _ in ranked[: cfg.elite]]
-            proposed = set(next_pop)                              # elite copy
-
-            def draw_parents() -> tuple[int, int]:
-                i, j = rng.choice(len(pop), size=2, p=probs)
-                return int(i), int(j)
+        # --- selection: fitness-proportional (roulette) --------------------
+        fits = np.array([e.fitness for e in evals])
+        if fits.sum() <= 0:
+            probs = np.full(len(pop), 1.0 / len(pop))
         else:
-            # --- NSGA selection: non-domination rank + crowding ------------
-            pts = [objective_fn(e) for e in evals]
-            rank = [0] * len(pop)
-            crowd = [0.0] * len(pop)
-            for r, fr in enumerate(non_dominated_sort(pts)):
-                fr_dist = crowding_distances([pts[i] for i in fr])
-                for i, d in zip(fr, fr_dist):
-                    rank[i] = r
-                    crowd[i] = d
-            order = sorted(range(len(pop)),
-                           key=lambda i: (rank[i], -crowd[i]))
-            next_pop = []
-            for i in order:           # elites: best by (rank, crowding),
-                if pop[i] not in next_pop:          # distinct patterns only
-                    next_pop.append(pop[i])
-                if len(next_pop) >= cfg.elite:
-                    break
-            proposed = set(next_pop)
+            probs = fits / fits.sum()
 
-            def _tourney() -> int:
-                """Binary tournament: lower rank wins, crowding breaks ties
-                (prefer the less crowded — keeps front spread)."""
-                i, j = (int(v) for v in rng.integers(0, len(pop), size=2))
-                return i if (rank[i], -crowd[i]) <= (rank[j], -crowd[j]) \
-                    else j
-
-            def draw_parents() -> tuple[int, int]:
-                return _tourney(), _tourney()
+        ranked = sorted(zip(pop, evals), key=lambda pe: pe[1].time_s)
+        next_pop: list[tuple] = [p for p, _ in ranked[: cfg.elite]]
+        proposed = set(next_pop)                                  # elite copy
 
         while len(next_pop) < cfg.population:
-            i, j = draw_parents()
+            i, j = (int(v) for v in rng.choice(len(pop), size=2, p=probs))
             a, b = list(pop[i]), list(pop[j])
             if rng.random() < cfg.crossover_rate and length > 1:
                 cut = int(rng.integers(1, length))

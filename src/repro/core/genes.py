@@ -5,49 +5,37 @@ The paper's chromosome is a binary string, one gene per offloadable region:
 ``1`` = run the region on the accelerator, ``0`` = keep the reference path.
 This module keeps that encoding as the default while letting a gene range
 over a *destination alphabet* — an ordered tuple of :class:`Destination`
-names such as ``("cpu", "gpu", "fpga_stub")``.  Gene value ``k`` assigns the
-region to alphabet entry ``k``; value 0 is always the reference (CPU) path
-and value 1 the primary accelerator, so binary chromosomes keep their exact
-historical meaning.
+names such as ``("cpu", "gpu_fused", "gpu_pallas")``.  Gene value ``k``
+assigns the region to alphabet entry ``k``; value 0 is always the reference
+(CPU) path and value 1 the primary accelerator, so binary chromosomes keep
+their exact historical meaning.
 
-Destinations are pluggable via :func:`register_destination`.  A destination
-may be *cost-only* (``is_cost_only``): regions assigned to it execute
-their reference implementation for correctness, and a deterministic modeled
-cost (:func:`modeled_cost_s`) is charged on top of the measurement — so the
-enlarged search space is real (the GA weighs it) before the hardware exists.
-The encoding stays language/frontend-independent; frontends only contribute
-the ordered site list.
+Destinations are pluggable via :func:`register_destination`.  Every
+destination executes: a gene's fitness is the measured time of the program
+it decodes to.  The encoding stays language/frontend-independent; frontends
+only contribute the ordered site list.
 
-Destination API v2 is a small frozen hierarchy: :class:`Device` is a single
+The destination hierarchy is small and frozen: :class:`Device` is a single
 physical device; :class:`MeshDestination` places a region on an ``n``-device
 mesh along a named axis with a sharding spec (arXiv 2011.12431's mixed
 offloading destinations, extended to placement × parallelism).  The wire
 format IS the destination name (``mesh:data:4:batch``), so alphabets,
 SeedBank records, phenotype keys, and PlanStore payloads — all of which
-carry name strings — round-trip mesh specs with no schema change.  On hosts
-with fewer than ``n`` devices a mesh destination degrades to cost-only:
-reference execution plus a modeled per-shard-transfer + collective cost
-(:func:`repro.core.transfer_planner.modeled_mesh_cost_s`).
+carry name strings — round-trip mesh specs with no schema change.  A mesh
+wider than the host is never searched: frontends propose only the meshes
+this host can build, and an explicit alphabet naming a wider one is refused
+(:func:`repro.core.frontends.registry.resolve_alphabet`).
 """
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Optional, Sequence
 
-import numpy as np
-
-from repro.core.ir import Region, RegionGraph
+from repro.core.ir import RegionGraph
 
 # ---------------------------------------------------------------------------
 # destination alphabet
 # ---------------------------------------------------------------------------
-
-#: modeled watt prior for destinations that declare no ``active_power_w``
-#: (a conventional host CPU package) — the energy model's fallback.
-DEFAULT_ACTIVE_POWER_W = 65.0
-#: modeled per-device watt prior for mesh destinations (GPU-class devices).
-MESH_DEVICE_POWER_W = 250.0
 
 _PROBED_DEVICE_COUNT: Optional[int] = None
 
@@ -55,9 +43,9 @@ _PROBED_DEVICE_COUNT: Optional[int] = None
 def probed_device_count() -> int:
     """How many accelerator-visible devices this process has (cached).
 
-    Mesh destinations compare their ``n`` against this to decide between
-    genuine sharded execution and cost-only modeling.  Falls back to 1
-    when jax is unavailable or backend init fails."""
+    Mesh destinations compare their ``n`` against this to decide whether
+    the host can build them.  Falls back to 1 when jax is unavailable or
+    backend init fails."""
     global _PROBED_DEVICE_COUNT
     if _PROBED_DEVICE_COUNT is None:
         try:
@@ -70,59 +58,32 @@ def probed_device_count() -> int:
 
 @dataclass(frozen=True)
 class Destination:
-    """One place a region can run (Destination API v2 base).
+    """One place a region can run.
 
-    ``executable`` destinations map to a real implementation of the site
-    (``impl_index`` selects it: 0 = reference, 1 = offloaded alternative).
-    Cost-only destinations (``is_cost_only``) execute the reference
-    implementation and charge a modeled time instead — a stand-in device
-    whose cost model keeps the search space honest before hardware exists.
+    ``impl_index`` selects the site implementation the destination runs
+    (0 = reference, 1 = offloaded alternative, 2+ = extra variants).
 
-    The v2 surface every consumer goes through:
+    The surface every consumer goes through:
 
     * ``wire()`` / ``from_wire()`` — the one serialization (the name string)
       used by gene alphabets, SeedBank cross-alphabet mapping, phenotype
       keys, and PlanStore payloads.
-    * ``watts()`` — modeled draw while executing (per-device prior ×
-      ``device_count``), the energy objective's input.
-    * ``is_cost_only`` — whether assignment charges a model instead of
-      running offloaded code *on this host* (environment-dependent for
-      meshes, static for stub devices).
-    * ``placement_tag`` — non-None when the assignment changes the
-      phenotype beyond the decoded impl map (stub parking, mesh placement),
-      so the measurement cache never conflates such chromosomes.
+    * ``placement_tag`` — non-None for a mesh placement, which changes the
+      phenotype beyond the decoded impl map (sharded execution of the
+      reference span), so the measurement cache never conflates such
+      chromosomes with the unsharded reference.
     """
 
     name: str
-    executable: bool = True
     impl_index: int = 0
-    # cost model for cost-only destinations (seconds):
-    launch_overhead_s: float = 0.0     # fixed per-region dispatch/transfer cost
-    per_trip_s: float = 0.0            # modeled cost per (static) loop trip
-    # energy model (repro.core.objectives): watts this destination draws
-    # while it executes a region's trips — the modeled prior behind the
-    # ``energy`` objective on hosts with no power counters.  The shipped
-    # values are deliberately *different* per destination so mixed-
-    # destination Pareto fronts exist on CPU-only CI.
-    active_power_w: float = 0.0
 
-    # -- v2 API ------------------------------------------------------------
     @property
     def device_count(self) -> int:
         return 1
 
     @property
-    def is_cost_only(self) -> bool:
-        return not self.executable
-
-    @property
     def placement_tag(self) -> Optional[str]:
-        return self.name if self.is_cost_only else None
-
-    def watts(self) -> float:
-        per_device = (self.active_power_w if self.active_power_w > 0
-                      else DEFAULT_ACTIVE_POWER_W)
-        return per_device * self.device_count
+        return None
 
     def wire(self) -> str:
         """Stable wire string: the name is the serialization."""
@@ -135,7 +96,7 @@ class Destination:
 
 @dataclass(frozen=True)
 class Device(Destination):
-    """A single physical (or stand-in) device — the scalar v1 alphabet."""
+    """A single-device placement — the scalar alphabet."""
 
 
 @dataclass(frozen=True)
@@ -150,9 +111,7 @@ class MeshDestination(Destination):
 
     Decoding keeps ``impl_index`` 0 (the reference implementation): the
     substitution engine replaces the site's span with a mesh-sharded run of
-    that same span when the host has >= ``n`` devices; otherwise the
-    destination is cost-only and :func:`modeled_cost_s` charges per-shard
-    transfers plus a modeled collective term."""
+    that same span on a host with >= ``n`` devices."""
 
     name: str = ""
     axis: str = "data"
@@ -172,8 +131,6 @@ class MeshDestination(Destination):
                              f"'dimN', got {spec!r}")
         object.__setattr__(self, "spec", spec)
         object.__setattr__(self, "name", f"mesh:{self.axis}:{self.n}:{spec}")
-        if self.active_power_w <= 0:
-            object.__setattr__(self, "active_power_w", MESH_DEVICE_POWER_W)
 
     @property
     def device_count(self) -> int:
@@ -193,13 +150,9 @@ class MeshDestination(Destination):
         return probed_device_count() >= self.n
 
     @property
-    def is_cost_only(self) -> bool:
-        return not self.available()
-
-    @property
     def placement_tag(self) -> Optional[str]:
-        # mesh placement always changes the phenotype (sharded execution or
-        # modeled charge), even when the decoded impl map is the reference
+        # mesh placement changes the phenotype (sharded execution), even
+        # though the decoded impl map is the reference
         return self.name
 
     @classmethod
@@ -215,31 +168,19 @@ class MeshDestination(Destination):
         return cls(axis=parts[1], n=n, spec=parts[3] if len(parts) == 4 else "")
 
 
-CPU = Device("cpu", executable=True, impl_index=0,
-             active_power_w=65.0)
-GPU = Device("gpu", executable=True, impl_index=1,
-             active_power_w=250.0)
-#: FPGA stub: no backend yet — reference execution plus a modeled cost of a
-#: PCIe-attached reconfigurable card (fixed DMA/launch latency, cheap trips,
-#: low board power: the paper's power-saving destination).
-FPGA_STUB = Device("fpga_stub", executable=False, impl_index=0,
-                   launch_overhead_s=2e-4, per_trip_s=5e-8,
-                   active_power_w=30.0)
+CPU = Device("cpu", impl_index=0)
+GPU = Device("gpu", impl_index=1)
 #: variant destinations: same accelerator, different *implementation* of the
 #: site (the kernel-substitution alphabet — a gene picks which code runs).
-GPU_FUSED = Device("gpu_fused", executable=True, impl_index=1,
-                   active_power_w=250.0)
-GPU_PALLAS = Device("gpu_pallas", executable=True, impl_index=2,
-                    active_power_w=220.0)
+GPU_FUSED = Device("gpu_fused", impl_index=1)
+GPU_PALLAS = Device("gpu_pallas", impl_index=2)
 
 _DESTINATIONS: dict[str, Destination] = {
-    d.name: d for d in (CPU, GPU, FPGA_STUB, GPU_FUSED, GPU_PALLAS)
+    d.name: d for d in (CPU, GPU, GPU_FUSED, GPU_PALLAS)
 }
 
 #: the paper's original binary CPU/GPU alphabet — the default everywhere.
 DEFAULT_ALPHABET: tuple[str, ...] = ("cpu", "gpu")
-#: the extended mixed-destination alphabet from the ROADMAP.
-EXTENDED_ALPHABET: tuple[str, ...] = ("cpu", "gpu", "fpga_stub")
 #: the implementation-variant alphabet the measured jaxpr frontend proposes:
 #: gene k selects site implementation k — reference, the fused-jnp rewrite,
 #: or the Pallas kernel (see repro.kernels.registry).
@@ -284,9 +225,8 @@ def mesh_proposals(axes: Sequence[str] = ("data",),
                    device_count: Optional[int] = None) -> tuple[str, ...]:
     """Mesh destination names this host can genuinely execute (n <= devices).
 
-    Returns () on single-device hosts so CI alphabets, fingerprints and
-    committed baselines stay byte-stable; explicit mesh names in
-    ``OffloadConfig.destinations`` still work anywhere (cost-modeled)."""
+    Returns () on single-device hosts, so their alphabets and fingerprints
+    carry no mesh genes."""
     ndev = probed_device_count() if device_count is None else device_count
     return tuple(MeshDestination(axis=axis, n=n).name
                  for axis in axes for n in sizes if 2 <= n <= ndev)
@@ -354,10 +294,9 @@ class GeneCoding:
     def decode(self, values: Sequence[int]) -> dict[str, Any]:
         """values -> {region name: chosen implementation}.
 
-        A cost-only destination decodes to the site implementation its
-        ``impl_index`` names (the reference path for the shipped stubs), so
-        executors run correct code; the modeled cost is charged separately
-        (:func:`modeled_cost_s`).
+        Each gene decodes to the site implementation its destination's
+        ``impl_index`` names, clamped to the site's menu; a mesh gene decodes
+        to the reference path, which the frontend runs sharded.
         """
         assert len(values) == self.length, (len(values), self.length)
         out: dict[str, Any] = {}
@@ -418,68 +357,3 @@ def coding_from_graph(graph: RegionGraph,
         sites.append(Site(r.name, ref, off, tuple(r.alternatives[2:]),
                           members=tuple(r.meta.get("block_members", ()))))
     return GeneCoding(tuple(sites), tuple(destinations))
-
-
-# ---------------------------------------------------------------------------
-# cost model for cost-only destinations
-# ---------------------------------------------------------------------------
-
-
-def _trip_product(graph: RegionGraph, region: Region) -> int:
-    """Static dynamic-trip estimate: own trip count times enclosing loops'."""
-    trips = region.trip_count or 1 if region.kind == "loop" else 1
-    r = region
-    while r.parent is not None:
-        r = graph.by_name(r.parent)
-        if r.kind == "loop":
-            trips *= r.trip_count or 1
-    return trips
-
-
-def site_modeled_cost_s(graph: RegionGraph, region: Region,
-                        dest: Destination) -> float:
-    """Deterministic modeled seconds for parking one region on ``dest``.
-
-    Stub devices charge their launch + per-trip model; mesh destinations
-    charge per-shard transfers plus a modeled collective for the axis
-    (:func:`repro.core.transfer_planner.modeled_mesh_cost_s`), with the
-    region's def/use sets standing in for byte volumes (1.0 each — the
-    same unit-bytes convention the transfer objective uses)."""
-    trips = _trip_product(graph, region)
-    if isinstance(dest, MeshDestination):
-        from repro.core import transfer_planner as tp
-        return tp.modeled_mesh_cost_s(
-            h2d_bytes=float(len(region.uses)),
-            d2h_bytes=float(len(region.defs)),
-            trips=trips, axis=dest.axis, n=dest.n)
-    return dest.launch_overhead_s + trips * dest.per_trip_s
-
-
-def modeled_cost_s(graph: RegionGraph, coding: GeneCoding,
-                   values: Sequence[int],
-                   mesh_executed: bool = False) -> float:
-    """Deterministic modeled time for genes on cost-only destinations.
-
-    Charged on top of the measured time of the chromosome (whose cost-only
-    regions executed their reference path), so patterns that park work on a
-    stub device pay that device's modeled latency in the fitness.
-
-    Mesh genes charge the mesh cost model unless ``mesh_executed`` — the
-    flag a frontend sets when its measured path genuinely decodes mesh
-    destinations as sharded execution (the jaxpr engine on a multi-device
-    host), in which case the measurement already contains the real cost.
-    An unavailable mesh (``is_cost_only``) charges the model regardless.
-    """
-    total = 0.0
-    claimed = coding.claimed_members(values)
-    for site, v in zip(coding.sites, values):
-        if site.region in claimed:
-            continue                 # the block adapter computes this region
-        dest = get_destination(coding.destinations[int(v)])
-        if isinstance(dest, MeshDestination):
-            if mesh_executed and not dest.is_cost_only:
-                continue             # really ran sharded: measured, not modeled
-        elif not dest.is_cost_only:
-            continue
-        total += site_modeled_cost_s(graph, graph.by_name(site.region), dest)
-    return total

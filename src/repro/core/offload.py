@@ -9,7 +9,7 @@ or a bare :class:`RegionGraph` — resolves the registered frontend for it,
 and drives the same pipeline for all of them:
 
   normalize -> build RegionGraph -> function-block pass (pattern DB)
-     -> gene coding over a destination alphabet (CPU/GPU/FPGA-stub, §genes)
+     -> gene coding over a destination alphabet (reference, variants, meshes)
      -> seed the GA population (pattern-DB hits + similarity neighbors)
      -> evaluate through the batching engine (cache, dedup, screening,
         workers / process pool)  -> verify  -> one unified OffloadResult.
@@ -36,8 +36,7 @@ from repro.core.frontends.registry import (FitnessBundle, OffloadConfig,
                                            decoded_pattern, detect_frontend,
                                            get_frontend, resolve_alphabet)
 from repro.core.ga import Evaluation, GAConfig, GAResult, run_ga
-from repro.core.genes import (GeneCoding, coding_from_graph,
-                              get_destination, modeled_cost_s)
+from repro.core.genes import GeneCoding, coding_from_graph, get_destination
 from repro.core.ir import RegionGraph
 from repro.core.transfer_planner import TransferPlan, plan_transfers
 from repro.core.variants import generic_plan_report
@@ -75,12 +74,9 @@ def phenotype_key(coding: GeneCoding,
     Chromosomes that decode to the same program (clamped ``impl_index`` on
     regions with short implementation menus, alphabet entries aliasing the
     same impl) are measured once per *program*, not once per bit string —
-    the ROADMAP's phenotype-dedup.  Destinations whose assignment changes
-    the phenotype beyond the decoded impl map carry a placement tag:
-    cost-only stubs (reference impl + a modeled charge) and mesh
-    destinations (reference impl, but sharded execution or a modeled mesh
-    charge), so parking a gene there is a different phenotype than leaving
-    it on the reference path.
+    the ROADMAP's phenotype-dedup.  Mesh destinations carry a placement tag
+    (reference impl, but sharded execution), so placing a gene on a mesh is
+    a different phenotype than leaving it on the reference path.
 
     ``resolver`` folds the frontend's *bind results* into the key
     (ROADMAP's resolution-fallback slice): ``resolver(region, impl_id)``
@@ -108,9 +104,9 @@ def phenotype_key(coding: GeneCoding,
             return ("raw", bits)
         impl = coding.decode(bits)
         # regions claimed by an active block gene are inert: their decoded
-        # impl is already forced to ref by decode(), and a stub destination
-        # parked on them charges nothing (modeled_cost_s skips them), so
-        # they must not split phenotypes either
+        # impl is already forced to ref by decode() and the block adapter
+        # computes their span, so a placement on them must not split
+        # phenotypes either
         claimed = coding.claimed_members(bits)
         tags = tuple((s.region, dests[int(v)].placement_tag)
                      for s, v in zip(coding.sites, bits)
@@ -131,8 +127,7 @@ def ga_search(graph: RegionGraph, fitness_fn: Callable[[tuple], Evaluation],
               cache_extra: str = "",
               evaluator: Optional[Evaluator] = None,
               seeds: Sequence[Sequence[int]] = (),
-              impl_resolver: Optional[Callable[[str, Any], Any]] = None,
-              objective_fn: Optional[Callable[[Evaluation], tuple]] = None
+              impl_resolver: Optional[Callable[[str, Any], Any]] = None
               ) -> tuple[GeneCoding, GAResult]:
     """Run the GA over a graph's unclaimed offloadable regions.
 
@@ -155,28 +150,13 @@ def ga_search(graph: RegionGraph, fitness_fn: Callable[[tuple], Evaluation],
     ``impl_resolver`` (usually ``FitnessBundle.impl_resolver``) folds the
     frontend's bind results into the phenotype key, so chromosomes whose
     variants fall back to the same implementation share one measurement.
-
-    A multi-axis ``cfg.objectives`` tuple (e.g.
-    :data:`repro.core.objectives.OBJECTIVES`) switches ``run_ga`` to
-    NSGA-style Pareto selection: an objective-vector function is built from
-    the graph/coding (or taken from ``objective_fn``), every new
-    measurement is annotated with per-objective detail fields so the
-    journal learns them, and — with a ``cache_dir`` — one ridge surrogate
-    per extra objective is fitted and persisted after the search (screening
-    itself stays latency-ranked).
     """
-    from repro.core import objectives as objmod
-
     cfg = ga_cfg or GAConfig()
     if coding is None:
         # bare ga_search has no config/frontend in scope: the precedence
         # helper resolves to the default alphabet (one rule everywhere)
         coding = coding_from_graph(graph, exclude=exclude,
                                    destinations=resolve_alphabet(None))
-    multi = len(tuple(cfg.objectives)) > 1 or objective_fn is not None
-    if multi and objective_fn is None:
-        objective_fn = objmod.make_objective_fn(graph, coding,
-                                                cfg.objectives)
     owns = evaluator is None
     pool: Optional[ProcessPool] = None
     fingerprint = ""
@@ -220,9 +200,7 @@ def ga_search(graph: RegionGraph, fitness_fn: Callable[[tuple], Evaluation],
                       surrogate=surrogate, screen_top_k=top_k,
                       phenotype_key=phenotype_key(coding,
                                                   resolver=impl_resolver),
-                      compile_workers=cfg.compile_workers,
-                      annotate=objmod.annotate_objectives(graph, coding)
-                      if multi else None)
+                      compile_workers=cfg.compile_workers)
         if cfg.pool is not None:
             pool = ProcessPool(cfg.pool, workers=cfg.workers or None)
             evaluator = Evaluator(None, **pool.evaluator_kwargs(), **common)
@@ -230,8 +208,7 @@ def ga_search(graph: RegionGraph, fitness_fn: Callable[[tuple], Evaluation],
             evaluator = Evaluator(fitness_fn, workers=cfg.workers, **common)
     try:
         ga = run_ga(coding.length, fitness_fn, cfg, log=log,
-                    evaluator=evaluator, arity=coding.arity, seeds=seeds,
-                    objective_fn=objective_fn if multi else None)
+                    evaluator=evaluator, arity=coding.arity, seeds=seeds)
         ga = dataclasses.replace(ga, surrogate_kind=surrogate_kind)
         if owns and cfg.cache_dir and ga.screened_out == 0:
             # only unscreened searches are evidence: a screened search
@@ -242,16 +219,6 @@ def ga_search(graph: RegionGraph, fitness_fn: Callable[[tuple], Evaluation],
                                ga.surrogate_rank_corr,
                                horizon_s=cfg.auto_screen_horizon_s,
                                kind=surrogate_kind)
-        if owns and multi and cfg.cache_dir and cfg.fit_surrogate:
-            # per-objective ridge fits from the (now annotated) journal —
-            # persisted for inspection/screening evidence, one model per
-            # extra objective from the same measurement rows
-            from repro.core.surrogate import fit_surrogate
-            for obj in tuple(cfg.objectives):
-                if obj != "latency":
-                    fit_surrogate(graph, coding, cfg.cache_dir, fingerprint,
-                                  min_records=cfg.surrogate_min_records,
-                                  objective=obj)
     finally:
         if owns:
             evaluator.close()
@@ -270,9 +237,9 @@ def _map_destination_value(value: int, rec_destinations: Sequence[str],
     """Translate one recorded gene value into the current alphabet.
 
     Cross-destination mapping (ROADMAP): a neighbor searched over a
-    *different* alphabet (a GPU gene seeding an FPGA search, a binary gene
-    seeding a variant search).  The recorded *destination name* is looked up
-    in the current alphabet; a name the alphabet lacks maps by intent —
+    *different* alphabet (a binary gene seeding a variant search, a mesh
+    gene seeding a host without that mesh).  The recorded *destination
+    name* is looked up in the current alphabet; a name the alphabet lacks maps by intent —
     reference stays reference, anything offloaded maps to the current
     primary accelerator (index 1) so the warm start preserves the on/off
     shape of the neighbor's pattern.  Legacy records without destination
@@ -458,52 +425,6 @@ class OffloadResult:
             "compile_overlap_saved_s": g.compile_overlap_saved_s,
         }
 
-    @property
-    def front(self) -> list[Evaluation]:
-        """The search's Pareto-optimal Evaluations (multi-objective mode;
-        single-objective searches report just the best)."""
-        return self.ga.front
-
-    def front_summary(self) -> list[dict]:
-        """JSON-safe Pareto front: one dict per non-dominated pattern with
-        its bits and per-objective values (persisted into PlanRecord so a
-        service can swap operating points without a new search).  Latency
-        comes from the measurement; energy/transfer prefer the annotated
-        detail fields and fall back to the objective models."""
-        from repro.core import objectives as objmod
-
-        out = []
-        for ev in self.ga.front:
-            vals = objmod.objective_values(ev, self.graph, self.coding)
-            out.append({
-                "bits": [int(v) for v in ev.bits],
-                "latency_s": float(vals[0]),
-                "energy_j": float(vals[1]),
-                "transfer_bytes": float(vals[2]),
-            })
-        return out
-
-    def operating_point(self, objective: str = "latency") -> Evaluation:
-        """The front point optimal on one axis (an operating point a
-        service picks per traffic level: ``latency`` under load,
-        ``energy`` when idle).  Ties break toward lower latency; an empty
-        front (single-objective search) returns ``best``."""
-        from repro.core import objectives as objmod
-
-        if not self.ga.front:
-            return self.best
-        try:
-            ax = objmod.OBJECTIVES.index(objective)
-        except ValueError:
-            raise ValueError(f"unknown objective {objective!r}; known: "
-                             f"{objmod.OBJECTIVES}") from None
-        key = {}
-        for ev in self.ga.front:
-            key[id(ev)] = objmod.objective_values(ev, self.graph,
-                                                  self.coding)
-        return min(self.ga.front,
-                   key=lambda e: (key[id(e)][ax], key[id(e)][0]))
-
     def summary(self) -> dict:
         return {
             "frontend": self.frontend,
@@ -514,7 +435,6 @@ class OffloadResult:
             "verified": self.verification.get("verified", False),
             "substituted": dict(self.report.substituted) if self.report
             else {},
-            "front_size": len(self.ga.front),
             **self.savings,
         }
 
@@ -522,58 +442,6 @@ class OffloadResult:
 # ---------------------------------------------------------------------------
 # the pipeline
 # ---------------------------------------------------------------------------
-
-
-class _DestinationCostFitness:
-    """Charge cost-only destinations' modeled time on top of measurements,
-    preserving the inner fitness's two-phase (prepare/measure) protocol so
-    the compile-overlap path still applies."""
-
-    def __init__(self, graph: RegionGraph, coding: GeneCoding,
-                 inner: Callable, mesh_executed: bool = False):
-        self._graph, self._coding, self._inner = graph, coding, inner
-        self._mesh_executed = mesh_executed
-
-    def _charge(self, ev: Evaluation) -> Evaluation:
-        pen = modeled_cost_s(self._graph, self._coding, ev.bits,
-                             mesh_executed=self._mesh_executed)
-        if pen > 0 and math.isfinite(ev.time_s):
-            ev = Evaluation(ev.bits, ev.time_s + pen, ev.valid,
-                            {**ev.detail, "modeled_cost_s": pen})
-        return ev
-
-    def __call__(self, values: tuple) -> Evaluation:
-        return self._charge(self._inner(tuple(values)))
-
-
-class _TwoPhaseDestinationCostFitness(_DestinationCostFitness):
-    def prepare(self, values: tuple):
-        return self._inner.prepare(tuple(values))
-
-    def measure(self, prepared) -> Evaluation:
-        return self._charge(self._inner.measure(prepared))
-
-
-def _with_destination_costs(graph: RegionGraph, coding: GeneCoding,
-                            fitness_fn: Callable,
-                            mesh_executed: bool = False) -> Callable:
-    """Charge modeled destination time on top of measurements: cost-only
-    stubs always, mesh genes unless the frontend's measured path genuinely
-    decodes them to sharded execution (``mesh_executed``, from
-    :attr:`FitnessBundle.mesh_executed`)."""
-    dests = [get_destination(d) for d in coding.destinations]
-
-    def may_charge(d) -> bool:
-        if d.placement_tag is None:
-            return False               # plain executable device: measured
-        return not (mesh_executed and not d.is_cost_only)
-
-    if not any(may_charge(d) for d in dests):
-        return fitness_fn
-    cls = _TwoPhaseDestinationCostFitness \
-        if hasattr(fitness_fn, "prepare") and hasattr(fitness_fn, "measure") \
-        else _DestinationCostFitness
-    return cls(graph, coding, fitness_fn, mesh_executed=mesh_executed)
 
 
 @dataclass
@@ -696,8 +564,7 @@ class Offloader:
             res = self._search(ctx, ga, extra_seeds)
             sp.set(best_time_s=res.best.time_s,
                    evaluations=res.ga.evaluations,
-                   generations=len(res.ga.history),
-                   front_size=len(res.ga.front))
+                   generations=len(res.ga.history))
             return res
 
     def _search(self, ctx: PlanContext, ga: Optional[GAConfig],
@@ -709,8 +576,6 @@ class Offloader:
         graph, bundle, coding = ctx.graph, ctx.bundle, ctx.coding
 
         fitness = cfg.fitness_fn or bundle.fitness_factory(coding)
-        fitness = _with_destination_costs(graph, coding, fitness,
-                                          mesh_executed=bundle.mesh_executed)
 
         ga_cfg = ga or cfg.ga
         if bundle.serial_only and (ga_cfg.workers > 1
@@ -731,8 +596,8 @@ class Offloader:
         if ga_cfg.pool is not None:
             # pool workers rebuild their fitness from the registered factory
             # and cannot see the fitness this pipeline just composed (block
-            # claims folded into base_impl, gene exclusions, destination
-            # costs, cfg.fitness_fn) — measuring one function while planning
+            # claims folded into base_impl, gene exclusions,
+            # cfg.fitness_fn) — measuring one function while planning
             # another would silently corrupt the result
             raise ValueError(
                 "GAConfig.pool cannot be used through Offloader.plan: the "

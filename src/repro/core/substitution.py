@@ -395,12 +395,13 @@ class SubstitutionEngine:
             dname = (destinations or {}).get(site.region)
             if dname and dname.startswith("mesh:"):
                 mesh_dest = get_destination(dname)
-                if mesh_dest.is_cost_only:
+                if not mesh_dest.available():
+                    # a stored plan from a larger host: run the variant
                     adapter, chosen, why = self._resolve_variant(site,
                                                                  requested)
                     why = (f"mesh {mesh_dest.name!r} unavailable "
                            f"({probed_device_count()} device(s) < "
-                           f"{mesh_dest.n}): modeled cost charged; {why}")
+                           f"{mesh_dest.n}); {why}")
                 else:
                     adapter, mesh_why = self._mesh_adapter(site, mesh_dest)
                     if adapter is not None:

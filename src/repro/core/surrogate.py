@@ -12,8 +12,8 @@ not static heuristics.  This module is that evidence loop closed:
   machinery the hand formula uses (the transfer planner), but kept separate
   per signal instead of collapsed into one number: per-destination gene
   counts, H2D/D2H transfer counts, byte volume, round-trip products of
-  per-iteration transfers, offloaded-region trip products, modeled stub
-  cost — plus the hand formula's own score as the *prior feature*.
+  per-iteration transfers, offloaded-region trip products — plus the hand
+  formula's own score as the *prior feature*.
 * :func:`fit_surrogate` — ridge / least-squares regression of those features
   against the persisted measurement journal
   (``measurements_{fingerprint}.jsonl``, written by
@@ -29,6 +29,8 @@ not static heuristics.  This module is that evidence loop closed:
 * coefficient persistence — fits journal to ``surrogate_fit.jsonl`` beside
   ``search_meta.jsonl`` (newest-per-fingerprint compaction under the same
   flock idiom), so fitted models are inspectable and survive the process.
+  Records are for inspection only: every search refits from the measurement
+  journal, and :func:`load_fit` ignores records of another schema.
 
 Like the static formula, a fitted surrogate only ever *ranks* offspring for
 the pre-screen — measurement stays the final arbiter (the paper's
@@ -38,14 +40,13 @@ from __future__ import annotations
 
 import math
 import os
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-from repro.core.genes import (GeneCoding, MeshDestination, _trip_product,
-                              get_destination, modeled_cost_s)
-from repro.core.ir import RegionGraph
+from repro.core.genes import GeneCoding, MeshDestination, get_destination
+from repro.core.ir import Region, RegionGraph
 from repro.core.transfer_planner import plan_transfers
 
 __all__ = ["FeatureExtractor", "FittedSurrogate", "fit_surrogate",
@@ -53,6 +54,10 @@ __all__ = ["FeatureExtractor", "FittedSurrogate", "fit_surrogate",
 
 SURROGATE_FIT_FILE = "surrogate_fit.jsonl"
 _FIT_MAX_LINES = 256
+#: version of the persisted fit record; records of another version (or
+#: none: those written with a ``stub_cost`` feature or per-objective fits)
+#: carry other feature names and are ignored by :func:`load_fit`
+_FIT_SCHEMA = 2
 
 
 # ---------------------------------------------------------------------------
@@ -92,6 +97,17 @@ def spearman_rank_corr(score: Sequence[float], t: Sequence[float]) -> float:
 # ---------------------------------------------------------------------------
 
 
+def _trip_product(graph: RegionGraph, region: Region) -> int:
+    """Static dynamic-trip estimate: own trip count times enclosing loops'."""
+    trips = region.trip_count or 1 if region.kind == "loop" else 1
+    r = region
+    while r.parent is not None:
+        r = graph.by_name(r.parent)
+        if r.kind == "loop":
+            trips *= r.trip_count or 1
+    return trips
+
+
 class FeatureExtractor:
     """chromosome -> feature vector, from the same pure-IR signals the hand
     formula collapses into one score.
@@ -106,9 +122,8 @@ class FeatureExtractor:
     * ``round_trips``    — dynamic trip product summed over per-iteration
       transfers (the paper's CPU↔accelerator round-trip penalty)
     * ``hoisted``        — transfers the planner pulled out of loops
-    * ``offload_trips``  — trip products of regions placed on an executable
+    * ``offload_trips``  — trip products of regions placed on an
       accelerator destination (how much work the pattern offloads)
-    * ``stub_cost``      — modeled seconds charged by cost-only destinations
     * ``block_active``   — function-block genes on an accelerated variant
       (each replaces its whole member span with one library call)
     * ``block_claimed``  — regions claimed by active block genes: their own
@@ -139,7 +154,7 @@ class FeatureExtractor:
                       for s in coding.sites}
         self.feature_names: tuple[str, ...] = tuple(
             ["prior", "h2d", "d2h", "bytes", "round_trips", "hoisted",
-             "offload_trips", "stub_cost", "block_active", "block_claimed",
+             "offload_trips", "block_active", "block_claimed",
              "mesh_genes", "mesh_devices", "mesh_model_axis"]
             + [f"dest{k}" for k in range(1, coding.arity)]
             + [f"site{i}@{k}" for i in range(coding.length)
@@ -175,12 +190,9 @@ class FeatureExtractor:
         claimed = coding.claimed_members(bits)
         offload_trips = sum(
             self._trip[s.region] for s, v in zip(coding.sites, bits)
-            if int(v) != 0 and not self._dests[int(v)].is_cost_only
-            and s.region not in claimed)
+            if int(v) != 0 and s.region not in claimed)
         n_block = sum(1 for s in coding.sites
                       if s.members and impl.get(s.region) != s.ref_impl)
-        stub = modeled_cost_s(graph, coding, bits) \
-            if any(d.placement_tag is not None for d in self._dests) else 0.0
         mesh_genes = mesh_devices = mesh_model = 0.0
         for s, v in zip(coding.sites, bits):
             d = self._dests[int(v)]
@@ -195,7 +207,7 @@ class FeatureExtractor:
         vec = np.asarray(
             [float(self.prior(bits)), float(n_h2d), float(n_d2h),
              total_bytes,
-             round_trips, float(n_hoist), float(offload_trips), stub,
+             round_trips, float(n_hoist), float(offload_trips),
              float(n_block), float(len(claimed)),
              mesh_genes, mesh_devices, mesh_model]
             + [float(c) for c in dest_counts] + onehot)
@@ -229,12 +241,6 @@ class FittedSurrogate:
     n_val: int = 0                        # held-out rows (0 = LOO was used)
     fingerprint: str = ""
     kind: str = "fitted"
-    objective: str = "latency"            # which journal column the fit
-                                          # predicts: measured seconds
-                                          # ("latency") or a per-objective
-                                          # detail field ("energy",
-                                          # "transfer") — one ridge model
-                                          # per objective, same journal
 
     def __call__(self, bits: tuple) -> float:
         x = (self.extractor(bits) - self.mean) / self.scale
@@ -259,33 +265,17 @@ class FittedSurrogate:
                 for n, c in zip(self.extractor.feature_names, self.coef)}
 
 
-#: objective name -> journal detail field holding its measured value
-#: (``None`` = the row's ``time_s`` itself).  Rows written before PR 9
-#: carry no per-objective fields; they simply drop out of non-latency
-#: fits (graceful latency-only degradation) instead of poisoning them.
-_OBJECTIVE_FIELDS: dict[str, Optional[str]] = {
-    "latency": None, "energy": "energy_j", "transfer": "transfer_bytes",
-}
-
-
-def _journal_rows(cache_dir: str, fingerprint: str, coding: GeneCoding,
-                  objective: str = "latency") -> list[tuple[tuple, float]]:
-    """(bits, measured objective value) for every finite valid measurement
-    of this fingerprint whose chromosome fits the current coding.  Unknown
-    objective names read the detail field of that name directly."""
+def _journal_rows(cache_dir: str, fingerprint: str,
+                  coding: GeneCoding) -> list[tuple[tuple, float]]:
+    """(bits, measured seconds) for every finite valid measurement of this
+    fingerprint whose chromosome fits the current coding."""
     from repro.core.evaluator import MeasurementCache
 
-    field_name = _OBJECTIVE_FIELDS.get(objective, objective)
-    rows: list[tuple[tuple, float]] = []
-    for bits, ev in MeasurementCache(cache_dir, fingerprint).load().items():
-        if not (ev.valid and math.isfinite(ev.time_s)
-                and len(bits) == coding.length
-                and all(0 <= int(v) < coding.arity for v in bits)):
-            continue
-        y = ev.time_s if field_name is None else ev.detail.get(field_name)
-        if isinstance(y, (int, float)) and math.isfinite(y):
-            rows.append((bits, float(y)))
-    return rows
+    return [(bits, float(ev.time_s)) for bits, ev
+            in MeasurementCache(cache_dir, fingerprint).load().items()
+            if ev.valid and math.isfinite(ev.time_s)
+            and len(bits) == coding.length
+            and all(0 <= int(v) < coding.arity for v in bits)]
 
 
 def fit_surrogate(graph: RegionGraph, coding: GeneCoding, cache_dir: str,
@@ -294,8 +284,7 @@ def fit_surrogate(graph: RegionGraph, coding: GeneCoding, cache_dir: str,
                   min_records: int = 10, ridge: float = 1e-2,
                   var_bytes: Optional[dict] = None,
                   base_impl: Optional[dict] = None,
-                  persist: bool = True,
-                  objective: str = "latency") -> Optional[FittedSurrogate]:
+                  persist: bool = True) -> Optional[FittedSurrogate]:
     """Fit a ridge regression of chromosome features against the persisted
     measurement journal for ``fingerprint``.
 
@@ -304,12 +293,6 @@ def fit_surrogate(graph: RegionGraph, coding: GeneCoding, cache_dir: str,
     ranking signal.  Otherwise the fit is journaled to
     ``{cache_dir}/surrogate_fit.jsonl`` (beside ``search_meta.jsonl``) and
     returned with both models' journal rank correlations attached.
-
-    ``objective`` selects the journal column predicted: the default
-    ``"latency"`` fits measured seconds (the historical behavior); the
-    multi-objective search additionally fits ``"energy"`` / ``"transfer"``
-    against the per-objective detail fields the annotate hook journals —
-    one ridge model per objective from the same measurement rows.
     """
     from repro.core.evaluator import transfer_cost_surrogate
 
@@ -317,7 +300,7 @@ def fit_surrogate(graph: RegionGraph, coding: GeneCoding, cache_dir: str,
         prior = transfer_cost_surrogate(graph, coding,
                                         var_bytes=var_bytes,
                                         base_impl=base_impl)
-    rows = _journal_rows(cache_dir, fingerprint, coding, objective)
+    rows = _journal_rows(cache_dir, fingerprint, coding)
     if len(rows) < max(3, int(min_records)):
         return None
     extractor = FeatureExtractor(graph, coding, prior,
@@ -379,7 +362,7 @@ def fit_surrogate(graph: RegionGraph, coding: GeneCoding, cache_dir: str,
         extractor=extractor, coef=coef, intercept=y_mean,
         mean=mean, scale=scale, n_records=len(rows),
         rank_corr=rank_corr, static_rank_corr=static_rank_corr,
-        n_val=n_val, fingerprint=fingerprint, objective=objective)
+        n_val=n_val, fingerprint=fingerprint)
     if persist:
         _save_fit(cache_dir, fitted)
     return fitted
@@ -396,8 +379,8 @@ def _save_fit(cache_dir: str, fit: FittedSurrogate) -> None:
     os.makedirs(cache_dir, exist_ok=True)
     journal = Journal(os.path.join(cache_dir, SURROGATE_FIT_FILE))
     rec = {
+        "schema": _FIT_SCHEMA,
         "fingerprint": fit.fingerprint,
-        "objective": fit.objective,
         "n_records": fit.n_records,
         "n_val": fit.n_val,
         "rank_corr": fit.rank_corr if math.isfinite(fit.rank_corr) else None,
@@ -415,24 +398,23 @@ def _save_fit(cache_dir: str, fit: FittedSurrogate) -> None:
             return
         journal.rewrite(
             newest_per_key(journal.records(),
-                           key=lambda r: (r.get("fingerprint"),
-                                          r.get("objective", "latency")),
+                           key=lambda r: r.get("fingerprint"),
                            max_records=_FIT_MAX_LINES),
             locked=False)
 
 
-def load_fit(cache_dir: str, fingerprint: str,
-             objective: str = "latency") -> Optional[dict]:
-    """Most recent persisted fit record for a (fingerprint, objective)
-    (coefficients by feature name, journal size, both rank correlations) —
-    the inspection entry point; returns None when nothing was ever fitted.
-    Records from before per-objective fits count as latency fits."""
+def load_fit(cache_dir: str, fingerprint: str) -> Optional[dict]:
+    """Most recent persisted fit record for a fingerprint (coefficients by
+    feature name, journal size, both rank correlations) — the inspection
+    entry point; returns None when nothing of the current schema was ever
+    fitted.  A record of an older schema names other features (or another
+    objective's fit), so it is skipped, never read as this one."""
     from repro.journal import Journal
 
     out: Optional[dict] = None
     for rec in Journal(os.path.join(cache_dir, SURROGATE_FIT_FILE)).records():
         if rec.get("fingerprint") == fingerprint \
-                and rec.get("objective", "latency") == objective:
+                and rec.get("schema") == _FIT_SCHEMA:
             out = rec
     if out is not None:
         out = dict(out)

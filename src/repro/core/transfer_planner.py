@@ -198,47 +198,3 @@ def plan_transfers(graph: RegionGraph, impl: dict[str, str],
 
     walk([r for r in graph.regions])
     return plan
-
-
-# ---------------------------------------------------------------------------
-# mesh cost model (deterministic priors for MeshDestination genes)
-# ---------------------------------------------------------------------------
-
-#: per-link host<->device bandwidth prior (PCIe-class, bytes/s) — each of a
-#: mesh's n links moves its own shard, so h2d/d2h volume divides by n.
-MESH_LINK_BYTES_PER_S = 12e9
-#: intra-mesh collective bandwidth prior (NVLink/ICI-class, bytes/s).
-MESH_COLLECTIVE_BYTES_PER_S = 50e9
-#: fixed per-launch mesh dispatch cost, charged once per device per trip.
-MESH_LAUNCH_OVERHEAD_S = 5e-5
-
-
-def collective_factor(axis: str, n: int) -> float:
-    """Modeled collective volume as a multiple of the region's output bytes.
-
-    The ring bound: an all-gather (data axis, assembling sharded outputs)
-    moves (n-1)/n of the tensor per device; a model-axis placement pays a
-    reduce-scatter *and* an all-gather to recombine partials — twice that.
-    """
-    if n <= 1:
-        return 0.0
-    base = (n - 1) / n
-    return base * (2.0 if axis == "model" else 1.0)
-
-
-def modeled_mesh_cost_s(h2d_bytes: float, d2h_bytes: float, trips: int,
-                        axis: str, n: int) -> float:
-    """Deterministic modeled seconds for running a region on an n-mesh.
-
-    Per-shard transfers (volume / n over the per-link bandwidth) + the
-    axis's collective term over output bytes + a per-device launch
-    overhead, all scaled by the static trip estimate.  This is the mesh
-    analogue of the fpga_stub launch/per-trip model: what
-    :func:`repro.core.genes.modeled_cost_s` charges when a mesh gene is
-    not genuinely executed on this host."""
-    if n <= 0:
-        return 0.0
-    per_trip = ((h2d_bytes + d2h_bytes) / max(n, 1) / MESH_LINK_BYTES_PER_S
-                + collective_factor(axis, n) * d2h_bytes
-                / MESH_COLLECTIVE_BYTES_PER_S)
-    return trips * per_trip + n * MESH_LAUNCH_OVERHEAD_S

@@ -174,8 +174,8 @@ def generic_plan_report(coding, values, base_impl: Optional[dict] = None,
 
     For frontends whose implementations are plain ids with no binding step
     (module ExecPlan values, ir impl maps): one choice per gene site —
-    reference-decoding genes report ``ref``, cost-only destinations report
-    the destination name falling back to ``ref``, clamped ``impl_index``
+    reference-decoding genes report ``ref``, mesh destinations report
+    the destination name over the ``ref`` decode, clamped ``impl_index``
     records the clamp — plus one choice per block-pass claim.
     """
     from repro.core.genes import get_destination
@@ -192,12 +192,10 @@ def generic_plan_report(coding, values, base_impl: Optional[dict] = None,
                    f"({len(impls)} impls)")
         is_ref = impl == s.ref_impl or str(impl) in _REF_IMPLS
         if dest.placement_tag is not None:
-            # stub devices and mesh placements: the decode is the reference
-            # path, the destination name is what the gene actually chose
+            # mesh placements: the decode is the reference path, the
+            # destination name is what the gene actually chose
             requested = dest.name
-            why = (f"cost-only destination {dest.name!r} runs the reference "
-                   f"path" if dest.is_cost_only else
-                   f"mesh destination {dest.name!r} (sharded execution is "
+            why = (f"mesh destination {dest.name!r} (sharded execution is "
                    f"the frontend's to realize)")
         elif is_ref:
             requested, why = "ref", why or "requested"

@@ -21,8 +21,6 @@ Design points the hot paths rely on:
 * **disabled path**: with neither sink on, :func:`span` reads one module
   global and returns the shared :data:`NULL_SPAN` singleton — no
   allocation, no clock read, no branch in the instrumented code.
-  ``benchmarks/bench_obs.py`` measures this cost and CI gates it
-  (``obs.trace_overhead_pct``).
 * **per-thread nesting**: each thread keeps its own span stack
   (``threading.local``), so concurrently-planning threads don't parent
   into each other.  Cross-thread work (the Evaluator's compile pool)

@@ -25,7 +25,6 @@ clock beside the device's operations.
 """
 from __future__ import annotations
 
-import collections
 import time
 from dataclasses import dataclass
 from typing import Optional
@@ -83,10 +82,6 @@ class Server:
         self.params = params
         self.cfg = cfg or ServeConfig()
         self._bound = _Bound(model, plan)
-        # request-arrival timestamps for traffic_hz(): the signal the
-        # planning service's operating-point policy reads (latency-optimal
-        # under load, energy-optimal idle)
-        self._req_times: collections.deque = collections.deque(maxlen=256)
 
     @classmethod
     def from_store(cls, model: Model, params, store, fingerprint: str,
@@ -129,7 +124,6 @@ class Server:
         tokens = inputs["tokens"]
         b, s = tokens.shape
         t0 = time.perf_counter()
-        self._req_times.append(t0)
         span = obs_trace.span
         with span("serve.generate", batch=b, prompt_len=s,
                   max_new=max_new) as gen:
@@ -187,15 +181,6 @@ class Server:
             "jit_serve_decode": bound.decode.lower(
                 self.params, token, state).compile().as_text(),
         }
-
-    def traffic_hz(self, window_s: float = 60.0) -> float:
-        """Recent request rate (requests/s over the trailing window) — feed
-        it to :meth:`repro.service.service.PlanService.select_for_traffic`
-        to pick the right Pareto operating point for the current load."""
-        if window_s <= 0:
-            return 0.0
-        cutoff = time.perf_counter() - float(window_s)
-        return sum(1 for t in self._req_times if t >= cutoff) / float(window_s)
 
     def _sample(self, logits, key, i):
         lg = logits[:, -1].astype(jnp.float32)

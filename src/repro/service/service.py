@@ -47,8 +47,8 @@ from repro.core.frontends.registry import OffloadConfig
 from repro.core.offload import Offloader, PlanContext
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
-from repro.service.store import (PlanRecord, PlanStore, _json_safe,
-                                 env_matches, record_from_result)
+from repro.service.store import (PlanRecord, PlanStore, env_matches,
+                                 record_from_result)
 
 __all__ = ["PlanService", "ServedPlan", "ServiceConfig", "ServiceStats"]
 
@@ -71,10 +71,6 @@ class ServiceConfig:
                                       # once per round (deployed/in-flight
                                       # fingerprints always spared); None
                                       # disables the sweep
-    busy_hz: float = 1.0              # traffic threshold for
-                                      # select_for_traffic: at/above it the
-                                      # latency-optimal operating point is
-                                      # deployed, below it energy-optimal
 
 
 @dataclass
@@ -93,8 +89,6 @@ class ServiceStats:
     env_mismatches: int = 0  # warm loads refused because the stored plan
                              # was measured on different hardware (the
                              # cross-host reuse fix: re-measured instead)
-    repoints: int = 0        # operating-point swaps served straight from
-                             # the stored Pareto front (no search)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -294,75 +288,6 @@ class PlanService:
     def fingerprints(self) -> tuple[str, ...]:
         with self._lock:
             return tuple(self._entries)
-
-    # -- operating points (the Pareto front, served) -------------------------
-
-    #: objective name -> per-point field in PlanRecord.front
-    _FRONT_FIELDS = {"latency": "latency_s", "energy": "energy_j",
-                     "transfer": "transfer_bytes"}
-
-    def select_operating_point(self, fingerprint: str,
-                               objective: str = "latency") -> ServedPlan:
-        """Deploy the stored Pareto-front point optimal on one axis —
-        **without a new search**: the front was measured when the plan was,
-        so swapping between its points is a pure artifact re-apply plus a
-        store append (ties break toward lower latency; a record with no
-        front, e.g. from a single-objective search, keeps the current
-        plan).  The swap publishes like a refinement hot-swap: immutable
-        plan, single reference assignment, previous retained for rollback.
-        """
-        with self._lock:
-            entry = self._entries.get(fingerprint)
-        if entry is None:
-            raise LookupError(f"fingerprint {fingerprint!r} is not deployed")
-        try:
-            axis = self._FRONT_FIELDS[objective]
-        except KeyError:
-            raise ValueError(
-                f"unknown objective {objective!r}; known: "
-                f"{tuple(self._FRONT_FIELDS)}") from None
-        rec = entry.current.record
-        front = [p for p in rec.front if p.get("bits")]
-        if not front:
-            return entry.current
-        inf = float("inf")
-        point = min(front, key=lambda p: (float(p.get(axis, inf)),
-                                          float(p.get("latency_s", inf))))
-        bits = tuple(int(v) for v in point["bits"])
-        if bits == tuple(rec.bits):
-            return entry.current           # already at that operating point
-        artifact = entry.offloader.apply(entry.ctx, bits)
-        payload: dict = {}
-        from repro.models.plan import ExecPlan
-        if isinstance(artifact, ExecPlan):
-            payload["exec_plan"] = {
-                k: v for k, v in dataclasses.asdict(artifact).items()
-                if isinstance(v, (str, int, float, bool)) or v is None}
-        pattern = {str(k): _json_safe(v)
-                   for k, v in entry.ctx.coding.decode(bits).items()}
-        stored = self.store.put(dataclasses.replace(
-            rec, bits=bits, pattern=pattern, payload=payload,
-            best_time_s=float(point.get("latency_s", inf)),
-            meta={**rec.meta, "origin": "operating-point",
-                  "objective": objective, "repointed_from": rec.version}))
-        new_plan = ServedPlan(fingerprint, stored, artifact, warm=True)
-        with self._lock:
-            entry.previous = entry.current
-            entry.current = new_plan
-            self.stats.repoints += 1
-        obs_metrics.counter("service.repoints", objective=objective).inc()
-        return new_plan
-
-    def select_for_traffic(self, fingerprint: str, traffic_hz: float,
-                           busy_hz: Optional[float] = None) -> ServedPlan:
-        """Traffic-level policy over :meth:`select_operating_point`: under
-        load (>= ``busy_hz`` requests/s, default from ServiceConfig) serve
-        the latency-optimal front point; idle, the energy-optimal one.
-        Feed it :meth:`repro.runtime.serve.Server.traffic_hz`."""
-        thr = self.service_config.busy_hz if busy_hz is None \
-            else float(busy_hz)
-        objective = "latency" if float(traffic_hz) >= thr else "energy"
-        return self.select_operating_point(fingerprint, objective)
 
     # -- store hygiene -------------------------------------------------------
 

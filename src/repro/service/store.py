@@ -108,11 +108,6 @@ class PlanRecord:
                                       # the measurements were taken on
                                       # (environment_fingerprint()); empty
                                       # = unknown host, treated as mismatch
-    front: tuple = ()                 # Pareto front of the producing search:
-                                      # dicts of {bits, latency_s, energy_j,
-                                      # transfer_bytes} per non-dominated
-                                      # pattern — lets the service swap
-                                      # operating points without a search
 
     @property
     def speedup(self) -> float:
@@ -146,12 +141,13 @@ class PlanRecord:
             if math.isfinite(self.best_time_s) else None
         rec["baseline_time_s"] = self.baseline_time_s \
             if math.isfinite(self.baseline_time_s) else None
-        rec["front"] = [dict(p, bits=[int(v) for v in p.get("bits", ())])
-                        for p in self.front]
         return rec
 
     @classmethod
     def from_json(cls, rec: dict) -> "PlanRecord":
+        """Read a journal line.  Only this schema's keys are read, so a line
+        with keys it no longer has (an older store's ``front``) still
+        loads."""
         def _t(v):
             return float("inf") if v is None else float(v)
         return cls(
@@ -169,10 +165,7 @@ class PlanRecord:
             payload=dict(rec.get("payload") or {}),
             meta=dict(rec.get("meta") or {}),
             ts=float(rec.get("ts") or 0.0),
-            env=dict(rec.get("env") or {}),
-            front=tuple(dict(p, bits=tuple(int(v)
-                                           for v in p.get("bits", ())))
-                        for p in rec.get("front") or ()))
+            env=dict(rec.get("env") or {}))
 
 
 def _json_safe(value: Any) -> Any:
@@ -214,8 +207,7 @@ def record_from_result(res: OffloadResult, fingerprint: str,
         source=res.graph.source_name,
         payload=payload,
         meta=dict(meta or {}),
-        env=environment_fingerprint(),
-        front=tuple(res.front_summary()))
+        env=environment_fingerprint())
 
 
 class PlanStore:

@@ -653,3 +653,55 @@ def test_python_ast_ga_selects_measured_variant():
     assert res.artifact.report is res.report
     # and the interpreted path really was slower: measured speedup > 1
     assert res.speedup > 1.0
+
+
+def test_python_ast_proposes_no_mesh_genes_on_a_multi_device_host(
+        monkeypatch):
+    """The interpreter never runs a region sharded, so even where the host
+    reports eight devices the python_ast frontend proposes the variant
+    alphabet alone: no mesh gene it could not execute."""
+    from repro.core import genes
+
+    monkeypatch.setattr(genes, "_PROBED_DEVICE_COUNT", 8)
+    src, consts, inputs_fn, _, _ = PY_WORKLOADS["rmsnorm"]
+    ctx = Offloader(OffloadConfig(repeats=1, options={"consts": consts})
+                    ).prepare(src, inputs_fn())
+    assert ctx.frontend == "python_ast"
+    assert ctx.bundle.destinations == VARIANT_ALPHABET
+    assert ctx.coding.destinations == VARIANT_ALPHABET
+
+
+@pytest.mark.parametrize("name", ["python_ast", "jaxpr"])
+def test_ga_selects_block_gene_and_members_stay_ref(name):
+    """Through the whole pipeline: under a fitness that rewards the block
+    gene the GA selects it, its member regions end on their reference path
+    in the final pattern, and the report names the block's implementation."""
+    if name == "python_ast":
+        target, inputs, options = BLOCK_SRC, _block_inputs(), {}
+    else:
+        target, args = _jx_block_case()
+        inputs, options = None, {"example_args": args}
+    off = Offloader(OffloadConfig(
+        repeats=1, ga=GAConfig(population=8, generations=3, seed=0),
+        options=options))
+    ctx = off.prepare(target, inputs)
+    block = next(s for s in ctx.coding.sites if s.members)
+
+    def fitness(values) -> Evaluation:
+        decoded = ctx.coding.decode(values)
+        t = 1.0 - 0.05 * sum(decoded[s.region] != s.ref_impl
+                             for s in ctx.coding.sites)
+        if decoded[block.region] != block.ref_impl:
+            t -= 0.5
+        return Evaluation(tuple(values), t, True)
+
+    ctx.config.fitness_fn = fitness
+    res = off.search(ctx)
+    assert res.frontend == name
+    assert res.pattern[block.region] != block.ref_impl
+    refs = {s.region: s.ref_impl for s in res.coding.sites}
+    for member in block.members:
+        if member in refs:
+            assert res.pattern[member] == refs[member]
+    assert res.report.substituted.get(block.region) == \
+        res.pattern[block.region]

@@ -1,7 +1,7 @@
 """Property-based gene-coding tests over *arbitrary* destination alphabets.
 
-The shipped alphabets (binary, extended, variant) are three points in the
-space the encoding must cover; these properties hold for any alphabet built
+The shipped alphabets (binary, variant, with or without meshes) are points
+in the space the encoding must cover; these properties hold for any alphabet built
 from registered destinations: decode totality + ``impl_index`` clamping on
 sites with short implementation menus, decode/encode (``destinations_of``)
 round-trip, cross-alphabet seed-value mapping, and phenotype-key
@@ -13,29 +13,23 @@ bottom pin the same rules on fixed cases.
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.genes import (Destination, GeneCoding, Site,
-                              coding_from_graph, get_destination,
+from repro.core.genes import (VARIANT_ALPHABET, Destination, GeneCoding,
+                              Site, coding_from_graph, get_destination,
                               register_destination)
 from repro.core.ir import Region, RegionGraph
 from repro.core.offload import _map_destination_value, phenotype_key
 
-# a pool of synthetic executable destinations covering impl_index 0..4, so
-# alphabets are *arbitrary*, not just the three shipped ones
+# a pool of synthetic destinations covering impl_index 0..4, so alphabets
+# are *arbitrary*, not just the shipped ones
 for _i in range(5):
     try:
-        register_destination(Destination(f"xdev{_i}", executable=True,
-                                         impl_index=_i))
+        register_destination(Destination(f"xdev{_i}", impl_index=_i))
     except ValueError:
         pass                       # already registered by a previous import
-try:
-    register_destination(Destination("xstub", executable=False, impl_index=0,
-                                     launch_overhead_s=1e-4))
-except ValueError:
-    pass
 
-# mesh wire names are first-class alphabet entries (Destination v2): the
-# properties must hold with them mixed in
-ALPHA_POOL = ("cpu", "gpu", "fpga_stub", "gpu_fused", "gpu_pallas", "xstub",
+# mesh wire names are first-class alphabet entries: the properties must
+# hold with them mixed in
+ALPHA_POOL = ("cpu", "gpu", "gpu_fused", "gpu_pallas",
               "xdev0", "xdev1", "xdev2", "xdev3", "xdev4",
               "mesh:data:4:batch", "mesh:model:2:feature")
 
@@ -158,11 +152,15 @@ def test_phenotype_key_equates_clamped_chromosomes_only():
 
 
 def test_phenotype_key_separates_cost_only_parking():
+    # parking a gene on a destination that decodes to the reference impl
+    # (a mesh placement) is still a different program from leaving it on
+    # the reference path: different phenotype, different measurement
     coding = coding_from_graph(_graph(),
-                               destinations=("cpu", "gpu", "fpga_stub"))
+                               destinations=VARIANT_ALPHABET
+                               + ("mesh:data:2:batch",))
     key = phenotype_key(coding)
-    # both decode to the reference impl, but the stub charges modeled cost:
-    # different phenotype, different measurement
+    assert coding.decode((3, 0)) == coding.decode((0, 0))
+    assert key((0, 0)) != key((3, 0))
     assert key((0, 0)) != key((2, 0))
 
 
@@ -244,22 +242,6 @@ def test_phenotype_key_ignores_claimed_member_genes():
     assert key(chrom(blk=1)) != key(chrom())
 
 
-def test_modeled_cost_skips_claimed_members():
-    from repro.core.genes import modeled_cost_s
-    graph = _block_graph()
-    coding = coding_from_graph(graph,
-                               destinations=("cpu", "gpu_fused",
-                                             "fpga_stub"))
-    order = [s.region for s in coding.sites]
-    stub_a = tuple({"a": 2}.get(r, 0) for r in order)
-    cost_alone = modeled_cost_s(graph, coding, stub_a)
-    assert cost_alone > 0.0
-    # parking a *claimed* member on the stub charges nothing: the block
-    # adapter computes it, the stub never runs
-    stub_a_claimed = tuple({"a": 2, "blk": 1}.get(r, 0) for r in order)
-    assert modeled_cost_s(graph, coding, stub_a_claimed) == 0.0
-
-
 @given(data=st.data())
 @settings(max_examples=40, deadline=None)
 def test_block_claiming_property_members_always_ref(data):
@@ -283,7 +265,7 @@ def test_block_claiming_property_members_always_ref(data):
 
 @pytest.mark.parametrize("value,rec,expect", [
     (1, ("cpu", "gpu"), 1),                  # same alphabet
-    (1, ("cpu", "fpga_stub"), 1),            # offloaded name missing -> slot 1
+    (1, ("cpu", "gpu_pallas"), 1),           # offloaded name missing -> slot 1
     (0, ("cpu", "gpu"), 0),                  # ref stays ref
     (5, ("cpu", "gpu"), 0),                  # corrupt record
     (3, (), 1),                              # legacy clamp
@@ -337,50 +319,25 @@ def test_mesh_gene_decodes_to_ref_and_tags_phenotype():
                                destinations=("cpu", "gpu",
                                              "mesh:data:4:batch"))
     # a mesh gene never invents an implementation: the decoded impl map is
-    # the reference path (the frontend realizes sharding, or the cost model
-    # charges it)
+    # the reference path (the frontend realizes sharding)
     decoded = coding.decode((2, 2))
     assert decoded == {"two": "ref", "three": "ref"}
     key = phenotype_key(coding)
-    # ...but placement changes the phenotype: all-ref, stub-parked and
-    # mesh-placed chromosomes are three different programs
+    # ...but placement changes the phenotype: all-ref, one gene and two
+    # genes on the mesh are three different programs
     assert key((0, 0)) != key((2, 0))
     assert key((2, 0)) != key((2, 2))
 
 
-def test_mesh_modeled_cost_charged_unless_executed():
-    from repro.core import genes
-    from repro.core.genes import modeled_cost_s, probed_device_count
-
-    graph = _graph()
-    coding = coding_from_graph(graph,
-                               destinations=("cpu", "gpu",
-                                             "mesh:data:4:batch"))
-    mesh_bits = (2, 2)
-    # on a single-device host the mesh is cost-only: positive modeled charge
-    assert probed_device_count() < 4
-    assert modeled_cost_s(graph, coding, mesh_bits) > 0.0
-    assert modeled_cost_s(graph, coding, (0, 0)) == 0.0
-    # a fitness that genuinely shards (mesh_executed=True) on a host
-    # that has the devices is not double-charged
-    old = genes._PROBED_DEVICE_COUNT
-    genes._PROBED_DEVICE_COUNT = 8
-    try:
-        assert modeled_cost_s(graph, coding, mesh_bits,
-                              mesh_executed=True) == 0.0
-        # modeled-only fitness still pays, even with the devices present
-        assert modeled_cost_s(graph, coding, mesh_bits) > 0.0
-    finally:
-        genes._PROBED_DEVICE_COUNT = old
-
-
 def test_mesh_proposals_respect_device_count():
-    from repro.core.genes import (VARIANT_ALPHABET, mesh_proposals,
-                                  with_mesh_destinations)
+    from repro.core.genes import mesh_proposals, with_mesh_destinations
 
     assert mesh_proposals(device_count=1) == ()
     assert mesh_proposals(device_count=4) == ("mesh:data:2:batch",
                                               "mesh:data:4:batch")
+    assert with_mesh_destinations(("cpu", "gpu"), device_count=8) == (
+        "cpu", "gpu", "mesh:data:2:batch", "mesh:data:4:batch",
+        "mesh:data:8:batch")
     assert mesh_proposals(axes=("data", "model"), device_count=2) == \
         ("mesh:data:2:batch", "mesh:model:2:feature")
     # with_mesh_destinations extends an alphabet without duplicates
@@ -390,11 +347,3 @@ def test_mesh_proposals_respect_device_count():
     # single-device host: alphabet unchanged (CI fingerprints stay stable)
     assert with_mesh_destinations(VARIANT_ALPHABET, device_count=1) == \
         VARIANT_ALPHABET
-
-
-def test_mesh_watts_scale_with_device_count():
-    from repro.core.genes import MESH_DEVICE_POWER_W, MeshDestination
-
-    d = MeshDestination(axis="data", n=4)
-    assert d.watts() == 4 * MESH_DEVICE_POWER_W
-    assert get_destination("cpu").watts() > 0.0

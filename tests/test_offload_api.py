@@ -17,12 +17,11 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import (DEFAULT_ALPHABET, EXTENDED_ALPHABET, Evaluation,
+from repro.core import (DEFAULT_ALPHABET, VARIANT_ALPHABET, Evaluation,
                         GAConfig, OffloadConfig, OffloadResult, Offloader,
                         Region, RegionGraph, coding_from_graph,
-                        detect_frontend, frontend_names, get_frontend,
-                        modeled_cost_s, plan, plan_offload, resolve_alphabet,
-                        run_ga)
+                        detect_frontend, frontend_names, get_frontend, plan,
+                        plan_offload, resolve_alphabet, run_ga)
 from repro.core.ga import GAResult
 from repro.core.offload import SeedBank, _pattern_db_seed, ga_search
 from repro.core.pattern_db import default_db
@@ -200,7 +199,9 @@ def test_module_artifact_is_execplan_with_block_claims():
     res = plan_offload(target, inputs, config=_config(kwargs))
     from repro.models.plan import ExecPlan
     assert isinstance(res.artifact, ExecPlan)
-    # block-pass claims survive into the final plan regardless of the GA
+    # the pattern DB matches the model graph's blocks, and the block-pass
+    # claims survive into the final plan regardless of the GA
+    assert res.block.offloads and res.block.plan_updates
     for field, value in res.block.plan_updates.items():
         assert getattr(res.artifact, field) == value
 
@@ -230,6 +231,34 @@ def test_serial_parallel_reproducible(name):
 
 
 # ---------------------------------------------------------------------------
+# contract 3b: fixed-seed goldens of Offloader.plan per frontend
+# ---------------------------------------------------------------------------
+
+# frontend -> chosen bits, evaluations, best_time_s per generation, with the
+# deterministic golden fitness at population 8, generations 3, seed 5
+PLAN_GOLDENS = {
+    "ir": ((0, 0, 0), 8, [1.5, 1.5, 1.5]),
+    "jaxpr": ((0,), 1, [1.0, 1.0, 1.0]),
+    "module": ((0, 2, 0, 1), 16, [1.65, 1.65, 1.2]),
+    "python_ast": ((0, 0), 4, [1.35, 1.35, 1.35]),
+}
+
+
+@pytest.mark.parametrize("name", ALL_FRONTENDS)
+def test_plan_golden_per_frontend(name):
+    from test_ga import golden_fitness
+
+    target, inputs, kwargs = FRONTEND_CASES[name]()
+    res = Offloader(_config(kwargs, fitness_fn=golden_fitness,
+                            ga=GAConfig(population=8, generations=3,
+                                        seed=5))).plan(target, inputs)
+    bits, evaluations, history = PLAN_GOLDENS[name]
+    assert res.best.bits == bits
+    assert res.ga.evaluations == evaluations
+    assert [h["best_time_s"] for h in res.ga.history] == history
+
+
+# ---------------------------------------------------------------------------
 # contract 4: multi-destination decode
 # ---------------------------------------------------------------------------
 
@@ -240,7 +269,7 @@ def test_multi_destination_decode(name):
 
     def plan():
         t, i, k = FRONTEND_CASES[name]()
-        cfg = _config(k, destinations=EXTENDED_ALPHABET,
+        cfg = _config(k, destinations=VARIANT_ALPHABET,
                       fitness_fn=_det_fitness,
                       ga=GAConfig(population=8, generations=3, seed=3))
         return Offloader(cfg).plan(t, i)
@@ -249,37 +278,21 @@ def test_multi_destination_decode(name):
     assert r1.coding.arity == 3
     assert all(0 <= int(v) < 3 for v in r1.best.bits)
 
-    # an all-stub chromosome decodes every site to its *reference*
-    # implementation (cost-only device) and charges a positive modeled cost
-    stub = tuple(2 for _ in r1.coding.sites)
-    decoded = r1.coding.decode(stub)
+    # the third letter selects each site's second alternative, clamped to
+    # the last implementation on a two-entry menu; 0 is the reference
+    third = tuple(2 for _ in r1.coding.sites)
+    decoded = r1.coding.decode(third)
     for site in r1.coding.sites:
-        assert decoded[site.region] == site.ref_impl
-    assert set(r1.coding.destinations_of(stub).values()) == {"fpga_stub"}
-    assert modeled_cost_s(r1.graph, r1.coding, stub) > 0
-    ref = tuple(0 for _ in r1.coding.sites)
-    assert modeled_cost_s(r1.graph, r1.coding, ref) == 0.0
+        assert decoded[site.region] == site.impls[min(2, len(site.impls) - 1)]
+    assert set(r1.coding.destinations_of(third).values()) == {"gpu_pallas"}
+    ref = r1.coding.decode(tuple(0 for _ in r1.coding.sites))
+    assert ref == {s.region: s.ref_impl for s in r1.coding.sites}
 
     # fixed-seed search over the enlarged space is reproducible bit-for-bit
     r2 = plan()
     assert r1.best.bits == r2.best.bits
     assert [h["best_time_s"] for h in r1.ga.history] == \
         [h["best_time_s"] for h in r2.ga.history]
-
-
-def test_destination_cost_steers_search_away_from_stub():
-    # with a fitness that ignores the genes, the modeled stub cost is the
-    # only signal — the GA must keep regions off the cost-only device
-    g = _ir_graph()
-    cfg = OffloadConfig(
-        destinations=EXTENDED_ALPHABET,
-        fitness_fn=lambda values: Evaluation(tuple(values), 1.0, True),
-        ga=GAConfig(population=10, generations=6, seed=0),
-        seed_from_db=False)
-    res = Offloader(cfg).plan(g)
-    assert "fpga_stub" not in res.destinations.values()
-    # and a measured chromosome that used the stub was charged for it
-    assert modeled_cost_s(g, res.coding, (2, 2, 2)) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -305,8 +318,8 @@ def test_plan_wrapper_assembles_config_from_kwargs():
 
 
 def test_resolve_alphabet_explicit_config_wins():
-    cfg = OffloadConfig(destinations=EXTENDED_ALPHABET)
-    assert resolve_alphabet(cfg, ("cpu", "gpu")) == EXTENDED_ALPHABET
+    cfg = OffloadConfig(destinations=VARIANT_ALPHABET)
+    assert resolve_alphabet(cfg, ("cpu", "gpu")) == VARIANT_ALPHABET
 
 
 def test_resolve_alphabet_falls_back_to_frontend_proposal():
@@ -319,6 +332,29 @@ def test_resolve_alphabet_falls_back_to_frontend_proposal():
 def test_resolve_alphabet_defaults_when_nothing_given():
     assert resolve_alphabet(None) == DEFAULT_ALPHABET
     assert resolve_alphabet(OffloadConfig(), None) == DEFAULT_ALPHABET
+
+
+@pytest.mark.parametrize("mesh,host_devices", [
+    ("mesh:data:2:batch", 1),
+    ("mesh:data:8:batch", 4),
+    ("mesh:model:4:feature", 2),
+])
+def test_resolve_alphabet_refuses_mesh_wider_than_host(monkeypatch, mesh,
+                                                       host_devices):
+    # a mesh the host cannot build has no measurement: an explicit alphabet
+    # naming one is refused before any search, never modelled
+    from repro.core import genes
+
+    monkeypatch.setattr(genes, "_PROBED_DEVICE_COUNT", host_devices)
+    with pytest.raises(ValueError, match="needs"):
+        resolve_alphabet(OffloadConfig(destinations=("cpu", "gpu", mesh)))
+    with pytest.raises(ValueError, match="needs"):
+        Offloader(OffloadConfig(destinations=("cpu", mesh))).prepare(
+            _ir_graph())
+    # a mesh that fits the host is accepted
+    fits = f"mesh:data:{host_devices}:batch"
+    assert resolve_alphabet(OffloadConfig(destinations=("cpu", fits))) == \
+        ("cpu", fits)
 
 
 def test_resolve_alphabet_validates_names():
@@ -372,19 +408,6 @@ def test_offloader_rejects_pool():
         Offloader(cfg).plan(_ir_graph())
 
 
-def test_surrogate_ranks_stub_behind_reference():
-    # cost-only genes decode to the reference path (zero transfers); the
-    # surrogate must charge their modeled cost so screening doesn't invert
-    from repro.core.evaluator import transfer_cost_surrogate
-
-    g = _ir_graph()
-    coding = coding_from_graph(g, destinations=EXTENDED_ALPHABET)
-    cost = transfer_cost_surrogate(g, coding)
-    n = coding.length
-    assert cost((2,) * n) > cost((0,) * n), \
-        "stub-parked chromosome must rank behind the free reference path"
-
-
 def test_surrogate_rank_corr_reported_by_search():
     g = _ir_graph()
     _, ga = ga_search(g, _det_fitness,
@@ -405,7 +428,7 @@ def test_seed_bank_neighbor_warm_start(tmp_path):
     assert bank.neighbor_seeds(g2, coding_from_graph(g2)) == []
     # values clamp to the current alphabet
     bank2 = SeedBank(str(tmp_path / "b2"))
-    coding3 = coding_from_graph(g, destinations=EXTENDED_ALPHABET)
+    coding3 = coding_from_graph(g, destinations=VARIANT_ALPHABET)
     bank2.record(g, coding3, (2, 0, 2))
     assert bank2.neighbor_seeds(g, coding)[0] == (1, 0, 1)
 
@@ -442,15 +465,13 @@ def test_seed_bank_cross_destination_mapping(tmp_path):
     # a neighbor's GPU gene (binary alphabet) seeds a search over alphabets
     # that don't contain "gpu": offloaded genes land on the new primary
     # accelerator, reference genes stay reference
-    from repro.core import VARIANT_ALPHABET
-
     g = _ir_graph()
     bank = SeedBank(str(tmp_path))
     bank.record(g, coding_from_graph(g), (1, 0, 1))        # cpu/gpu record
     variant_coding = coding_from_graph(g, destinations=VARIANT_ALPHABET)
     assert bank.neighbor_seeds(g, variant_coding) == [(1, 0, 1)]
-    stub_coding = coding_from_graph(g, destinations=("cpu", "fpga_stub"))
-    assert bank.neighbor_seeds(g, stub_coding) == [(1, 0, 1)]
+    pallas_coding = coding_from_graph(g, destinations=("cpu", "gpu_pallas"))
+    assert bank.neighbor_seeds(g, pallas_coding) == [(1, 0, 1)]
     # and the reverse: a variant-alphabet record seeding a binary search
     bank2 = SeedBank(str(tmp_path / "rev"))
     bank2.record(g, variant_coding, (2, 0, 1))             # pallas/ref/fused

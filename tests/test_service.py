@@ -556,100 +556,65 @@ def test_pre_env_records_always_remeasure(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# operating points: the persisted Pareto front is served without a search
+# the record schema: no Pareto front, and older lines that carry one load
 # ---------------------------------------------------------------------------
 
 
-def _mo_ir_config(**over):
-    from repro.core.genes import EXTENDED_ALPHABET
-    from repro.core.objectives import OBJECTIVES
-
-    def speedup(values) -> Evaluation:
-        t = 1.0 - 0.12 * sum(int(v) == 1 for v in values)
-        return Evaluation(tuple(values), t, True)
-
-    ga = over.pop("ga", GAConfig(population=8, generations=2, seed=0,
-                                 objectives=OBJECTIVES))
-    over.setdefault("fitness_fn", speedup)
-    over.setdefault("destinations", EXTENDED_ALPHABET)
-    return OffloadConfig(frontend="ir", ga=ga, **over)
-
-
-def test_select_operating_point_swaps_without_search(tmp_path):
-    with PlanService(str(tmp_path), config=_mo_ir_config()) as svc:
-        plan = svc.plan(_ir_graph())
-        fp = plan.fingerprint
-        assert len(plan.record.front) >= 2
-        searches = svc.stats.searches
-
-        # already latency-optimal (the GA best): a no-op, not a repoint
-        lat = svc.select_operating_point(fp, "latency")
-        assert lat is plan and svc.stats.repoints == 0
-
-        en = svc.select_operating_point(fp, "energy")
-        assert svc.stats.searches == searches, "repoint must not search"
-        assert svc.stats.repoints == 1
-        assert en.record.bits != lat.record.bits
-        assert en.record.meta["origin"] == "operating-point"
-        assert en.record.meta["objective"] == "energy"
-        assert en.version > lat.version          # persisted as a new head
-        assert svc.current(fp) is en
-        # the energy point trades latency for joules
-        en_pt = min(plan.record.front, key=lambda p: p["energy_j"])
-        assert en.record.bits == tuple(en_pt["bits"])
-        assert en.record.best_time_s >= lat.record.best_time_s
-
-        # swap back: rollback target retained, still no search
-        back = svc.select_operating_point(fp, "latency")
-        assert back.record.bits == lat.record.bits
-        assert svc.stats.searches == searches and svc.stats.repoints == 2
-
-        with pytest.raises(ValueError):
-            svc.select_operating_point(fp, "carbon")
-        with pytest.raises(LookupError):
-            svc.select_operating_point("no-such-fp")
-
-
-def test_select_for_traffic_policy(tmp_path):
-    svc_cfg = ServiceConfig(busy_hz=2.0)
-    with PlanService(str(tmp_path), config=_mo_ir_config(),
-                     service=svc_cfg) as svc:
-        fp = svc.plan(_ir_graph()).fingerprint
-        busy = svc.select_for_traffic(fp, traffic_hz=10.0)
-        idle = svc.select_for_traffic(fp, traffic_hz=0.1)
-        assert busy.record.bits != idle.record.bits
-        assert idle.record.meta["objective"] == "energy"
-        # threshold boundary: at busy_hz the latency point serves
-        again = svc.select_for_traffic(fp, traffic_hz=2.0)
-        assert again.record.bits == busy.record.bits
-        # explicit threshold override wins over ServiceConfig
-        forced = svc.select_for_traffic(fp, traffic_hz=1.0, busy_hz=0.5)
-        assert forced.record.bits == busy.record.bits
-
-
 def test_single_objective_record_has_no_front_and_keeps_plan(tmp_path):
+    import json
+
+    from repro.service.store import PLAN_STORE_FILE
+
     with PlanService(str(tmp_path), config=_ir_config()) as svc:
         plan = svc.plan(_ir_graph())
-        # single-objective search: a one-point front (the best) persists,
-        # so every objective resolves to the deployed plan — no swap
-        assert len(plan.record.front) == 1
-        same = svc.select_operating_point(plan.fingerprint, "energy")
-        assert same.record.bits == plan.record.bits
-        assert svc.stats.repoints == 0
+    # the journal line is the record's own fields: no front is written
+    with open(tmp_path / PLAN_STORE_FILE) as f:
+        line = json.loads(f.readline())
+    assert "front" not in line
+    assert line["bits"] == list(plan.record.bits)
+    # and a restart keeps serving the same plan, with no search
+    with PlanService(str(tmp_path), config=_ir_config()) as svc2:
+        again = svc2.plan(_ir_graph())
+        assert again.warm and svc2.stats.searches == 0
+        assert again.record.bits == plan.record.bits
 
 
-def test_server_traffic_hz_tracks_request_rate():
-    server = Server.__new__(Server)          # rate window only, no model
-    import collections
-    server._req_times = collections.deque(maxlen=256)
-    assert server.traffic_hz() == 0.0
-    now = time.perf_counter()
-    server._req_times.extend([now - 0.5, now - 0.2, now - 0.1])
-    assert server.traffic_hz(window_s=60.0) == pytest.approx(3 / 60.0)
-    assert server.traffic_hz(window_s=0.0) == 0.0
-    # requests older than the window age out of the rate
-    server._req_times.appendleft(now - 120.0)
-    assert server.traffic_hz(window_s=60.0) == pytest.approx(3 / 60.0)
+def test_store_loads_pre_change_record_with_front(tmp_path):
+    # a journal line written by a store whose records carried the search's
+    # Pareto front: the extra key is ignored, the plan warm-loads and serves
+    import json
+
+    from repro.service.store import PLAN_STORE_FILE
+
+    arch = get_config("qwen3_0_6b")
+    cfg = OffloadConfig(ga=GAConfig(population=4, generations=1, seed=0))
+    off = Offloader(cfg)
+    ctx = off.prepare(arch)
+    rec = record_from_result(off.search(ctx), ctx.fingerprint)
+    line = {**rec.to_json(), "version": 1, "ts": time.time(),
+            "front": [{"bits": list(rec.bits),
+                       "latency_s": rec.best_time_s,
+                       "transfer_bytes": 0.0}]}
+    with open(tmp_path / PLAN_STORE_FILE, "w") as f:
+        f.write(json.dumps(line) + "\n")
+
+    store = PlanStore(str(tmp_path))
+    loaded = store.load(ctx.fingerprint)
+    assert loaded.bits == rec.bits and loaded.version == 1
+    assert loaded.payload == rec.payload
+    with PlanService(str(tmp_path), config=cfg) as svc:
+        plan = svc.plan(arch)
+        assert plan.warm and svc.stats.searches == 0
+        assert plan.record.bits == rec.bits
+
+    small = arch.reduced()
+    model = build_model(small)
+    params = model.init(jax.random.key(0))
+    server = Server.from_store(model, params, store, ctx.fingerprint,
+                               ServeConfig(max_new_tokens=2))
+    assert server.plan == plan.artifact
+    toks = jnp.zeros((1, 8), jnp.int32)
+    assert server.generate({"tokens": toks}).shape == (1, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -699,11 +664,15 @@ def test_no_ttl_configured_means_no_sweep(tmp_path):
         assert svc.store.load(fp) is not None
 
 
-def test_store_roundtrips_mesh_destinations(tmp_path):
+def test_store_roundtrips_mesh_destinations(tmp_path, monkeypatch):
     # mesh placements are wire names in the alphabet, so a mesh plan rides
-    # the JSONL schema unchanged: store -> load -> parsed MeshDestination
+    # the JSONL schema unchanged: store -> load -> parsed MeshDestination.
+    # The plan is made as on a four-device host (an explicit mesh wider
+    # than the host is refused)
+    from repro.core import genes
     from repro.core.genes import MeshDestination
 
+    monkeypatch.setattr(genes, "_PROBED_DEVICE_COUNT", 4)
     alphabet = ("cpu", "gpu", "mesh:data:4:batch")
     off = Offloader(_ir_config(destinations=alphabet))
     ctx = off.prepare(_ir_graph())

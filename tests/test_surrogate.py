@@ -127,6 +127,30 @@ def test_coefficient_persistence_round_trip(tmp_path):
     assert rec2["n_records"] == fit2.n_records
 
 
+def test_fit_records_of_an_older_schema_are_ignored_then_refit(tmp_path):
+    # a coefficient record persisted under older feature names (a modelled
+    # stub-cost feature, another objective's fit) is never read as the
+    # current fit: load_fit skips it, and the next fit replaces it
+    g = _seed_journal(tmp_path)
+    coding = coding_from_graph(g)
+    names = list(FeatureExtractor(g, coding, prior=lambda b: 0.0)
+                 .feature_names)
+    old = names[:7] + ["stub_cost"] + names[7:]
+    with open(os.path.join(str(tmp_path), SURROGATE_FIT_FILE), "w") as f:
+        for objective in ("latency", "transfer"):
+            f.write(json.dumps({
+                "fingerprint": "fp", "objective": objective,
+                "n_records": 99, "feature_names": old,
+                "coef": [1.0] * len(old), "mean": [0.0] * len(old),
+                "scale": [1.0] * len(old), "intercept": 0.0}) + "\n")
+    assert load_fit(str(tmp_path), "fp") is None
+    fit = fit_surrogate(g, coding, str(tmp_path), "fp", min_records=10)
+    rec = load_fit(str(tmp_path), "fp")
+    assert rec["n_records"] == fit.n_records != 99
+    assert rec["feature_names"] == names
+    assert "stub_cost" not in rec["coefficients"]
+
+
 def test_feature_extractor_names_align_with_vector(tmp_path):
     g = _graph()
     coding = coding_from_graph(g)
